@@ -6,7 +6,7 @@ import collections
 
 import numpy as np
 
-from benchmarks.common import emit, keyset, rows_to_csv
+from benchmarks.common import emit, enable_compile_cache, keyset, rows_to_csv
 from repro.core import make
 
 ENGINES = ["binomial", "jump", "fliphash-recon", "powerch-recon", "jumpback-recon"]
@@ -39,4 +39,5 @@ def main() -> list[list]:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -33,7 +33,7 @@ import time
 
 import numpy as np
 
-from benchmarks.common import REPO_ROOT, emit, rows_to_csv, write_bench_json
+from benchmarks.common import REPO_ROOT, emit, enable_compile_cache, rows_to_csv, write_bench_json
 
 sys.path.insert(0, os.path.join(REPO_ROOT, "tests"))
 
@@ -163,4 +163,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     raise SystemExit(main())

@@ -14,7 +14,7 @@ man (a full reshuffle) whose column reads ``n/a``.
 """
 from __future__ import annotations
 
-from benchmarks.common import emit, keyset, rows_to_csv
+from benchmarks.common import emit, enable_compile_cache, keyset, rows_to_csv
 from repro.core import make
 
 ENGINES = ["binomial", "jump", "anchor-lifo", "dx-lifo", "fliphash-recon", "jumpback-recon", "modulo"]
@@ -76,4 +76,5 @@ def main() -> list[list]:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -2,7 +2,7 @@
 fleet resizes and failure storms (the system-level face of the paper)."""
 from __future__ import annotations
 
-from benchmarks.common import emit, rows_to_csv
+from benchmarks.common import emit, enable_compile_cache, rows_to_csv
 from repro.placement.assignment import Assignment
 from repro.placement.elastic import FailureDomain, plan_expert_migration
 
@@ -43,4 +43,5 @@ def main() -> list[list]:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -9,7 +9,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from benchmarks.common import emit, rows_to_csv, time_loop
+from benchmarks.common import emit, enable_compile_cache, rows_to_csv, time_loop
 from repro.core.binomial import binomial_lookup32
 from repro.core.binomial_jax import binomial_lookup_vec
 from repro.kernels.binomial_hash import binomial_bulk_lookup_pallas
@@ -59,4 +59,5 @@ def main() -> list[list]:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

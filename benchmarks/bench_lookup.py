@@ -9,7 +9,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from benchmarks.common import emit, keyset, rows_to_csv, time_loop
+from benchmarks.common import emit, enable_compile_cache, keyset, rows_to_csv, time_loop
 from repro.core import make
 from repro.core.binomial_jax import binomial_lookup_vec
 
@@ -46,4 +46,5 @@ def main() -> list[list]:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

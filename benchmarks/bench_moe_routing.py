@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.common import emit, rows_to_csv, time_loop
+from benchmarks.common import emit, enable_compile_cache, rows_to_csv, time_loop
 from repro.configs import reduced_config
 from repro.core.binomial_jax import binomial_lookup_vec, mix32
 from repro.models.layers.moe import init_moe, route
@@ -101,4 +101,5 @@ def main(argv: list[str] | None = None) -> list[list]:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main(sys.argv[1:])

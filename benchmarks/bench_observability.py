@@ -37,7 +37,7 @@ import sys
 
 import numpy as np
 
-from benchmarks.common import emit, time_loop, write_bench_json
+from benchmarks.common import emit, enable_compile_cache, time_loop, write_bench_json
 
 ENGINES = ("binomial", "jump")
 N_REPLICAS = 48
@@ -203,4 +203,5 @@ def main(argv: list[str] | None = None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())

@@ -30,7 +30,7 @@ import sys
 
 import numpy as np
 
-from benchmarks.common import emit, rows_to_csv, time_loop, write_bench_json
+from benchmarks.common import emit, enable_compile_cache, rows_to_csv, time_loop, write_bench_json
 
 ENGINES = ("binomial", "jump")
 R = 3
@@ -177,4 +177,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     raise SystemExit(main())

@@ -8,7 +8,7 @@ from __future__ import annotations
 import json
 import os
 
-from benchmarks.common import OUT_DIR, emit, rows_to_csv
+from benchmarks.common import OUT_DIR, emit, enable_compile_cache, rows_to_csv
 
 DRYRUN_JSON = os.path.join(OUT_DIR, "dryrun.json")
 
@@ -52,4 +52,5 @@ def main() -> list[list]:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

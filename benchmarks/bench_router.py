@@ -11,9 +11,8 @@ exists for — and (c) a storm-severity sweep at fixed removed fractions:
 * ``fused``    — the single-dispatch fused lookup+divert kernel over
   device-resident fleet state (``BatchRouter`` default).
 
-Plus a multi-device section — the mesh-sharded datapath (DESIGN.md §8) run
-in a subprocess with fake host devices, so the shard_map path is exercised
-end-to-end even on a single-chip host — an ``end_to_end`` ingest
+Plus a multi-device section — the mesh-sharded datapath (DESIGN.md §8) over
+every device of the process, in-process — an ``end_to_end`` ingest
 section: session ids in, replica ids out, comparing the vectorised ingest
 (``route_batch``: byte-matrix FNV-1a + bulk movement store, DESIGN.md §9)
 and the kernel-fused u64-id ingest (``route_ids``) against the retired
@@ -43,9 +42,6 @@ storm/steady ratio this bench exists to track needs the noise floor low.
 from __future__ import annotations
 
 import argparse
-import json
-import os
-import subprocess
 import sys
 import time
 
@@ -53,7 +49,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.common import emit, rows_to_csv, write_bench_json
+from benchmarks.common import emit, enable_compile_cache, rows_to_csv, write_bench_json
 from repro.serving.batch_router import BatchRouter
 from repro.serving.router import SessionRouter
 
@@ -288,71 +284,40 @@ def _end_to_end_stats(n_sessions: int, iters: int) -> dict:
     return out
 
 
-_MULTI_DEVICE_SCRIPT = r"""
-import os, sys, json, time
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count={n_dev} "
-    + os.environ.get("XLA_FLAGS", "")
-)
-import jax, numpy as np
-import jax.numpy as jnp
-from repro.serving.batch_router import BatchRouter
-
-batch, iters = {batch}, {iters}
-rng = np.random.default_rng(0)
-keys = jnp.asarray(rng.integers(0, 2**64, size=(batch,), dtype=np.uint64)
-                   .astype(np.uint32))
-
-def timed(router):
-    jax.block_until_ready(router.route_keys(keys))
-    best = float("inf")
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        jax.block_until_ready(router.route_keys(keys))
-        best = min(best, time.perf_counter() - t0)
-    return best
-
-mesh = jax.make_mesh(({n_dev},), ("data",))
-sharded = BatchRouter(16, mesh=mesh)
-single = BatchRouter(16)
-for r in (sharded, single):
-    r.fail(3)  # measure the storm path, the harder case
-res = {{
-    "n_devices": {n_dev},
-    "sharded_us_per_batch": timed(sharded) * 1e6,
-    "single_us_per_batch": timed(single) * 1e6,
-}}
-print("RESULTS " + json.dumps(res))
-"""
-
-
 def _multi_device_stats(batch: int, iters: int) -> dict:
-    """Run the mesh-sharded datapath in a subprocess with fake host devices.
-
-    On a CPU host the fake devices contend for the same cores (XLA:CPU
-    already parallelises single-device batches), so keys/s here validates
-    the shard_map path end-to-end rather than demonstrating chip scaling —
-    the honest expectation on real multi-chip hosts is near-linear because
-    the per-device work is embarrassingly parallel (no collectives).
+    """Time the mesh-sharded datapath over every device of this process
+    (``jax.devices()``) against a single-device router, storm path (one
+    failed replica).  In-process: a chip belongs to the process that
+    touched JAX first, so a child process could not reach it.  On a
+    one-device host the mesh has one shard and only checks the shard_map
+    path end to end; with fake CPU devices (``XLA_FLAGS``) they contend for
+    the same cores, so only a multi-chip host shows scaling.
     """
-    n_dev = min(8, os.cpu_count() or 1)
-    script = _MULTI_DEVICE_SCRIPT.format(n_dev=n_dev, batch=batch, iters=iters)
-    env = dict(os.environ)
-    src = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
-    prev = env.get("PYTHONPATH")
-    env["PYTHONPATH"] = src if not prev else src + os.pathsep + prev
-    try:
-        out = subprocess.run(
-            [sys.executable, "-c", script], env=env, capture_output=True,
-            text=True, timeout=900,
-        )
-        line = [l for l in out.stdout.splitlines() if l.startswith("RESULTS ")]
-        if out.returncode != 0 or not line:
-            return {"error": (out.stderr or out.stdout)[-2000:]}
-        res = json.loads(line[0][len("RESULTS "):])
-    except (subprocess.TimeoutExpired, OSError) as e:  # pragma: no cover
-        return {"error": str(e)}
-    res["batch_keys"] = batch
+    n_dev = len(jax.devices())
+    rng = np.random.default_rng(0)
+    keys = jnp.asarray(
+        rng.integers(0, 2**64, size=(batch,), dtype=np.uint64).astype(np.uint32)
+    )
+
+    def timed(router) -> float:
+        jax.block_until_ready(router.route_keys(keys))
+        best = float("inf")
+        for _ in range(iters):
+            t0 = time.perf_counter()
+            jax.block_until_ready(router.route_keys(keys))
+            best = min(best, time.perf_counter() - t0)
+        return best
+
+    sharded = BatchRouter(16, mesh=jax.make_mesh((n_dev,), ("data",)))
+    single = BatchRouter(16)
+    for r in (sharded, single):
+        r.fail(3)  # measure the storm path, the harder case
+    res = {
+        "n_devices": n_dev,
+        "batch_keys": batch,
+        "sharded_us_per_batch": timed(sharded) * 1e6,
+        "single_us_per_batch": timed(single) * 1e6,
+    }
     res["sharded_keys_per_sec"] = batch / (res["sharded_us_per_batch"] / 1e6)
     res["sharded_over_single"] = (
         res["single_us_per_batch"] / res["sharded_us_per_batch"]
@@ -567,4 +532,5 @@ def main(argv: list[str] | None = None) -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main(sys.argv[1:])

@@ -42,7 +42,7 @@ import time
 
 import numpy as np
 
-from benchmarks.common import emit, rows_to_csv, write_bench_json
+from benchmarks.common import emit, enable_compile_cache, rows_to_csv, write_bench_json
 
 ENGINES = ("binomial", "jump")
 
@@ -359,4 +359,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     raise SystemExit(main())

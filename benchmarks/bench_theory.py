@@ -6,7 +6,7 @@ import collections
 
 import numpy as np
 
-from benchmarks.common import emit, keyset, rows_to_csv
+from benchmarks.common import emit, enable_compile_cache, keyset, rows_to_csv
 from repro.core import analysis, binomial_lookup64
 
 
@@ -47,4 +47,5 @@ def main() -> list[list]:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -10,6 +10,29 @@ import time
 OUT_DIR = os.path.join(os.path.dirname(__file__), "out")
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
+#: where compiled executables persist when ``JAX_COMPILATION_CACHE_DIR`` is
+#: unset: one fixed path in the checkout (gitignored), because a cache
+#: directory that moves between runs is never found again
+DEFAULT_COMPILE_CACHE = os.path.join(REPO_ROOT, ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for a script that drives
+    the device, and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    this sets no other directory; otherwise the cache goes to
+    ``DEFAULT_COMPILE_CACHE``.  Call it from a script's entry point, before
+    the first compile — never from library code or tests.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        import jax
+
+        path = DEFAULT_COMPILE_CACHE
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
 
 def rows_to_csv(name: str, header: list[str], rows: list[list]) -> str:
     os.makedirs(OUT_DIR, exist_ok=True)

@@ -59,4 +59,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from benchmarks.common import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -41,7 +41,7 @@ import dataclasses
 from typing import Callable, Iterable, Iterator, Optional
 
 import jax
-import jax.core as jax_core
+import jax.extend.core as jax_core
 import numpy as np
 
 from repro.analysis.markers import waivers_of
@@ -159,7 +159,7 @@ def certify_callable(
     waivers = waivers or {}
     report = TargetReport(engine=engine, target=target)
 
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         base = tracer(contract.omega)
         eqns = list(iter_eqns(base.jaxpr))
         counts = [len(eqns)]

@@ -171,7 +171,7 @@ class _Linter(ast.NodeVisitor):
                 node,
                 "config-mutation",
                 "global jax config mutated in library code — use the scoped "
-                "context manager (e.g. jax.experimental.enable_x64) or move "
+                "context manager (e.g. jax.enable_x64) or move "
                 "the flip to test/tool setup",
             )
         self.generic_visit(node)

@@ -164,10 +164,12 @@ def relocate_within_level(b: jax.Array, h: jax.Array) -> jax.Array:
     ``cascade(b) = 2^(d+1)-1`` so ``f = cascade >> 1 = 2^d-1`` and
     ``top = f+1 = 2^d`` — skipping the popcount multiply and variable shift
     of ``highest_one_bit_index`` (same values, fewer VPU ops per call, and
-    this is called ω+1 times per lookup).
+    this is called ω+1 times per lookup).  ``b = 0`` needs no clamp to 1:
+    ``cascade(0) >> 1 == cascade(1) >> 1 == 0``, and lanes with ``b < 2``
+    return ``b`` anyway (an unsigned max does not lower on the TPU).
     """
     b = b.astype(jnp.uint32)
-    f = _or_cascade(jnp.maximum(b, np.uint32(1))) >> 1
+    f = _or_cascade(b) >> 1
     top = f + np.uint32(1)
     i = hash_pair(h, f) & f
     return jnp.where(b < 2, b, top + i)

@@ -108,29 +108,73 @@ class JumpHash32:
 # ---------------------------------------------------------------------------
 
 
+def _bits_f32(bits: jax.Array) -> jax.Array:
+    return jax.lax.bitcast_convert_type(bits.astype(jnp.uint32), jnp.float32)
+
+
+def rn_top_quotient(fr: jax.Array, q0: jax.Array) -> jax.Array:
+    """``RN(2^31 / fr)`` for integer-valued f32 ``fr`` in [1, 2^31], given
+    any estimate ``q0`` of it within a few ulps.
+
+    The scalar oracle divides in numpy, which rounds correctly; a TPU v5e's
+    f32 divide misses the correctly rounded quotient by an ulp for about a
+    quarter of these divisors.  So the device rounds the quotient itself.
+    With ``fr = m * 2^(E-23)`` (``m`` the 24-bit mantissa), the quotient is
+    ``2^47/m * 2^(7-E)`` and its mantissa ``Q = RN(2^47/m)`` lies in
+    (2^23, 2^24].  For the estimate's mantissa ``Q0``,
+    ``t = 2^48 + m - 2m*Q0`` satisfies ``Q - Q0 = floor(t / 2m)``; ``t`` is
+    small, so wrapping u32 products give it exactly, and three compares
+    each way find the floor.  ``2^47/m`` is never a tie: ``2^48 = (2Q+1)m``
+    has no solution.
+    """
+    bits = jax.lax.bitcast_convert_type(fr, jnp.uint32)
+    e = bits >> np.uint32(23)
+    m2 = ((bits & np.uint32(0x7FFFFF)) | np.uint32(0x800000)) << np.uint32(1)
+    # 2^(E-7) and 2^(7-E) from exponent fields (E = e - 127, 0 <= E <= 31)
+    down = _bits_f32((e - np.uint32(7)) << np.uint32(23))
+    up = _bits_f32((np.uint32(261) - e) << np.uint32(23))
+    big_q0 = (q0 * down).astype(jnp.int32)  # integer-valued: exact
+    t = jax.lax.bitcast_convert_type(
+        (m2 >> np.uint32(1)) - big_q0.astype(jnp.uint32) * m2, jnp.int32
+    )
+    step = m2.astype(jnp.int32)
+    k = jnp.zeros_like(t)
+    for i in range(1, 4):
+        k = k + (t >= i * step).astype(jnp.int32) - (t < (1 - i) * step).astype(jnp.int32)
+    return (big_q0 + k).astype(jnp.float32) * up
+
+
 def jump_unrolled_body(keys_u32: jax.Array, n_u32: jax.Array, omega: int) -> jax.Array:
     """ω-unrolled jump chain: u32 keys + traced n -> u32 buckets in [0, n).
 
     Every lane runs all ω LCG steps (divergent exits buy nothing on a VREG
     grid); ``done`` freezes each lane's bucket at its first exiting step.
     The f32 product can reach ~2^51 on exited lanes — their (out-of-range)
-    u32 cast is masked off by ``done``, and continuing lanes satisfy
+    integer cast is masked off by ``done``, and continuing lanes satisfy
     ``fj < n <= 2^24`` so their cast is exact.
+
+    Every int <-> f32 conversion goes through int32: the TPU has no
+    u32 <-> f32 cast.  ``n`` and ``b + 1`` are at most 2^24, so int32 holds
+    them exactly; ``r`` reaches 2^31, whose bits read as int32 -2^31, and
+    ``abs`` of its (exact) f32 image restores 2^31.  The quotient goes
+    through ``rn_top_quotient``, so every backend rounds it as numpy does.
     """
     lo = keys_u32.astype(jnp.uint32)
     hi = jnp.zeros_like(lo)
     b = jnp.zeros_like(lo)
     done = jnp.zeros(lo.shape, dtype=bool)
-    fn = n_u32.astype(jnp.float32)
+    fn = n_u32.astype(jnp.int32).astype(jnp.float32)
     for _ in range(omega):
         # k = k * LCG + 1 mod 2^64, in u32 limbs (add-with-carry on the +1)
         lo, hi = _mul64(lo, hi, JUMP_LCG)
         lo = lo + np.uint32(1)
         hi = hi + jnp.where(lo == 0, np.uint32(1), np.uint32(0))
         r = (hi >> np.uint32(1)) + np.uint32(1)  # (k >> 33) + 1
-        fj = (b + np.uint32(1)).astype(jnp.float32) * (_F_TOP / r.astype(jnp.float32))
+        fr = jnp.abs(jax.lax.bitcast_convert_type(r, jnp.int32).astype(jnp.float32))
+        fb = (b + np.uint32(1)).astype(jnp.int32).astype(jnp.float32)
+        fj = fb * rn_top_quotient(fr, _F_TOP / fr)
         exits = fj >= fn
-        b = jnp.where(~done & ~exits, fj.astype(jnp.uint32), b)
+        b = jnp.where(~done & ~exits, fj.astype(jnp.int32).astype(jnp.uint32), b)
         done = done | exits
     return jnp.where(n_u32 <= np.uint32(1), np.uint32(0), b)
 

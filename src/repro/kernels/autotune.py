@@ -29,8 +29,12 @@ import time
 #: definition; ``RouterSpec.resolved_block_rows`` resolves through it too
 from repro.core.bulk import DEFAULT_BLOCK_ROWS  # noqa: F401,E402
 
-#: candidate VMEM tilings: 64 KiB .. 1 MiB per in/out block at 4B x 128 lanes
-CANDIDATES = (128, 256, 512, 1024, 2048)
+#: candidate VMEM tilings: 64 KiB .. 256 KiB per in/out block at 4B x 128
+#: lanes.  The kernel bodies keep every unrolled intermediate block-sized,
+#: so larger tiles stop paying: on a TPU v5e at capacity 1024, 2048 rows
+#: need 17 MiB of scoped VMEM (the limit is 16 MiB) and the compiler
+#: refuses them, and 1024 rows take ~3x the compile time of 512.
+CANDIDATES = (128, 256, 512)
 
 
 def cache_path() -> str:
@@ -60,7 +64,7 @@ def _store(path: str, cache: dict) -> None:
 
 
 #: bump to invalidate every persisted verdict when the kernels change shape
-CACHE_SCHEMA = "v1"
+CACHE_SCHEMA = "v2"
 
 
 def tuned_block_rows(

@@ -334,17 +334,18 @@ def make_sharded_route(mesh, spec: RouterSpec | None = None, **legacy_kwargs):
 def _make_sharded_route_impl(mesh, spec: RouterSpec):
     from jax.sharding import PartitionSpec as P
 
-    from repro.sharding.rules import shard_map_compat
-
     def inner(keys, fleet):
         return route_bulk(keys, fleet, spec)
 
     fleet_specs = FleetState(P(), P(), P(), capacity=spec.capacity)
-    sharded = shard_map_compat(
+    sharded = jax.shard_map(
         inner,
-        mesh,
+        mesh=mesh,
         in_specs=(P(spec.shard_axis), fleet_specs),
         out_specs=P(spec.shard_axis),
+        # a pallas_call's output carries no varying-axes type for the check
+        # to read; each shard's result depends on its own keys only
+        check_vma=False,
     )
     return jax.jit(sharded, donate_argnums=(0,) if spec.donate_keys else ())
 

@@ -34,7 +34,7 @@ from repro.configs.base import ArchConfig
 from repro.core.binomial_jax import mix32
 from repro.core.registry import make_bulk
 from repro.models.layers.common import dense_init, init_mlp, apply_mlp
-from repro.sharding.rules import current_mesh, expert_layout, logical, shard, shard_map_compat
+from repro.sharding.rules import current_mesh, expert_layout, logical, shard
 
 GOLDEN32 = np.uint32(0x9E3779B9)
 
@@ -296,7 +296,7 @@ def apply_moe(p, x, token_ids, layer_salt, cfg: ArchConfig):
                 return jax.lax.psum(y, "model").reshape(xs.shape)
 
             dspec = P(dp_axes, None, None)
-            y = shard_map_compat(
+            y = jax.shard_map(
                 body,
                 mesh=mesh,
                 in_specs=(dspec, dspec, dspec, fsdp_w, fsdp_w, fsdp_wo),

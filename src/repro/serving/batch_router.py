@@ -375,14 +375,22 @@ class BatchRouter:
         if not self.spec.pallas_selected() or self.spec.interpret:
             return autotune.DEFAULT_BLOCK_ROWS
         if rows not in self._tuned_rows:
-            probe = np.zeros((rows * LANES,), dtype=np.uint32)
+            # device-resident, laid out as route_keys would see it: timing a
+            # host upload along with the kernel would hide the tiling
+            from jax.sharding import NamedSharding, PartitionSpec as P
+
+            probe = jax.device_put(
+                np.zeros((rows * LANES * self._n_shards,), dtype=np.uint32),
+                None if self.mesh is None
+                else NamedSharding(self.mesh, P(self.spec.shard_axis)),
+            )
 
             def measure(candidate: int) -> None:
                 # probe batches are timing scaffolding, not traffic: keep
                 # them out of any attached load accumulator
                 monitor, self._load_monitor = self._load_monitor, None
                 try:
-                    jax.block_until_ready(self._dispatch(probe, candidate))
+                    jax.block_until_ready(self._route(probe, candidate))
                 finally:
                     self._load_monitor = monitor
 
@@ -515,7 +523,9 @@ class BatchRouter:
             flat = jnp.asarray(flat).copy()
         out = route(flat, self._fleet_dev)
         if pad:
-            out = out[:total]
+            # a ragged length cannot stay split over the axis; name the
+            # (replicated) result sharding, which Explicit mesh axes require
+            out = out.at[:total].get(out_sharding=NamedSharding(self.mesh, P()))
         return out.reshape(shape)
 
     def route_keys(self, keys) -> jax.Array:
@@ -540,12 +550,14 @@ class BatchRouter:
         rows = -(-size // LANES)
         # tune for what one device actually sees: the per-shard row count
         block_rows = self._resolve_block_rows(-(-rows // self._n_shards))
-        if self.mesh is not None:
-            out = self._route_sharded(keys_u32, block_rows)
-        else:
-            out = self._dispatch(keys_u32, block_rows)
+        out = self._route(keys_u32, block_rows)
         self.stats.lookups += size
         return out
+
+    def _route(self, keys_u32, block_rows: int) -> jax.Array:
+        if self.mesh is not None:
+            return self._route_sharded(keys_u32, block_rows)
+        return self._dispatch(keys_u32, block_rows)
 
     def route_keys_np(self, keys) -> np.ndarray:
         """Numpy-in/numpy-out convenience wrapper around ``route_keys``."""

@@ -13,7 +13,6 @@ Logical axes:
 """
 from __future__ import annotations
 
-import inspect
 import re
 from contextlib import contextmanager
 
@@ -21,30 +20,6 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 _ACTIVE: dict = {"mesh": None, "fsdp": False, "expert_layout": "ep"}
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``shard_map`` across JAX versions.
-
-    ``jax.shard_map`` only exists on newer JAX; older releases ship it as
-    ``jax.experimental.shard_map.shard_map``.  The replication-check kwarg
-    was also renamed (``check_rep`` -> ``check_vma``) on a different schedule
-    than the promotion to ``jax.``, so the kwarg name is picked from the
-    actual signature rather than inferred from where the function lives.
-    """
-    if hasattr(jax, "shard_map"):
-        sm = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as sm
-
-    params = inspect.signature(sm).parameters
-    if "check_vma" in params:
-        kwargs = {"check_vma": check_vma}
-    elif "check_rep" in params:
-        kwargs = {"check_rep": check_vma}
-    else:  # opaque (*args/**kwargs) signature — rely on the default check
-        kwargs = {}
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
 
 
 def set_mesh(mesh: Mesh | None, fsdp: bool = False, expert_layout: str = "ep") -> None:

@@ -21,7 +21,7 @@ def _fake_measure(times: dict, calls: list):
 def test_tuner_picks_fastest_candidate_and_persists(tmp_path, monkeypatch):
     path = str(tmp_path / "cache.json")
     # fake timer: pretend 256 is the fastest tiling
-    times = {128: 5e-4, 256: 1e-4, 512: 3e-4, 1024: 9e-4, 2048: 9e-4}
+    times = {128: 5e-4, 256: 1e-4, 512: 3e-4}
     ticker = {"t": 0.0}
 
     def fake_clock():
@@ -78,16 +78,16 @@ def test_tuner_distinguishes_cache_keys(tmp_path, monkeypatch):
                                   measure=measure, path=path)
 
     def measure2(c):
-        ticker["t"] += (1e-4 if c == 1024 else 5e-4)
+        ticker["t"] += (1e-4 if c == 512 else 5e-4)
 
     b = autotune.tuned_block_rows("tpu", rows=4096, capacity=256,
                                   measure=measure2, path=path)
-    assert a == 128 and b == 1024
+    assert a == 128 and b == 512
     # a different datapath variant must NOT inherit the fused verdict
     c = autotune.tuned_block_rows("tpu", rows=4096, capacity=64,
                                   measure=measure2, path=path,
                                   variant="two_pass")
-    assert c == 1024
+    assert c == 512
     with open(path) as f:
         assert len(json.load(f)) == 3
 

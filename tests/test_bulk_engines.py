@@ -12,11 +12,13 @@ import pytest
 from repro.core import bits
 from repro.core.bulk import FleetState, RouterSpec
 from repro.core.jump_jax import (
+    _F_TOP,
     JumpHash32,
     jump_lookup32,
     jump_lookup_dyn,
     jump_lookup_vec,
     jump_memento_route,
+    rn_top_quotient,
 )
 from repro.core.memento_jax import mask_words, pack_removed_mask, pack_table
 from repro.kernels import ops
@@ -76,6 +78,25 @@ def test_jump_lookup_respects_omega_bound():
         scal = [jump_lookup32(int(x), 1000, omega) for x in keys]
         np.testing.assert_array_equal(out, scal)
         assert (out >= 0).all() and (out < 1000).all()
+
+
+@pytest.mark.parametrize("ulps", [-2, -1, 0, 1, 2])
+def test_rn_top_quotient_corrects_an_inexact_divide(ulps):
+    """The device rounds 2^31 / fr itself: an estimate a few ulps off (a
+    TPU v5e's divide misses by one for ~1/4 of the divisors) still yields
+    numpy's correctly rounded quotient, for every divisor the jump step can
+    form (integer-valued f32 in [1, 2^31])."""
+    edges = np.array([1, 2, 3, 2**23 - 1, 2**23, 2**23 + 1, 2**24 - 1,
+                      2**24, 2**30 + 2**7, 2**31], dtype=np.float32)
+    small = RNG.integers(1, 1 << 24, size=1 << 15).astype(np.float32)
+    big = RNG.integers(0x4B800000, 0x4F000001, size=1 << 15,
+                       dtype=np.int64).astype(np.uint32).view(np.float32)
+    fr = np.concatenate([edges, small, big])
+    exact = _F_TOP / fr
+    estimate = (exact.view(np.uint32).astype(np.int64) + ulps).astype(
+        np.uint32).view(np.float32)
+    got = np.asarray(rn_top_quotient(jnp.asarray(fr), jnp.asarray(estimate)))
+    np.testing.assert_array_equal(got.view(np.uint32), exact.view(np.uint32))
 
 
 def test_jump_engine_scalar_facade():

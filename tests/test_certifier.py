@@ -142,7 +142,9 @@ def test_host_callback_fails():
 
 
 def _transfer_route(keys, omega):
-    lut = jax.device_put(np.arange(8, dtype=np.int32))
+    # a traced operand: JAX folds a device_put of a numpy constant into a
+    # closed-over constant, which leaves no device_put equation to count
+    lut = jax.device_put(jnp.arange(8, dtype=jnp.int32))
     return lut[keys.astype(jnp.int32) % 8]
 
 

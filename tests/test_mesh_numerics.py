@@ -14,8 +14,6 @@ import sys
 import pytest
 
 _SCRIPT = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import dataclasses
 import jax, jax.numpy as jnp
@@ -23,6 +21,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import reduced_config
+from repro.launch.mesh import make_local_mesh
 from repro.models import model as M
 from repro.sharding import rules
 
@@ -46,7 +45,7 @@ for arch, elayout in [("qwen3-moe-235b-a22b", "ep"), ("qwen3-moe-235b-a22b", "tp
     ref_loss, _ = M.loss_fn(params, batch, cfg)
     ref_grad = jax.grad(lambda p: M.loss_fn(p, batch, cfg)[0])(params)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_local_mesh(2, 4)
     with rules.mesh_context(mesh, fsdp=True, expert_layout=elayout):
         pspecs = rules.params_pspecs(params)
         psh = jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs,
@@ -76,6 +75,7 @@ print("RESULTS " + json.dumps(results))
 def test_sharded_matches_reference():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     out = subprocess.run(
         [sys.executable, "-c", _SCRIPT], env=env, capture_output=True, text=True, timeout=1200
     )

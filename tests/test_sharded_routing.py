@@ -11,8 +11,6 @@ import sys
 import pytest
 
 _SCRIPT = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import jax
 import numpy as np
@@ -85,6 +83,7 @@ print("RESULTS " + json.dumps(results))
 def test_sharded_routing_matches_single_device_and_oracle():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     out = subprocess.run(
         [sys.executable, "-c", _SCRIPT], env=env, capture_output=True, text=True,
         timeout=900,

@@ -1,0 +1,107 @@
+"""Compile the main-path Pallas kernels for a described TPU v5e.
+
+Interpret mode runs the kernels on the CPU but cannot see what the chip's
+compiler refuses (unsupported casts, unsigned ops, tiles that overflow the
+scoped VMEM).  These tests compile every ``pallas_call`` the routing path
+dispatches — route, u64 ingest and ``lookup_dyn`` of both bulk engines, and
+the static binomial lookup — for one chip of a ``v5e:2x2`` topology that is
+described, not attached, at 2^20 keys and capacity 1024, and check the
+kernel is in the compiled program.
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and pytest-xdist workers
+import every test file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.memento_jax import mask_words
+from repro.core.registry import BULK_ENGINES
+from repro.kernels.autotune import CANDIDATES
+from repro.kernels.fused import LANES
+
+CAPACITY = 1024
+KEYS = 1 << 20
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    log_dir = os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep these compiles out of it
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    compilation_cache.reset_cache()
+    if log_dir == "disabled":
+        os.environ.pop("TPU_LOG_DIR", None)
+
+
+def _shape(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compiled_text(sharding, engine: str, kernel: str, block_rows: int) -> str:
+    eng = BULK_ENGINES[engine]
+    keys = _shape(sharding, (KEYS,), jnp.uint32)
+    fleet = (
+        _shape(sharding, (1, mask_words(CAPACITY)), jnp.uint32),
+        _shape(sharding, (1, CAPACITY), jnp.int32),
+        _shape(sharding, (2,), jnp.uint32),
+    )
+    extents = (mask_words(CAPACITY), CAPACITY)
+    if kernel == "route":
+        fn = lambda k, *f: eng.route_pallas(  # noqa: E731
+            k, *f, *extents, block_rows=block_rows)
+        args = (keys, *fleet)
+    elif kernel == "ingest":
+        fn = lambda lo, hi, *f: eng.ingest_pallas(  # noqa: E731
+            lo, hi, *f, *extents, block_rows=block_rows)
+        args = (keys, keys, *fleet)
+    else:
+        fn = lambda k, n: eng.lookup_dyn_pallas(  # noqa: E731
+            k, n, block_rows=block_rows)
+        args = (keys, _shape(sharding, (), jnp.uint32))
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("kernel", ["route", "ingest", "lookup_dyn"])
+@pytest.mark.parametrize("engine", sorted(BULK_ENGINES))
+def test_kernel_compiles_for_v5e(one_chip, engine, kernel):
+    """Smallest autotuner tile: the kernel body itself must lower."""
+    assert "tpu_custom_call" in _compiled_text(
+        one_chip, engine, kernel, min(CANDIDATES)
+    )
+
+
+def test_static_binomial_lookup_compiles_for_v5e(one_chip):
+    """The static-n lookup (``ops.binomial_bulk_lookup`` on a TPU)."""
+    from repro.kernels.binomial_hash import binomial_bulk_lookup_pallas
+
+    keys = _shape(one_chip, (KEYS,), jnp.uint32)
+    fn = lambda k: binomial_bulk_lookup_pallas(  # noqa: E731
+        k, 1000, block_rows=min(CANDIDATES))
+    assert "tpu_custom_call" in jax.jit(fn).lower(keys).compile().as_text()
+
+
+def test_largest_autotuned_tile_fits_v5e(one_chip):
+    """The largest tile the autotuner may pick holds the most VMEM."""
+    assert max(CANDIDATES) * LANES <= KEYS
+    assert "tpu_custom_call" in _compiled_text(
+        one_chip, "binomial", "route", max(CANDIDATES)
+    )
