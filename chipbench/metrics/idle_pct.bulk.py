@@ -1,0 +1,6 @@
+"""Share of the traced window in which no operation ran on the chip; on
+four chips, the idlest chip's."""
+
+
+def read(run):
+    return None if run.trace is None else run.trace.idle_pct_max()
