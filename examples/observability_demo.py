@@ -6,7 +6,10 @@ Run:  PYTHONPATH=src python examples/observability_demo.py
 Four acts, all on ONE virtual µs timeline so every number reproduces:
 
 1. a streaming front end serves traffic with the telemetry plane wired
-   through admit -> batch close -> dispatch -> read -> complete;
+   through its spans: a measured ``dispatch`` per closed batch (tagged
+   with its size, the requests its gate shed and their summed queue
+   wait), ``lifecycle_tick`` inside it, ``collect`` on the result, and
+   one ``request`` span per served request;
 2. a bulk router with a ``LoadMonitor`` attached routes exact and
    stride-sampled batches through the instrumented fused dispatch, then
    drains the device accumulator and compares peak/mean against the
@@ -79,8 +82,12 @@ def act_1_streaming(clock, metrics, trace):
     total_lat = sum(h.count for h in lat.values())
     print(f"  served {served}, shed {shed}; latency histogram holds "
           f"{total_lat} samples across {len(lat)} tenants")
-    for name in ("admit", "batch_close", "dispatch", "request"):
-        print(f"  spans[{name:>11}] = {trace.count(name)}")
+    for name in ("dispatch", "collect", "lifecycle_tick", "request"):
+        print(f"  spans[{name:>14}] = {trace.count(name)}")
+    size = sum(s.tag("size") for s in trace.spans("dispatch"))
+    wait = sum(s.tag("wait_us_sum") for s in trace.spans("dispatch"))
+    if size:
+        print(f"  mean wait in the open batch: {wait / size:.1f} us")
 
 
 def act_2_load_monitor(metrics):

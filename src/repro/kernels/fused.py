@@ -41,6 +41,7 @@ from repro.core.binomial_jax import (
     mulhi32,
 )
 from repro.core.memento_jax import _binomial_lookup_body
+from repro.observability.trace import NULL_SPAN, span
 
 LANES = 128  # TPU minor-dim tile
 
@@ -124,6 +125,11 @@ def _check_state_extents(packed_mask, table, n_words: int, n_slots: int) -> None
         raise ValueError(f"n_words ({n_words}) must be in [1, {packed_mask.shape[1]}]")
     if not 1 <= n_slots <= table.shape[1]:
         raise ValueError(f"n_slots ({n_slots}) must be in [1, {table.shape[1]}]")
+
+
+def _host_span(name: str, eager: bool):
+    """``span(name)`` around eager work; nothing while tracing."""
+    return span(name) if eager else NULL_SPAN
 
 
 def _pad_flat(flat: jax.Array, block_rows: int) -> tuple[jax.Array, int]:
@@ -217,13 +223,26 @@ def make_fused_kernels(lookup, name: str) -> FusedKernels:
         keys, packed_mask, table, state, n_words, n_slots,
         omega=16, block_rows=512, interpret=False,
     ):
-        """Any-shape int keys + fleet state -> i32 replica ids, fused kernel."""
-        flat, total = _pad_flat(keys.reshape(-1).astype(jnp.uint32), block_rows)
-        out = route_2d(
-            flat.reshape(-1, LANES), packed_mask, table, state, n_words,
-            n_slots, omega=omega, block_rows=block_rows, interpret=interpret,
-        )
-        return out.reshape(-1)[:total].reshape(keys.shape)
+        """Any-shape int keys + fleet state -> i32 replica ids, fused kernel.
+
+        Called eagerly, the layout in and out are executables of their own,
+        each under a ``route.layout`` span, and the kernel's enqueue is the
+        ``route.launch`` span; traced (inside a jit or a shard_map) it opens
+        no span, since a span there would time the tracing.
+        """
+        eager = not isinstance(keys, jax.core.Tracer)
+        with _host_span("route.layout", eager):
+            flat, total = _pad_flat(keys.reshape(-1).astype(jnp.uint32), block_rows)
+            flat = flat.reshape(-1, LANES)
+        with _host_span("route.launch", eager) as s:
+            if s:
+                s.tag(rows=flat.shape[0], block_rows=block_rows)
+            out = route_2d(
+                flat, packed_mask, table, state, n_words,
+                n_slots, omega=omega, block_rows=block_rows, interpret=interpret,
+            )
+        with _host_span("route.layout", eager):
+            return out.reshape(-1)[:total].reshape(keys.shape)
 
     @functools.partial(
         jax.jit,

@@ -34,14 +34,14 @@ from repro.observability.metrics import (
     MetricsRegistry,
 )
 from repro.observability.trace import (
-    SPAN_ADMIT,
-    SPAN_BATCH_CLOSE,
+    SPAN_COLLECT,
     SPAN_DISPATCH,
     SPAN_LIFECYCLE_TICK,
     SPAN_READ,
     SPAN_REQUEST,
     Span,
     SpanTrace,
+    span,
 )
 
 __all__ = [
@@ -63,12 +63,12 @@ __all__ = [
     "Gauge",
     "Histogram",
     "MetricsRegistry",
-    "SPAN_ADMIT",
-    "SPAN_BATCH_CLOSE",
+    "SPAN_COLLECT",
     "SPAN_DISPATCH",
     "SPAN_LIFECYCLE_TICK",
     "SPAN_READ",
     "SPAN_REQUEST",
     "Span",
     "SpanTrace",
+    "span",
 ]

@@ -54,6 +54,8 @@ import numpy as np
 from repro.core.binomial_jax import GOLDEN32, mix32, mulhi32
 from repro.core.bulk import FleetState, PlacementSpec, RouterSpec
 from repro.kernels import ops
+from repro.kernels.fused import LANES
+from repro.observability.trace import span
 from repro.placement.assignment import MovementPlan
 from repro.serving.lifecycle.errors import (
     MODE_DEGRADED,
@@ -373,9 +375,13 @@ class StorePlacement:
         """Raw device placement: ``(replicas (N, r) i32, exhausted (N,)
         bool)``, no degradation typing (the expert path; ``place`` wraps
         it).  Routability (``n_alive >= 1``) is still enforced."""
-        fleet = self._fleet_dev()
-        keys_u32 = self.router._coerce_keys(keys)
-        return ops.route_replicas_bulk(keys_u32, fleet, self.spec)
+        with span("route.call"):
+            fleet = self._fleet_dev()
+            keys_u32 = self.router._coerce_keys(keys)
+            with span("route.launch") as s:
+                if s:
+                    s.tag(rows=-(-int(np.size(keys_u32)) // LANES))
+                return ops.route_replicas_bulk(keys_u32, fleet, self.spec)
 
     def place(self, keys) -> PlacedBatch:
         """Place keys on ``r`` distinct alive shards, typed and epoch-

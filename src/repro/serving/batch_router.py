@@ -68,6 +68,7 @@ from repro.core.registry import make_bulk
 from repro.kernels import autotune
 from repro.kernels import ops
 from repro.kernels.fused import LANES
+from repro.observability.trace import span
 from repro.serving.lifecycle.errors import FleetUnavailableError
 from repro.serving.router import SessionRouter, hash_session_ids
 
@@ -506,26 +507,36 @@ class BatchRouter:
         pad = (-total) % self._n_shards
         owned = not isinstance(keys_u32, jax.Array)  # we upload -> we may donate
         if pad:
-            flat = (np.pad if isinstance(flat, np.ndarray) else jnp.pad)(flat, (0, pad))
+            with span("route.layout"):
+                flat = (np.pad if isinstance(flat, np.ndarray) else jnp.pad)(
+                    flat, (0, pad))
             owned = True
         if isinstance(flat, np.ndarray):
             # upload already sharded along the mesh axis — the executable
             # never has to re-lay it out, and the buffer is ours to donate
-            flat = jax.device_put(
-                flat, NamedSharding(self.mesh, P(self.spec.shard_axis))
-            )
+            with span("route.layout"):
+                flat = jax.device_put(
+                    flat, NamedSharding(self.mesh, P(self.spec.shard_axis))
+                )
         route = self._sharded_route.get(block_rows)
         if route is None:
             route = ops.make_sharded_route(self.mesh, self._dispatch_spec(block_rows))
             self._sharded_route[block_rows] = route
         if self.spec.donate_keys and not owned:
             # donation consumes the buffer; never consume one the caller owns
-            flat = jnp.asarray(flat).copy()
-        out = route(flat, self._fleet_dev)
+            with span("route.layout"):
+                flat = jnp.asarray(flat).copy()
+        with span("route.launch") as s:
+            if s:
+                s.tag(rows=(total + pad) // self._n_shards // LANES,
+                      block_rows=block_rows)
+            out = route(flat, self._fleet_dev)
         if pad:
             # a ragged length cannot stay split over the axis; name the
             # (replicated) result sharding, which Explicit mesh axes require
-            out = out.at[:total].get(out_sharding=NamedSharding(self.mesh, P()))
+            with span("route.layout"):
+                out = out.at[:total].get(
+                    out_sharding=NamedSharding(self.mesh, P()))
         return out.reshape(shape)
 
     def route_keys(self, keys) -> jax.Array:
@@ -540,19 +551,21 @@ class BatchRouter:
         movement bookkeeping; use ``route_batch`` for session-level
         observability, ``route_keys_np`` for numpy.
         """
-        self._check_routable()
-        keys_u32 = self._coerce_keys(keys)
-        size = int(np.size(keys_u32))
-        if size == 0:
-            # zero-row batches have nothing to dispatch (and the kernel grid
-            # cannot be empty) — answer with an empty result of the right type
-            return jnp.zeros(np.shape(keys_u32), dtype=jnp.int32)
-        rows = -(-size // LANES)
-        # tune for what one device actually sees: the per-shard row count
-        block_rows = self._resolve_block_rows(-(-rows // self._n_shards))
-        out = self._route(keys_u32, block_rows)
-        self.stats.lookups += size
-        return out
+        with span("route.call"):
+            self._check_routable()
+            keys_u32 = self._coerce_keys(keys)
+            size = int(np.size(keys_u32))
+            if size == 0:
+                # zero-row batches have nothing to dispatch (and the kernel
+                # grid cannot be empty) — answer with an empty result of
+                # the right type
+                return jnp.zeros(np.shape(keys_u32), dtype=jnp.int32)
+            rows = -(-size // LANES)
+            # tune for what one device actually sees: the per-shard row count
+            block_rows = self._resolve_block_rows(-(-rows // self._n_shards))
+            out = self._route(keys_u32, block_rows)
+            self.stats.lookups += size
+            return out
 
     def _route(self, keys_u32, block_rows: int) -> jax.Array:
         if self.mesh is not None:

@@ -32,6 +32,7 @@ from __future__ import annotations
 import dataclasses
 from typing import NamedTuple
 
+from repro.observability.trace import SPAN_LIFECYCLE_TICK, span
 from repro.serving.lifecycle.detector import (
     FailureDetector,
     HeartbeatConfig,
@@ -88,7 +89,8 @@ class LifecycleManager:
         self.config = config or LifecycleConfig()
         self.clock = clock or MonotonicClock()
         #: optional SpanTrace — each tick() records a ``lifecycle_tick``
-        #: span (the streaming front end attaches its shared trace here)
+        #: span, measured on this manager's clock (the streaming front end
+        #: attaches its shared trace here)
         self.tracer = tracer
         #: attached PlacementRepairer (None = no placement tier); every
         #: journaled membership mutation re-syncs it
@@ -141,16 +143,18 @@ class LifecycleManager:
         repair batch (the repairer's budget), so re-replication bandwidth
         is metered by the dispatch cadence.
         """
-        events = self.apply(self.detector.poll())
-        if self._placement is not None:
-            self._placement.tick()
-        if self.tracer is not None:
-            now_us = int(self.clock.now() * 1_000_000)
-            self.tracer.record(
-                "lifecycle_tick", now_us, now_us,
-                events=len(events), epoch=self.epoch,
-            )
+        with span(SPAN_LIFECYCLE_TICK, self.tracer, self._now_us) as s:
+            with span("detector.poll"):
+                transitions = self.detector.poll()
+            events = self.apply(transitions)
+            if self._placement is not None:
+                self._placement.tick()
+            if s:
+                s.tag(events=len(events), epoch=self.epoch)
         return events
+
+    def _now_us(self) -> int:
+        return int(self.clock.now() * 1_000_000)
 
     # -- membership events (all journaled) -----------------------------------
     def apply(self, transitions) -> list:

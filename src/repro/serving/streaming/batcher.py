@@ -27,10 +27,15 @@ substituted into the guarantee.
 
 Telemetry (DESIGN.md §15): served/dispatch counters, batch-size and
 per-tenant request-latency histograms all land in the shared
-``MetricsRegistry``, and with a ``SpanTrace`` attached the batcher
-records the request-path spans (``admit`` at submit, ``batch_close`` +
-``dispatch`` at close, one ``request`` span per served request at
-collect) on the same µs timeline the batcher itself runs on.
+``MetricsRegistry``.  The batcher opens three spans
+(``repro.observability.trace.span``): ``dispatch``, measured from the
+close's gate to the returned handle and tagged ``size``, ``shed``,
+``bound_us`` (the declared bound) and ``wait_us_sum`` (the kept
+requests' summed wait in the open batch); ``collect``, the wait on the
+device result; and one ``request`` span per served request, arrival to
+completion.  With a ``SpanTrace`` attached they land in its ring on the
+batcher's own µs clock; while the JAX profiler traces, ``dispatch`` and
+``collect`` land in its trace too.
 
 Time is pluggable (``clock.now_us()``): virtual for chaos/bench
 determinism, wall for production.  In virtual mode the service model is
@@ -39,11 +44,16 @@ injected too; in wall mode the materialisation block is measured.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Callable
 
 import numpy as np
 
+from repro.observability.trace import (
+    SPAN_COLLECT,
+    SPAN_DISPATCH,
+    SPAN_REQUEST,
+    span,
+)
 from repro.serving.lifecycle.errors import SHED_LATE
 
 from .admission import AdmissionConfig, AdmissionController
@@ -140,7 +150,8 @@ class LifecycleDispatch:
         events = self.mgr.tick()
         if events and self.on_events is not None:
             self.on_events(events)
-        dev = jax.device_put(jnp.asarray(keys_u32, dtype=jnp.uint32))
+        with span("upload"):
+            dev = jax.device_put(jnp.asarray(keys_u32, dtype=jnp.uint32))
         return _RouteHandle(self.mgr.route_keys(dev))
 
 
@@ -245,11 +256,6 @@ class MicroBatcher:
         self.admission.admit(
             request.tenant, request.deadline_us, now, self.dispatch_eta_us(now)
         )
-        if self.tracer is not None:
-            self.tracer.record(
-                "admit", now, now, tenant=request.tenant,
-                deadline_us=request.deadline_us,
-            )
         if not self._open:
             self._open_since_us = now
         self._open.append(request)
@@ -297,18 +303,25 @@ class MicroBatcher:
         start = max(now_us, self._last_done_us)
         cfg = self.config
         keep: list[StreamRequest] = []
-        for req in batch:
-            # second gate: serve only if the PREDICTED overshoot is within
-            # one batch window — everything else is shed typed, not served
-            # late (this is what bounds the deadline-miss invariant)
-            if start + cfg.service_bound_us <= req.deadline_us + cfg.max_wait_us:
-                keep.append(req)
-            else:
-                self.admission.record_late_shed(req.tenant, SHED_LATE)
+        wait_us_sum = 0
+        with span(SPAN_DISPATCH, self.tracer, self.clock.now_us) as s:
+            for req in batch:
+                # second gate: serve only if the PREDICTED overshoot is within
+                # one batch window — everything else is shed typed, not served
+                # late (this is what bounds the deadline-miss invariant)
+                if start + cfg.service_bound_us <= req.deadline_us + cfg.max_wait_us:
+                    keep.append(req)
+                    wait_us_sum += start - req.arrival_us
+                else:
+                    self.admission.record_late_shed(req.tenant, SHED_LATE)
+            if keep:
+                keys = np.asarray([r.key for r in keep], dtype=np.uint32)
+                handle = self.dispatch_fn(keys)
+            if s:
+                s.tag(size=len(keep), shed=len(batch) - len(keep),
+                      bound_us=cfg.service_bound_us, wait_us_sum=wait_us_sum)
         if not keep:
             return
-        keys = np.asarray([r.key for r in keep], dtype=np.uint32)
-        handle = self.dispatch_fn(keys)
         self._dispatched.inc()
         self._batch_sizes.observe(len(keep))
         bound = (
@@ -316,30 +329,24 @@ class MicroBatcher:
             if self.service_model is not None
             else cfg.service_bound_us
         )
-        if self.tracer is not None:
-            self.tracer.record(
-                "batch_close", start, start, size=len(keep),
-                shed=len(batch) - len(keep),
-            )
-            self.tracer.record(
-                "dispatch", start, start + int(bound), size=len(keep)
-            )
         self._inflight = _Inflight(keep, handle, start, start + int(bound))
 
     def _collect(self) -> None:
         inf, self._inflight = self._inflight, None
-        t0 = time.perf_counter_ns()
-        replicas, epoch, mode = inf.handle.result()
-        measured_us = max(1, (time.perf_counter_ns() - t0) // 1_000)
+        t0 = self.clock.now_us()
+        with span(SPAN_COLLECT, self.tracer, self.clock.now_us):
+            replicas, epoch, mode = inf.handle.result()
         if self.service_model is not None:
             # the model was sampled ONCE at dispatch (stateful models — e.g.
             # spike windows — must see exactly one draw per dispatch)
             service_us = inf.eta_us - inf.t_dispatch_us
             done = inf.t_dispatch_us + int(service_us)
         else:
-            # wall mode: completion is simply "now, after the block"
-            service_us = int(measured_us)
-            done = max(self.clock.now_us(), inf.t_dispatch_us + 1)
+            # wall mode: completion is simply "now, after the block", and
+            # the service time is the block, on the batcher's clock
+            now = self.clock.now_us()
+            service_us = max(1, now - t0)
+            done = max(now, inf.t_dispatch_us + 1)
         self._last_done_us = done
         self.service_ewma_us += 0.1 * (float(service_us) - self.service_ewma_us)
         self.metrics.gauge("stream_service_ewma_us").set(self.service_ewma_us)
@@ -359,7 +366,7 @@ class MicroBatcher:
             ).observe(max(0, done - req.arrival_us))
             if self.tracer is not None:
                 self.tracer.record(
-                    "request", req.arrival_us, done, tenant=req.tenant,
+                    SPAN_REQUEST, req.arrival_us, done, tenant=req.tenant,
                     replica=int(rep), epoch=epoch,
                 )
         self._served.inc(len(inf.requests))

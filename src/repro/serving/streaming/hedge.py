@@ -28,6 +28,7 @@ from collections import deque
 
 import numpy as np
 
+from repro.observability.trace import span
 from repro.serving.lifecycle.detector import REMOVED, SUSPECT
 
 
@@ -80,28 +81,31 @@ class BreakerBoard:
     def observe(self) -> None:
         """Snapshot detector states; record alive→suspect flips and trip
         breakers that crossed the threshold.  Call once per pump/dispatch —
-        the same cadence the detector itself is polled on."""
-        now = self.clock.now_us()
-        cfg = self.config
-        for slot in self.detector.slots:
-            state = self.detector.state_of(slot)
-            prev = self._last_state.get(slot)
-            if state == SUSPECT and prev != SUSPECT:
-                dq = self._suspect_at.setdefault(slot, deque())
-                dq.append(now)
-                while dq and now - dq[0] > cfg.window_us:
-                    dq.popleft()
-                if len(dq) >= cfg.trip_after and not self.is_open(slot):
-                    self._open_until[slot] = now + cfg.cooldown_us
-                    self.metrics.counter(
-                        "stream_breaker_trips_total", shard=str(slot)
-                    ).inc()
-            elif state == REMOVED:
-                # the detector formally failed it: membership takes over,
-                # the breaker's flap history is moot
-                self._suspect_at.pop(slot, None)
-                self._open_until.pop(slot, None)
-            self._last_state[slot] = state
+        the same cadence the detector itself is polled on.  The loop is
+        timed by a profiler span only: it runs every pump, and would flood
+        a ring."""
+        with span("breakers.observe"):
+            now = self.clock.now_us()
+            cfg = self.config
+            for slot in self.detector.slots:
+                state = self.detector.state_of(slot)
+                prev = self._last_state.get(slot)
+                if state == SUSPECT and prev != SUSPECT:
+                    dq = self._suspect_at.setdefault(slot, deque())
+                    dq.append(now)
+                    while dq and now - dq[0] > cfg.window_us:
+                        dq.popleft()
+                    if len(dq) >= cfg.trip_after and not self.is_open(slot):
+                        self._open_until[slot] = now + cfg.cooldown_us
+                        self.metrics.counter(
+                            "stream_breaker_trips_total", shard=str(slot)
+                        ).inc()
+                elif state == REMOVED:
+                    # the detector formally failed it: membership takes over,
+                    # the breaker's flap history is moot
+                    self._suspect_at.pop(slot, None)
+                    self._open_until.pop(slot, None)
+                self._last_state[slot] = state
 
     def is_open(self, slot: int) -> bool:
         until = self._open_until.get(int(slot))
