@@ -9,7 +9,9 @@ extras): hand ``make_fused_kernels`` an unrolled jnp lookup body
 u32/f32 elementwise ops only, n <= 1 handled) and it returns the full
 kernel set —
 
-* ``route_2d`` / ``route_pallas``   — fused lookup + divert, pre-hashed keys;
+* ``route_2d`` / ``route_pallas``   — fused lookup + divert, pre-hashed keys
+  (``route_2d`` takes keys of whole tiles in any shape and does the layout
+  in and out inside its one executable);
 * ``ingest_2d`` / ``ingest_pallas`` — the u64-id ingest twins (limb-wise
   splitmix64 mixed in-register, then the same body);
 * ``lookup_dyn_2d`` / ``lookup_dyn_pallas`` — the plain dynamic-n bulk
@@ -120,6 +122,23 @@ def _check_2d(rows: int, lanes: int, block_rows: int) -> None:
         )
 
 
+def _tile_rows(shape: tuple[int, ...], block_rows: int) -> int:
+    """Rows of the ``(rows, 128)`` view of keys shaped ``shape``.
+
+    A ``(rows, 128)`` shape is checked as ``_check_2d`` checks it; any other
+    shape must hold a whole number of ``block_rows * 128`` tiles.
+    """
+    if len(shape) == 2 and shape[1] == LANES:
+        _check_2d(shape[0], LANES, block_rows)
+        return shape[0]
+    size, tile = int(np.prod(shape)), block_rows * LANES
+    if size % tile:
+        raise ValueError(
+            f"key count ({size}) must be a multiple of block_rows * {LANES} ({tile})"
+        )
+    return size // LANES
+
+
 def _check_state_extents(packed_mask, table, n_words: int, n_slots: int) -> None:
     if not 1 <= n_words <= packed_mask.shape[1]:
         raise ValueError(f"n_words ({n_words}) must be in [1, {packed_mask.shape[1]}]")
@@ -198,14 +217,21 @@ def make_fused_kernels(lookup, name: str) -> FusedKernels:
         keys, packed_mask, table, state, n_words, n_slots,
         omega=16, block_rows=512, interpret=False,
     ):
-        """(rows, 128) u32 keys + fleet state -> (rows, 128) i32 replica ids."""
-        rows, lanes = keys.shape
-        _check_2d(rows, lanes, block_rows)
+        """Int keys of whole tiles + fleet state -> i32 replica ids, same shape.
+
+        The keys may have any shape and int dtype whose element count is a
+        multiple of ``block_rows * 128``.  The cast to u32, the reshape to
+        ``(rows, 128)`` and the reshape of the result back to the keys'
+        shape run inside this one executable, where the compiler makes them
+        bitcasts: a 1-D buffer and its ``(rows, 128)`` view hold the same
+        bytes in the same order, so the chip runs the kernel and no copy.
+        """
+        rows = _tile_rows(keys.shape, block_rows)
         _check_state_extents(packed_mask, table, n_words, n_slots)
         grid_spec = _route_grid_spec(
             block_rows, packed_mask.shape, table.shape, rows // block_rows
         )
-        return pl.pallas_call(
+        out = pl.pallas_call(
             functools.partial(
                 _kernel_route, omega=omega, n_words=n_words, n_slots=n_slots
             ),
@@ -216,8 +242,9 @@ def make_fused_kernels(lookup, name: str) -> FusedKernels:
             jnp.asarray(state, jnp.uint32).reshape(2),
             packed_mask.astype(jnp.uint32),
             table.astype(jnp.int32),
-            keys.astype(jnp.uint32),
+            keys.astype(jnp.uint32).reshape(rows, LANES),
         )
+        return out.reshape(keys.shape)
 
     def route_pallas(
         keys, packed_mask, table, state, n_words, n_slots,
@@ -225,24 +252,35 @@ def make_fused_kernels(lookup, name: str) -> FusedKernels:
     ):
         """Any-shape int keys + fleet state -> i32 replica ids, fused kernel.
 
-        Called eagerly, the layout in and out are executables of their own,
-        each under a ``route.layout`` span, and the kernel's enqueue is the
-        ``route.launch`` span; traced (inside a jit or a shard_map) it opens
-        no span, since a span there would time the tracing.
+        The keys' element count decides the path.  Aligned, a multiple of
+        the ``block_rows * 128`` tile (every 2^20-key batch), the keys go to
+        ``route_2d`` as given: one executable, opened as one
+        ``route.launch`` span, with no layout span.  Ragged (the served
+        path's few keys), an eager pad to whole tiles runs before it and an
+        eager slice after it, each under a ``route.layout`` span.  The pad
+        and slice stay outside the jit on purpose: inside it, every distinct
+        ragged length would lower the Pallas kernel anew, where outside it
+        only each padded row count does.  Traced (inside a jit or a
+        shard_map) the same rule holds and no span opens, since a span
+        there would time the tracing.
         """
         eager = not isinstance(keys, jax.core.Tracer)
-        with _host_span("route.layout", eager):
-            flat, total = _pad_flat(keys.reshape(-1).astype(jnp.uint32), block_rows)
-            flat = flat.reshape(-1, LANES)
+        shape, total = keys.shape, keys.size
+        ragged = total % (block_rows * LANES) != 0
+        if ragged:
+            with _host_span("route.layout", eager):
+                keys, _ = _pad_flat(keys.reshape(-1).astype(jnp.uint32), block_rows)
         with _host_span("route.launch", eager) as s:
             if s:
-                s.tag(rows=flat.shape[0], block_rows=block_rows)
+                s.tag(rows=keys.size // LANES, block_rows=block_rows)
             out = route_2d(
-                flat, packed_mask, table, state, n_words,
+                keys, packed_mask, table, state, n_words,
                 n_slots, omega=omega, block_rows=block_rows, interpret=interpret,
             )
+        if not ragged:
+            return out
         with _host_span("route.layout", eager):
-            return out.reshape(-1)[:total].reshape(keys.shape)
+            return out[:total].reshape(shape)
 
     @functools.partial(
         jax.jit,

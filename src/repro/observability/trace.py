@@ -21,8 +21,8 @@ profiler, R the ring):
 
 * ``route.call`` (P): ``BatchRouter.route_keys``, ``StorePlacement.place_keys``;
 * ``route.layout`` (P): each eager executable around the route program
-  (reshape in and out; in the sharded route the pad, the upload, the
-  donation copy and the output slice);
+  (the pad and the slice of a ragged batch; in the sharded route the pad,
+  the upload, the donation copy and the output slice);
 * ``route.launch`` (P): the call that enqueues the route program, tagged
   ``rows`` and ``block_rows``;
 * ``dispatch`` (R, P): ``MicroBatcher._close``, from the gate to the

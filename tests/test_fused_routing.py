@@ -236,6 +236,94 @@ def test_route_keys_is_exactly_one_dispatch_per_batch(monkeypatch):
     assert binomial_route_fused_2d._cache_size() == before  # zero retraces
 
 
+class _RecordedSpan:
+    """Stands in for a span of ``repro.kernels.fused``: keeps its name and
+    tags in ``opened``, in the order the spans open."""
+
+    def __init__(self, name, opened):
+        self.name, self.tags = name, {}
+        opened.append(self)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def tag(self, **tags):
+        self.tags.update(tags)
+
+
+# block_rows=8: the tile is 1,024 keys, so 4,096 keys are aligned and 4,000
+# are ragged
+ALIGNED_1D, ALIGNED_2D, RAGGED = (4096,), (32, 128), (4000,)
+
+
+@pytest.mark.parametrize(
+    "shape,spans",
+    [
+        (ALIGNED_1D, ["route.launch"]),
+        (ALIGNED_2D, ["route.launch"]),
+        (RAGGED, ["route.layout", "route.launch", "route.layout"]),
+    ],
+)
+def test_route_pallas_spans_follow_alignment(monkeypatch, shape, spans):
+    """Aligned keys go to ``route_2d`` as given, one ``route.launch`` and no
+    layout span; ragged keys open ``route.layout`` around the pad and the
+    slice."""
+    from repro.kernels import fused
+
+    router = BatchRouter(8, interpret=True, block_rows=8)
+    router.fail(2)
+    keys = jnp.asarray(RNG.integers(0, 2**32, size=shape, dtype=np.uint32))
+    opened: list[_RecordedSpan] = []
+    monkeypatch.setattr(fused, "span", lambda name: _RecordedSpan(name, opened))
+    out = router.route_keys(keys)
+    assert out.shape == keys.shape and out.dtype == jnp.int32
+    assert [s.name for s in opened] == spans
+    (launch,) = [s for s in opened if s.name == "route.launch"]
+    assert launch.tags == {"rows": -(-keys.size // 1024) * 8, "block_rows": 8}
+
+
+@pytest.mark.parametrize("shape", [ALIGNED_1D, ALIGNED_2D, RAGGED])
+def test_route_pallas_tracks_oracle_without_retrace(shape):
+    """Aligned (1-D and (rows, 128)) and ragged batches stay bit-exact with
+    the scalar table-mode router across the event stream, and ``route_2d``
+    compiles once per shape, never per event."""
+    router = BatchRouter(8, interpret=True, block_rows=8)
+    oracle = _oracle(8)
+    keys_np = RNG.integers(0, 2**32, size=shape, dtype=np.uint32)
+    keys = jnp.asarray(keys_np)
+    router.route_keys(keys)  # compile once
+    before = binomial_route_fused_2d._cache_size()
+    for ev, arg in EVENTS:
+        args = () if arg is None else (arg,)
+        getattr(router, ev)(*args), getattr(oracle, ev)(*args)
+        out = np.asarray(router.route_keys(keys))
+        assert out.shape == shape
+        expect = [oracle.domain.locate(int(x)) for x in keys_np.reshape(-1)]
+        np.testing.assert_array_equal(out.reshape(-1), expect)
+    assert binomial_route_fused_2d._cache_size() == before  # zero retraces
+
+
+def test_route_2d_takes_flat_keys_in_its_own_program():
+    """A 1-D aligned batch lowers to the one program the chip names
+    ``jit_route_2d``; a key count of partial tiles is refused there."""
+    router = BatchRouter(8, interpret=True, block_rows=8)
+    fleet = router._fleet_dev
+    args = (fleet.packed, fleet.table, fleet.state, router.spec.n_words,
+            router.spec.n_slots)
+    keys = jnp.zeros(ALIGNED_1D, jnp.uint32)
+    text = binomial_route_fused_2d.lower(
+        keys, *args, block_rows=8, interpret=True).as_text()
+    assert text.startswith("module @jit_route_2d ")
+    with pytest.raises(ValueError, match=r"key count \(4000\) must be a multiple"):
+        binomial_route_fused_2d(keys[:4000], *args, block_rows=8, interpret=True)
+    with pytest.raises(ValueError, match=r"rows \(31\) must be a multiple"):
+        binomial_route_fused_2d(
+            keys.reshape(-1, 128)[:31], *args, block_rows=8, interpret=True)
+
+
 def test_route_keys_zero_per_batch_state_uploads():
     """Device fleet state is pinned at event time; route_keys re-uses the
     same buffers — no per-batch host->device rebuild/upload."""

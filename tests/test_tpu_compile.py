@@ -13,6 +13,7 @@ one process at a time may load the TPU library, and pytest-xdist workers
 import every test file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,10 +23,18 @@ from jax.sharding import SingleDeviceSharding
 from repro.core.memento_jax import mask_words
 from repro.core.registry import BULK_ENGINES
 from repro.kernels.autotune import CANDIDATES
+from repro.kernels.binomial_hash import binomial_route_fused_2d
 from repro.kernels.fused import LANES
+from repro.kernels.jump_hash import jump_route_fused_2d
 
 CAPACITY = 1024
 KEYS = 1 << 20
+
+#: the jitted route program each engine's eager ``route_pallas`` enqueues
+ROUTE_2D = {"binomial": binomial_route_fused_2d, "jump": jump_route_fused_2d}
+
+#: a layout copy of the keys or of the result: ``u32[rows,128]`` or ``s32[N]``
+LAYOUT_COPY = re.compile(r"= (u32\[\d+,128\]|s32\[\d+\])\S* copy\(")
 
 
 @pytest.fixture(scope="module")
@@ -56,14 +65,18 @@ def _shape(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _compiled_text(sharding, engine: str, kernel: str, block_rows: int) -> str:
-    eng = BULK_ENGINES[engine]
-    keys = _shape(sharding, (KEYS,), jnp.uint32)
-    fleet = (
+def _fleet(sharding):
+    return (
         _shape(sharding, (1, mask_words(CAPACITY)), jnp.uint32),
         _shape(sharding, (1, CAPACITY), jnp.int32),
         _shape(sharding, (2,), jnp.uint32),
     )
+
+
+def _compiled_text(sharding, engine: str, kernel: str, block_rows: int) -> str:
+    eng = BULK_ENGINES[engine]
+    keys = _shape(sharding, (KEYS,), jnp.uint32)
+    fleet = _fleet(sharding)
     extents = (mask_words(CAPACITY), CAPACITY)
     if kernel == "route":
         fn = lambda k, *f: eng.route_pallas(  # noqa: E731
@@ -87,6 +100,19 @@ def test_kernel_compiles_for_v5e(one_chip, engine, kernel):
     assert "tpu_custom_call" in _compiled_text(
         one_chip, engine, kernel, min(CANDIDATES)
     )
+
+
+@pytest.mark.parametrize("engine", sorted(ROUTE_2D))
+def test_aligned_route_is_the_kernel_alone_on_v5e(one_chip, engine):
+    """An aligned eager ``route_pallas`` enqueues ``route_2d`` on the flat
+    keys as given; its layout in and out compile to bitcasts, so the program
+    is the one kernel and no copy of the keys or the result."""
+    text = ROUTE_2D[engine].lower(
+        _shape(one_chip, (KEYS,), jnp.uint32), *_fleet(one_chip),
+        mask_words(CAPACITY), CAPACITY, block_rows=min(CANDIDATES),
+    ).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert LAYOUT_COPY.search(text) is None, LAYOUT_COPY.search(text).group(0)
 
 
 def test_static_binomial_lookup_compiles_for_v5e(one_chip):
