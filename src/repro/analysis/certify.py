@@ -541,8 +541,14 @@ PLACEMENT_CONTRACT = EngineContract(omega=3)
 PLACEMENT_TRACE_MAX_RESALT = 4
 
 
+#: zone count of the zoned placement trace: the deployments' three
+#: availability zones (the zone-free pass is the ``zones=1`` trace)
+PLACEMENT_TRACE_ZONES = 3
+
+
 def certify_placement_route(
-    engine_name: str, contract: Optional[EngineContract] = None
+    engine_name: str, contract: Optional[EngineContract] = None,
+    zones: int = 1,
 ) -> TargetReport:
     """Certify the R-way replicated placement pass (DESIGN.md §13).
 
@@ -555,27 +561,38 @@ def certify_placement_route(
     same resolution op count; the broadcast route call is shape-independent
     in eqn count), u32-closed, zero transfers — the O(1)-per-replica
     contract, machine-checked like every other engine path.
+
+    With ``zones > 1`` the zone fallback (§13.5) is traced too, over
+    representative ``ZoneState`` operands, as
+    ``placement/route_replicas/zoned``: each column's zone step is a fixed
+    O(zones) select walk plus one zone-table gather, so R-affinity holds.
     """
     contract = contract or PLACEMENT_CONTRACT
-    from repro.core.memento_jax import mask_words
+    from repro.core.bulk import zone_width
+    from repro.core.memento_jax import mask_words, table_width
     from repro.core.registry import make_bulk
     from repro.placement.store import route_replicas_impl
 
     eng = make_bulk(engine_name)
     keys, packed, table, state = _fleet_operands(contract)
     n_words = mask_words(contract.capacity)
+    width = zone_width(contract.capacity, zones)
+    zone_ops = () if zones == 1 else (
+        np.zeros((1, table_width(zones * width)), np.int32),
+        np.ones((2, zones), np.uint32),
+    )
 
     def tracer(r):
         return jax.make_jaxpr(
-            lambda k, p, t, s: route_replicas_impl(
-                k, p, t, s, r=r, omega=16, n_words=n_words,
+            lambda k, p, t, s, *z: route_replicas_impl(
+                k, p, t, s, *z, r=r, omega=16, n_words=n_words,
                 max_resalt=PLACEMENT_TRACE_MAX_RESALT, route=eng.route,
+                zones=zones, zone_width=width,
             )
-        )(keys, packed, table, state)
+        )(keys, packed, table, state, *zone_ops)
 
-    return certify_callable(
-        engine_name, "placement/route_replicas", tracer, contract=contract
-    )
+    label = "placement/route_replicas" + ("" if zones == 1 else "/zoned")
+    return certify_callable(engine_name, label, tracer, contract=contract)
 
 
 def certify_load_pass(
@@ -631,6 +648,9 @@ def certify_all(
         report.targets.extend(certify_engine(name))
         report.targets.append(certify_lifecycle_route(name))
         report.targets.append(certify_placement_route(name))
+        report.targets.append(
+            certify_placement_route(name, zones=PLACEMENT_TRACE_ZONES)
+        )
         report.targets.append(certify_streaming_route(name))
         report.targets.append(certify_load_pass(name))
     if include_chain_baseline:

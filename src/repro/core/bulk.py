@@ -36,7 +36,12 @@ from typing import Any, Callable
 import jax
 import numpy as np
 
-from repro.core.memento_jax import mask_words, pack_removed_mask, pack_table
+from repro.core.memento_jax import (
+    mask_words,
+    pack_removed_mask,
+    pack_table,
+    table_width,
+)
 
 #: default block tiling of the fused kernels (rows of 128 lanes per grid
 #: step) — the one definition; ``repro.kernels.autotune`` re-exports it
@@ -45,6 +50,9 @@ DEFAULT_BLOCK_ROWS = 512
 #: engines that step through f32 arithmetic (jump) need b+1 exact in a
 #: float32 mantissa, so the slot space is bounded well below u32
 MAX_CAPACITY = 1 << 24
+
+#: zones a placement may span: a key's used-zone mask is one u32 word
+MAX_ZONES = 32
 
 
 @dataclasses.dataclass(frozen=True)
@@ -205,6 +213,56 @@ jax.tree_util.register_pytree_node(
 )
 
 
+def zone_width(capacity: int, zones: int) -> int:
+    """Entries a zone holds in the zone table: its slot count can reach
+    ``ceil(capacity / zones)`` (slot ``s`` lies in zone ``s mod zones``)."""
+    return -(-capacity // zones)
+
+
+@dataclasses.dataclass
+class ZoneState:
+    """The zone fallback's device operands — a registered jax pytree, read
+    by the placement pass only (DESIGN.md §13.5).
+
+    table  (1, W) int32: zone ``z``'s ``ReplacementTable`` permutation, as
+           global slot ids with the alive prefix first, at entries
+           ``[z * width, z * width + total_z)``; ``width`` is
+           ``zone_width(capacity, zones)``, and ``W`` that times ``zones``
+           rounded up to whole lanes
+    state  (2, Z) uint32: each zone's slot count, then its alive count
+
+    Shapes are fixed by the capacity and the zone count, so no fleet event
+    retraces the pass; the router's ``FleetState`` is left as it is.
+    """
+
+    table: Any
+    state: Any
+
+    @classmethod
+    def pack(cls, zone_tables, capacity: int) -> "ZoneState":
+        """Host-side pack of a ``ZoneTables`` truth."""
+        zones = zone_tables.zones
+        width = zone_width(capacity, zones)
+        table = np.zeros((1, table_width(zones * width)), np.int32)
+        for z, t in enumerate(zone_tables.tables):
+            table[0, z * width : z * width + t.n_total] = (
+                np.asarray(t.slots, np.int64) * zones + z
+            )
+        state = np.array(
+            [[t.n_total for t in zone_tables.tables],
+             [t.n_alive for t in zone_tables.tables]],
+            dtype=np.uint32,
+        )
+        return cls(table, state)
+
+
+jax.tree_util.register_pytree_node(
+    ZoneState,
+    lambda z: ((z.table, z.state), None),
+    lambda _, children: ZoneState(*children),
+)
+
+
 @dataclasses.dataclass(frozen=True)
 class PlacementSpec:
     """Frozen configuration of one R-way replicated placement tier.
@@ -221,6 +279,10 @@ class PlacementSpec:
                 most ``j`` of which are taken).  Smaller explicit bounds are
                 allowed for experiments — exhaustion then surfaces as a
                 typed ``PlacementExhaustedError``, never a silent duplicate.
+    zones       failure domains the slot space is split into (slot ``s`` in
+                zone ``s mod zones``): a key's holders lie in
+                ``min(r, alive zones)`` distinct zones (DESIGN.md §13.5).
+                1, the default, is the zone-free pass.
 
     Hashable (it keys jit caches); validated at construction.
     """
@@ -228,6 +290,7 @@ class PlacementSpec:
     router: RouterSpec = dataclasses.field(default_factory=RouterSpec)
     r: int = 3
     max_resalt: int | None = None
+    zones: int = 1
 
     def __post_init__(self):
         if self.r < 1:
@@ -242,6 +305,17 @@ class PlacementSpec:
                 f"max_resalt must be >= 0, got {self.max_resalt}; pass None "
                 "for the distinctness-guaranteeing default"
             )
+        if not 1 <= self.zones <= min(MAX_ZONES, self.router.capacity):
+            raise ValueError(
+                f"zones must be in [1, {min(MAX_ZONES, self.router.capacity)}] "
+                f"(got {self.zones}): a key's used-zone mask is one u32 word, "
+                "and a zone needs a slot"
+            )
+
+    @property
+    def zone_width(self) -> int:
+        """Static zone-table entries per zone."""
+        return zone_width(self.router.capacity, self.zones)
 
     @property
     def resolved_max_resalt(self) -> int:

@@ -148,6 +148,62 @@ class ReplacementTable:
         return self.slots[q]
 
 
+class ZoneTables:
+    """One ``ReplacementTable`` per zone of the slot space (DESIGN.md §13.5).
+
+    Slot ``s`` lies in zone ``s mod zones``, at local index ``s // zones``
+    of that zone's table: the layout that growth by one node per zone in
+    turn gives, which keeps the zones equal in size.  Each zone's table is
+    the same swap-to-the-boundary permutation as the fleet's own, over the
+    zone's local indices, so every event costs one O(1) swap in one zone,
+    and LIFO growth and shrink stay LIFO inside each zone.  Each method
+    takes the event's global slot id.
+    """
+
+    def __init__(self, zones: int, n: int):
+        self.zones = zones
+        self.tables = [ReplacementTable(len(range(z, n, zones))) for z in range(zones)]
+
+    def _local(self, slot: int) -> tuple[ReplacementTable, int]:
+        return self.tables[slot % self.zones], slot // self.zones
+
+    def fail(self, slot: int) -> None:
+        table, i = self._local(slot)
+        table.fail(i)
+
+    def recover(self, slot: int) -> None:
+        table, i = self._local(slot)
+        table.recover(i)
+
+    def append(self, slot: int) -> None:
+        table, i = self._local(slot)
+        if table.append() != i:
+            raise ValueError(f"slot {slot} is not the next slot of its zone")
+
+    def pop_last(self, slot: int) -> None:
+        table, i = self._local(slot)
+        if table.pop_last() != i:
+            raise ValueError(f"slot {slot} is not the last slot of its zone")
+
+    @property
+    def alive_zones(self) -> int:
+        """Zones with at least one alive slot."""
+        return sum(1 for t in self.tables if t.n_alive)
+
+    def capture(self) -> tuple:
+        """Each zone's ``(slots, pos, n_alive)``: what a snapshot holds."""
+        return tuple((tuple(t.slots), tuple(t.pos), t.n_alive) for t in self.tables)
+
+    def install(self, captured) -> None:
+        """Set every zone's table to a ``capture()``."""
+        if len(captured) != self.zones:
+            raise ValueError(
+                f"{len(captured)} captured zone tables for {self.zones} zones"
+            )
+        for t, (slots, pos, n_alive) in zip(self.tables, captured):
+            t.slots, t.pos, t.n_alive = list(slots), list(pos), n_alive
+
+
 class MementoWrapper:
     name = "memento"
     exact = False  # reconstruction of the published description
@@ -160,6 +216,7 @@ class MementoWrapper:
         chain_bits: int = 64,
         resolve: str = "chain",
         allow_empty: bool = False,
+        zones: int = 1,
     ):
         """``base_factory(n) -> engine`` builds the underlying LIFO engine.
 
@@ -175,7 +232,16 @@ class MementoWrapper:
         The serving tier uses this to answer routes on an all-failed fleet
         with a typed ``FleetUnavailableError`` rather than refusing the
         failure event itself, which no real outage asks permission for.
+
+        ``zones > 1`` (table mode only) splits the slot space into that many
+        zones, slot ``s`` in zone ``s mod zones``, and keeps their
+        ``ZoneTables`` from genesis (DESIGN.md §13.5).
         """
+        if zones < 1 or (zones > 1 and resolve != "table"):
+            raise ValueError(
+                f"zones must be >= 1, and > 1 only with resolve='table'; got "
+                f"{zones} with resolve={resolve!r}"
+            )
         if chain_bits not in (32, 64):
             raise ValueError(f"chain_bits must be 32 or 64, got {chain_bits}")
         if resolve not in ("chain", "table"):
@@ -188,6 +254,15 @@ class MementoWrapper:
         self.resolve = resolve
         self.allow_empty = allow_empty
         self.table = ReplacementTable(n) if resolve == "table" else None
+        #: the per-zone tables of a zoned slot space, kept from genesis in
+        #: step with the table (one O(1) swap per event), so they are a pure
+        #: function of the event stream like the table itself; None without
+        #: zones
+        self.zone_tables = ZoneTables(zones, n) if zones > 1 else None
+
+    def _zone_event(self, op: str, slot: int) -> None:
+        if self.zone_tables is not None:
+            getattr(self.zone_tables, op)(slot)
 
     # -- size/state ---------------------------------------------------------
     @property
@@ -207,6 +282,7 @@ class MementoWrapper:
         out = self.base.add_bucket()
         if self.table is not None:
             self.table.append()
+            self._zone_event("append", out)
         return out
 
     def remove_bucket(self, b: int | None = None) -> int:
@@ -225,6 +301,7 @@ class MementoWrapper:
             self.removed.add(last)
             if self.table is not None:
                 self.table.fail(last)
+                self._zone_event("fail", last)
             return last
         if b is None or b == self.n_total - 1:
             # true LIFO removal — shrink the base engine; also garbage-collect
@@ -233,17 +310,21 @@ class MementoWrapper:
             self.removed.discard(out)
             if self.table is not None:
                 self.table.pop_last()
+                self._zone_event("pop_last", out)
             while self.n_total - 1 in self.removed and self.n_total > 1:
-                self.removed.discard(self.n_total - 1)
+                gone = self.n_total - 1
+                self.removed.discard(gone)
                 self.base.remove_bucket()
                 if self.table is not None:
                     self.table.pop_last()
+                    self._zone_event("pop_last", gone)
             return out
         if b in self.removed or not (0 <= b < self.n_total):
             raise ValueError(f"bucket {b} is not alive")
         self.removed.add(b)
         if self.table is not None:
             self.table.fail(b)
+            self._zone_event("fail", b)
         return b
 
     def restore_bucket(self, b: int) -> None:
@@ -253,6 +334,7 @@ class MementoWrapper:
         self.removed.discard(b)
         if self.table is not None:
             self.table.recover(b)
+            self._zone_event("recover", b)
 
     # -- lookup -------------------------------------------------------------
     def _chain_step(self, key: int, b: int, i: int, total: int) -> int:

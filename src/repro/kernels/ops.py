@@ -29,6 +29,7 @@ from __future__ import annotations
 import warnings
 
 import jax
+import numpy as np
 
 from repro.core.binomial_jax import binomial_lookup_dyn
 from repro.core.bulk import FleetState, RouterSpec
@@ -172,7 +173,8 @@ def route_ingest_bulk(
     )
 
 
-def route_replicas_bulk(keys: jax.Array, fleet: FleetState, pspec) -> tuple:
+def route_replicas_bulk(keys: jax.Array, fleet: FleetState, pspec,
+                        zone=None, counts=None) -> tuple:
     """R-way replicated placement: keys + fleet state -> ``(replicas (N, r)
     i32 distinct alive shards, exhausted (N,) bool)``, ONE dispatch.
 
@@ -184,39 +186,51 @@ def route_replicas_bulk(keys: jax.Array, fleet: FleetState, pspec) -> tuple:
     elementwise + gathers — XLA fuses it; no Pallas twin).
 
     keys   any int shape (u32 key space); fleet  ``FleetState``;
-    pspec  ``PlacementSpec`` — replication r, probe bound, the RouterSpec
+    pspec  ``PlacementSpec`` — replication r, probe bound, zones, the
+    RouterSpec.  With ``pspec.zones > 1``: ``zone`` is the ``ZoneState``,
+    ``counts`` the ``(2, 2)`` u32 fallback accumulator (low words, then
+    high words; zeros when None), and the same dispatch returns it advanced
+    as a third output (§13.5).
     """
     from repro.placement.store import _route_replicas_jit  # late: placement
     # imports this module
 
     spec = pspec.router
     eng = _engine(spec)
+    static = dict(r=pspec.r, omega=spec.omega, n_words=spec.n_words,
+                  max_resalt=pspec.resolved_max_resalt, route=eng.route)
+    if pspec.zones == 1:
+        return _route_replicas_jit(
+            keys, fleet.packed, fleet.table, fleet.state, **static
+        )
+    if counts is None:
+        counts = np.zeros((2, 2), np.uint32)
     return _route_replicas_jit(
-        keys, fleet.packed, fleet.table, fleet.state,
-        r=pspec.r, omega=spec.omega, n_words=spec.n_words,
-        max_resalt=pspec.resolved_max_resalt, route=eng.route,
+        keys, fleet.packed, fleet.table, fleet.state, zone, counts,
+        zones=pspec.zones, zone_width=pspec.zone_width, **static,
     )
 
 
 def placement_diff_bulk(
-    keys: jax.Array, fleet_old: FleetState, fleet_new: FleetState, pspec
+    keys: jax.Array, fleet_old: FleetState, fleet_new: FleetState, pspec,
+    zone_old=None, zone_new=None,
 ) -> tuple:
     """Bulk migration diff: both placements + the transfer mask in ONE
     dispatch — ``(old (N, r), new (N, r), moved (N, r) bool, exhausted)``
     with ``moved[i, j] = new[i, j] not in old[i, :]`` (membership, not
     positional inequality: a column swap is free, only a shard with no
-    prior copy needs bytes).  Operand contract as ``route_replicas_bulk``.
+    prior copy needs bytes).  Operand contract as ``route_replicas_bulk``;
+    with ``pspec.zones > 1`` both sides' ``ZoneState`` come too.
     """
     from repro.placement.store import _placement_diff_jit
 
     spec = pspec.router
     eng = _engine(spec)
     return _placement_diff_jit(
-        keys,
-        fleet_old.packed, fleet_old.table, fleet_old.state,
-        fleet_new.packed, fleet_new.table, fleet_new.state,
+        keys, fleet_old, fleet_new, zone_old, zone_new,
         r=pspec.r, omega=spec.omega, n_words=spec.n_words,
         max_resalt=pspec.resolved_max_resalt, route=eng.route,
+        zones=pspec.zones, zone_width=pspec.zone_width,
     )
 
 

@@ -125,6 +125,15 @@ class MetricsRegistry:
         self.clock = clock
         self._metrics: dict[tuple, object] = {}
         self._kinds: dict[str, str] = {}
+        #: called before every read: where a count waits on the device
+        #: (the placement pass's zone fallback), it is synced here, when
+        #: someone looks, and never on the hot path
+        self._collectors: list = []
+
+    def add_collector(self, fn) -> None:
+        """Run ``fn()`` before each read of the registry; it brings counts
+        kept elsewhere up to date through the ordinary update calls."""
+        self._collectors.append(fn)
 
     def _get_or_make(self, cls, name: str, labels: dict, **kwargs):
         key = (name, _label_key(labels))
@@ -161,8 +170,13 @@ class MetricsRegistry:
         return h
 
     # -- read side -----------------------------------------------------------
+    def _collect_pending(self) -> None:
+        for fn in self._collectors:
+            fn()
+
     def family(self, name: str) -> dict[tuple, object]:
         """Every series of one family: ``{sorted-label-items: metric}``."""
+        self._collect_pending()
         return {
             key[1]: m for key, m in self._metrics.items() if key[0] == name
         }
@@ -177,4 +191,5 @@ class MetricsRegistry:
 
     def collect(self):
         """Every series, sorted by (name, labels) for stable exposition."""
+        self._collect_pending()
         return [self._metrics[k] for k in sorted(self._metrics)]

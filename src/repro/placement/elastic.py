@@ -66,6 +66,10 @@ class FailureDomain:
     ``resolve="table"`` switches failure resolution from the rejection
     chain to the constant-time replacement table (DESIGN.md §7) — the
     semantics the batched device datapath implements.
+
+    ``zones > 1`` (table mode) splits the slot space into that many zones,
+    slot ``s`` in zone ``s mod zones``, whose tables the domain keeps from
+    genesis for zone-aware placement (DESIGN.md §13.5).
     """
 
     def __init__(
@@ -77,6 +81,7 @@ class FailureDomain:
         max_chain: int = 4096,
         resolve: str = "chain",
         allow_empty: bool = False,
+        zones: int = 1,
     ):
         def factory(m: int):
             eng = make(engine, m)
@@ -93,6 +98,7 @@ class FailureDomain:
             chain_bits=chain_bits,
             resolve=resolve,
             allow_empty=allow_empty,
+            zones=zones,
         )
 
     @property
@@ -118,6 +124,17 @@ class FailureDomain:
         if self._eng.table is None:
             raise ValueError("domain was not constructed with resolve='table'")
         return self._eng.table
+
+    @property
+    def zones(self) -> int:
+        """Zones of the slot space (1: no zones)."""
+        view = self._eng.zone_tables
+        return 1 if view is None else view.zones
+
+    @property
+    def zone_tables(self):
+        """The ``ZoneTables`` kept from genesis (None without zones)."""
+        return self._eng.zone_tables
 
     def locate(self, key: int) -> int:
         return self._eng.get_bucket(key)

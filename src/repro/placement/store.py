@@ -26,6 +26,10 @@ Three layers:
   positions, at most ``j`` of which are taken), so every key gets exactly
   ``min(r, n_alive)`` distinct alive shards.  While-free, affine in ``r``,
   u32-closed, zero transfers — certified as ``placement/route_replicas``.
+  With ``zones > 1`` the same pass first moves a column whose shard lies
+  in a zone an earlier column holds to a free alive zone, resolved over
+  that zone's own replacement table (DESIGN.md §13.5), so a key's holders
+  span ``min(r, alive zones)`` failure domains.
 
 * ``StorePlacement`` — the host control plane: guarded placement with typed
   degradation (``n_alive == 0`` stays ``FleetUnavailableError``;
@@ -51,15 +55,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.binomial_jax import GOLDEN32, mix32, mulhi32
-from repro.core.bulk import FleetState, PlacementSpec, RouterSpec
+from repro.core.binomial_jax import GOLDEN32, hash_pair, mix32, mulhi32
+from repro.core.bulk import FleetState, PlacementSpec, RouterSpec, ZoneState
 from repro.kernels import ops
 from repro.kernels.fused import LANES
+from repro.observability.metrics import MetricsRegistry
 from repro.observability.trace import span
 from repro.placement.assignment import MovementPlan
 from repro.serving.lifecycle.errors import (
     MODE_DEGRADED,
     MODE_NORMAL,
+    MODE_ZONE_DEGRADED,
     FleetUnavailableError,
     PlacementDegradedError,
     PlacementExhaustedError,
@@ -68,6 +74,10 @@ from repro.serving.lifecycle.errors import (
 #: salt seeding the re-salt chain — distinct from every family salt so the
 #: resolution probes decorrelate from the base placements they collide with
 RESALT_SALT = np.uint32(0x7F4A7C15)
+
+#: salt of the hash that picks a column's new zone — distinct from the
+#: family and re-salt salts, so the zone choice decorrelates from both
+ZONE_SALT = np.uint32(0x2C1B3C6D)
 
 #: sentinel holder id: "this replica column holds no copy anywhere"
 NO_HOLDER = -1
@@ -91,13 +101,17 @@ def route_replicas_impl(
     packed_mask: jax.Array,
     table: jax.Array,
     state: jax.Array,
+    zone_table: jax.Array | None = None,
+    zone_state: jax.Array | None = None,
     *,
     r: int,
     omega: int,
     n_words: int,
     max_resalt: int,
     route,
-) -> tuple[jax.Array, jax.Array]:
+    zones: int = 1,
+    zone_width: int = 0,
+) -> tuple:
     """Place every key on ``r`` distinct alive shards — ONE traced pass.
 
     keys         (N,) u32 key space (any int dtype; truncated like the
@@ -105,11 +119,16 @@ def route_replicas_impl(
     packed_mask / table / state — the ``FleetState`` leaves (operand
                  contract of the fused engines; ``n_alive >= 1`` is the
                  caller-guarded precondition, as for ``route_bulk``)
+    zone_table / zone_state — the ``ZoneState`` leaves; read only when
+                 ``zones > 1``
     r            replication factor (static)
     max_resalt   static probe bound per column (``PlacementSpec``
                  resolves ``None`` to ``r``, the distinctness guarantee)
     route        the engine's fused jnp route
                  ``(keys, packed, table, state, omega=, n_words=)``
+    zones        static zone count (slot ``s`` in zone ``s mod zones``);
+                 1 traces the zone-free pass and nothing of the zones
+    zone_width   static zone-table entries per zone (``PlacementSpec``)
 
     Returns ``(replicas, exhausted)``: ``replicas`` is ``(N, r)`` int32,
     every entry an ALIVE shard; column ``j`` is distinct from columns
@@ -118,12 +137,22 @@ def route_replicas_impl(
     fleet is smaller than ``j+1``).  ``exhausted`` is ``(N,)`` bool, set
     for keys where distinctness was achievable (``n_alive > j``) but
     ``max_resalt`` probes ran out — impossible at the default bound.
+    With ``zones > 1`` a third output, ``(2,)`` u32, counts the columns
+    the zone fallback moved and the columns the shard re-salt handled.
+
+    The zone fallback (DESIGN.md §13.5): column ``j >= 1`` whose routed
+    shard lies in a zone an earlier column already uses, while more than
+    ``j`` zones are alive, moves to the k-th free alive zone in zone order
+    (``k = mulhi32(mix32(fam ^ ZONE_SALT), alive_zones - j)``), and to a
+    shard there by the table divert's two redirects over that zone's own
+    table.  With ``j`` or fewer zones alive the column keeps its shard and
+    the re-salt below makes it distinct.
 
     The whole pass is one fused-route call (eqn count independent of
     ``r`` — all families route as one broadcast batch) plus O(r * (n_words
-    + max_resalt)) elementwise resolution ops: while-free and affine in
-    ``r`` at a fixed probe bound, which is exactly what the certifier's
-    ``placement/route_replicas`` target pins.
+    + max_resalt + zones)) elementwise resolution ops: while-free and
+    affine in ``r`` at a fixed probe bound, which is exactly what the
+    certifier's ``placement/route_replicas`` targets pin.
     """
     keys_u32 = keys.reshape(-1).astype(jnp.uint32)
     n_alive = state[1].astype(jnp.uint32)
@@ -152,12 +181,61 @@ def route_replicas_impl(
         for s in range(n_words):
             used[s] = jnp.where(w == np.uint32(s), used[s] | bit, used[s])
 
+    zoned = zones > 1
+    if zoned:
+        z_slots = zone_table.reshape(-1).astype(jnp.uint32)
+        z_total = zone_state[0].astype(jnp.uint32)
+        z_alive = zone_state[1].astype(jnp.uint32)
+        zone_up = [z_alive[z] > np.uint32(0) for z in range(zones)]
+        n_zones_alive = sum(up.astype(jnp.uint32) for up in zone_up)
+        # zone of a slot, s mod zones, by a multiply-high with
+        # ceil(2^32 / zones): exact for every s < 2^32 / zones
+        magic = np.uint32(-(-(1 << 32) // zones))
+
+        def zone_of(b):
+            return b - np.uint32(zones) * mulhi32(b, magic)
+
+        used_zones = jnp.zeros_like(keys_u32)  # bit z: zone z holds a column
+        moved_zone = jnp.uint32(0)
+        moved_shard = jnp.uint32(0)
+
     cols = []
     exhausted = jnp.zeros(keys_u32.shape, bool)
     for j in range(r):
         b = base[:, j]
         if j > 0:
+            if zoned and j < zones:
+                # j columns hold j distinct zones; a free alive zone exists
+                # iff more than j zones are alive
+                free_left = n_zones_alive > np.uint32(j)
+                move = free_left & (((used_zones >> zone_of(b)) & 1) != 0)
+                n_free = jnp.where(free_left, n_zones_alive - np.uint32(j),
+                                   np.uint32(0))
+                k = mulhi32(mix32(fam[:, j] ^ ZONE_SALT), n_free)
+                # the k-th free alive zone in zone order, with its counts
+                target = jnp.zeros_like(b)
+                z_n = jnp.zeros_like(b)
+                z_a = jnp.zeros_like(b)
+                seen = jnp.zeros_like(b)
+                for z in range(zones):
+                    free = zone_up[z] & (((used_zones >> np.uint32(z)) & 1) == 0)
+                    pick = free & (seen == k)
+                    target = jnp.where(pick, np.uint32(z), target)
+                    z_n = jnp.where(pick, z_total[z], z_n)
+                    z_a = jnp.where(pick, z_alive[z], z_a)
+                    seen = seen + free.astype(jnp.uint32)
+                # the table divert's two redirects over that zone's table
+                h = hash_pair(fam[:, j], target)
+                q = mulhi32(h, z_n)
+                q = jnp.where(q >= z_a, mulhi32(mix32(h ^ (q * GOLDEN32)), z_a), q)
+                cand = z_slots.at[target * np.uint32(zone_width) + q].get(
+                    mode="promise_in_bounds"
+                )
+                b = jnp.where(move, cand, b)
+                moved_zone = moved_zone + jnp.sum(move, dtype=jnp.uint32)
             coll = is_used(b)
+            if zoned:
+                moved_shard = moved_shard + jnp.sum(coll, dtype=jnp.uint32)
             # re-salt into the alive-prefix POSITION space (every position
             # < n_alive holds an alive shard by the table's construction),
             # then probe linearly with a conditional-subtract wrap: the
@@ -176,58 +254,76 @@ def route_replicas_impl(
             # (j+1 distinct shards cannot exist), not an exhaustion
             exhausted = exhausted | (coll & (np.uint32(j) < n_alive))
         mark_used(b)
+        if zoned:
+            used_zones = used_zones | (jnp.uint32(1) << zone_of(b))
         cols.append(b)
 
     replicas = jnp.stack(cols, axis=-1).astype(jnp.int32)
-    return replicas.reshape(*keys.shape, r), exhausted.reshape(keys.shape)
+    out = replicas.reshape(*keys.shape, r), exhausted.reshape(keys.shape)
+    if zoned:
+        return *out, jnp.stack([moved_zone, moved_shard])
+    return out
 
 
 @functools.partial(
-    jax.jit, static_argnames=("r", "omega", "n_words", "max_resalt", "route")
+    jax.jit, static_argnames=("r", "omega", "n_words", "max_resalt", "route",
+                              "zones", "zone_width")
 )
-def _route_replicas_jit(keys, packed, table, state, *, r, omega, n_words,
-                        max_resalt, route):
-    return route_replicas_impl(
-        keys, packed, table, state, r=r, omega=omega, n_words=n_words,
-        max_resalt=max_resalt, route=route,
+def _route_replicas_jit(keys, packed, table, state, zone=None, counts=None, *,
+                        r, omega, n_words, max_resalt, route, zones=1,
+                        zone_width=0):
+    if zones == 1:
+        return route_replicas_impl(
+            keys, packed, table, state, r=r, omega=omega, n_words=n_words,
+            max_resalt=max_resalt, route=route,
+        )
+    # the fallback counts ride across calls in a device accumulator of
+    # (low, high) u32 words, the carry taken on the device
+    replicas, exhausted, moved = route_replicas_impl(
+        keys, packed, table, state, zone.table, zone.state, r=r, omega=omega,
+        n_words=n_words, max_resalt=max_resalt, route=route, zones=zones,
+        zone_width=zone_width,
     )
+    lo = counts[0] + moved
+    hi = counts[1] + (lo < moved).astype(jnp.uint32)
+    return replicas, exhausted, jnp.stack([lo, hi])
 
 
 def placement_diff_impl(
-    keys, old_packed, old_table, old_state, new_packed, new_table, new_state,
-    *, r, omega, n_words, max_resalt, route,
+    keys, old_fleet: FleetState, new_fleet: FleetState,
+    old_zone: ZoneState | None = None, new_zone: ZoneState | None = None,
+    *, r, omega, n_words, max_resalt, route, zones=1, zone_width=0,
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Old-vs-new placement diff — the bulk migration plan, ONE traced pass.
 
-    Routes the keys under BOTH fleet states and marks every (key, column)
-    pair whose new shard holds no copy under the old placement:
-    ``moved[i, j] = new[i, j] not in old[i, :]`` — membership, not
-    positional inequality, because a replica that merely swapped columns
-    needs no data transfer.  Returns ``(old, new, moved, exhausted_new)``.
+    Routes the keys under BOTH fleet states (and, with ``zones > 1``, both
+    zone states) and marks every (key, column) pair whose new shard holds
+    no copy under the old placement: ``moved[i, j] = new[i, j] not in
+    old[i, :]`` — membership, not positional inequality, because a replica
+    that merely swapped columns needs no data transfer.  Returns ``(old,
+    new, moved, exhausted_new)``.
     """
-    old, _ = route_replicas_impl(
-        keys, old_packed, old_table, old_state, r=r, omega=omega,
-        n_words=n_words, max_resalt=max_resalt, route=route,
-    )
-    new, exhausted = route_replicas_impl(
-        keys, new_packed, new_table, new_state, r=r, omega=omega,
-        n_words=n_words, max_resalt=max_resalt, route=route,
-    )
+    def place(fleet, zone):
+        zone_ops = () if zones == 1 else (zone.table, zone.state)
+        return route_replicas_impl(
+            keys, fleet.packed, fleet.table, fleet.state, *zone_ops, r=r,
+            omega=omega, n_words=n_words, max_resalt=max_resalt, route=route,
+            zones=zones, zone_width=zone_width,
+        )
+
+    old = place(old_fleet, old_zone)[0]
+    new, exhausted = place(new_fleet, new_zone)[:2]
     moved = jnp.ones(new.shape, bool)
     for k in range(r):
         moved = moved & (new != old[..., k : k + 1])
     return old, new, moved, exhausted
 
 
-@functools.partial(
-    jax.jit, static_argnames=("r", "omega", "n_words", "max_resalt", "route")
+_placement_diff_jit = jax.jit(
+    placement_diff_impl,
+    static_argnames=("r", "omega", "n_words", "max_resalt", "route", "zones",
+                     "zone_width"),
 )
-def _placement_diff_jit(keys, op, ot, os_, np_, nt, ns, *, r, omega, n_words,
-                        max_resalt, route):
-    return placement_diff_impl(
-        keys, op, ot, os_, np_, nt, ns, r=r, omega=omega, n_words=n_words,
-        max_resalt=max_resalt, route=route,
-    )
 
 
 # ---------------------------------------------------------------------------
@@ -242,7 +338,8 @@ class PlacedBatch(NamedTuple):
     replicas: object  #: (N, r) int32 alive shard ids, distinct per row up
     #: to min(r, n_alive)
     epoch: int
-    mode: str  #: MODE_NORMAL, or MODE_DEGRADED when n_alive < r
+    mode: str  #: MODE_NORMAL; MODE_DEGRADED when n_alive < r; or
+    #: MODE_ZONE_DEGRADED when fewer zones are alive than min(r, zones)
     n_distinct: int  #: min(r, n_alive) at placement time
 
 
@@ -315,17 +412,38 @@ class StorePlacement:
     """
 
     def __init__(self, router, r: int = 3, *, max_resalt: int | None = None,
-                 strict: bool = False):
+                 zones: int = 1, strict: bool = False,
+                 metrics: MetricsRegistry | None = None):
         self.router = router
-        self.spec = PlacementSpec(router=router.spec, r=r, max_resalt=max_resalt)
+        self.spec = PlacementSpec(
+            router=router.spec, r=r, max_resalt=max_resalt, zones=zones
+        )
+        if zones > 1 and router.domain.zones != zones:
+            raise ValueError(
+                f"StorePlacement(zones={zones}) needs a fleet that keeps "
+                f"{zones} zones from genesis: build the router with "
+                f"zones={zones} (it keeps {router.domain.zones})"
+            )
         #: strict=True turns an n_alive < r placement into a typed
         #: PlacementDegradedError instead of a degraded-mode batch
         self.strict = strict
         self._keys = np.zeros((0,), np.uint32)
         self._holders = np.zeros((0, r), np.int64)
-        #: fleet snapshot the registered holders were last synced against —
-        #: the implicit "old" side of plan_migration()
-        self._synced_fleet = self._fleet_snapshot()
+        #: the registry the zone-fallback counters reach, synced from the
+        #: device when it is read
+        self.metrics = metrics if metrics is not None else MetricsRegistry()
+        if zones > 1:
+            #: (routing epoch, device ZoneState) last uploaded
+            self._zone_dev: tuple[int, ZoneState] | None = None
+            #: device accumulator of the (zone-fallback, shard-fallback)
+            #: column counts across calls: low words, then high words
+            self._fallbacks = jnp.zeros((2, 2), jnp.uint32)
+            self._drained = (0, 0)
+            self._columns = 0
+            self.metrics.add_collector(self._drain_counts)
+        #: fleet (and zone) snapshot the registered holders were last synced
+        #: against — the implicit "old" side of plan_migration()
+        self._synced = self._snapshot()
 
     # -- fleet state access --------------------------------------------------
     @property
@@ -340,16 +458,50 @@ class StorePlacement:
     def n_alive(self) -> int:
         return self.router.domain.alive_count
 
-    def _fleet_snapshot(self) -> FleetState:
+    @property
+    def alive_zones(self) -> int:
+        """Zones with an alive shard."""
+        return self.router.domain.zone_tables.alive_zones
+
+    def _zone_host(self) -> ZoneState:
+        return ZoneState.pack(
+            self.router.domain.zone_tables, self.router.spec.capacity
+        )
+
+    def _snapshot(self) -> tuple[FleetState, ZoneState | None]:
         h = self.router._fleet_host
-        return FleetState(
+        fleet = FleetState(
             h.packed.copy(), h.table.copy(), h.state.copy(), h.capacity
         )
+        return fleet, (None if self.spec.zones == 1 else self._zone_host())
 
     def _fleet_dev(self) -> FleetState:
         """The router's pinned device twin (flushing coalesced events)."""
         self.router._check_routable()
         return self.router._fleet_dev
+
+    def _zone_state_dev(self) -> ZoneState:
+        """The zone tables' device twin, re-pinned after fleet events (the
+        host tables moved O(1) per event; one upload per changed epoch)."""
+        epoch = self.router.routing_epoch
+        if self._zone_dev is None or self._zone_dev[0] != epoch:
+            self._zone_dev = (epoch, jax.device_put(self._zone_host()))
+        return self._zone_dev[1]
+
+    def _drain_counts(self) -> None:
+        """Sync the device fallback counts into the registry's counters —
+        run when the registry is read, never per call.  The device counts
+        are 64-bit (a carry into the high word), so no read cadence loses
+        a count."""
+        lo, hi = np.asarray(self._fallbacks).astype(np.uint64)
+        now = [int(c) for c in (hi << np.uint64(32)) | lo]
+        zone, shard = (a - b for a, b in zip(now, self._drained))
+        self._drained = tuple(now)
+        m = self.metrics
+        m.counter("placement_zone_fallback_columns_total").inc(zone)
+        m.counter("placement_shard_fallback_columns_total").inc(shard)
+        m.counter("placement_columns_total").inc(self._columns)
+        self._columns = 0
 
     def _alive_mask(self) -> np.ndarray:
         """(capacity,) bool — slot id alive right now."""
@@ -369,19 +521,40 @@ class StorePlacement:
             if self.strict:
                 raise PlacementDegradedError(n, self.spec.r, epoch=self.epoch)
             return MODE_DEGRADED
+        return self._zone_mode()
+
+    def _zone_mode(self) -> str:
+        """MODE_ZONE_DEGRADED while fewer zones are alive than a key's
+        holders should span, ``min(r, zones)``; else MODE_NORMAL."""
+        zones = self.spec.zones
+        if zones > 1 and self.alive_zones < min(self.spec.r, zones):
+            return MODE_ZONE_DEGRADED
         return MODE_NORMAL
 
     def place_keys(self, keys) -> tuple[jax.Array, jax.Array]:
         """Raw device placement: ``(replicas (N, r) i32, exhausted (N,)
         bool)``, no degradation typing (the expert path; ``place`` wraps
-        it).  Routability (``n_alive >= 1``) is still enforced."""
+        it).  Routability (``n_alive >= 1``) is still enforced.  With zones,
+        the same dispatch advances the fallback counts on the device."""
         with span("route.call"):
             fleet = self._fleet_dev()
             keys_u32 = self.router._coerce_keys(keys)
+            size = int(np.size(keys_u32))
+            if self.spec.zones == 1:
+                with span("route.launch") as s:
+                    if s:
+                        s.tag(rows=-(-size // LANES))
+                    return ops.route_replicas_bulk(keys_u32, fleet, self.spec)
+            zone = self._zone_state_dev()
             with span("route.launch") as s:
                 if s:
-                    s.tag(rows=-(-int(np.size(keys_u32)) // LANES))
-                return ops.route_replicas_bulk(keys_u32, fleet, self.spec)
+                    s.tag(rows=-(-size // LANES), zones=self.spec.zones,
+                          alive_zones=self.alive_zones)
+                replicas, exhausted, self._fallbacks = ops.route_replicas_bulk(
+                    keys_u32, fleet, self.spec, zone, self._fallbacks
+                )
+            self._columns += size * self.spec.r
+            return replicas, exhausted
 
     def place(self, keys) -> PlacedBatch:
         """Place keys on ``r`` distinct alive shards, typed and epoch-
@@ -427,7 +600,7 @@ class StorePlacement:
         self._holders = np.concatenate(
             [self._holders, np.asarray(batch.replicas, np.int64)], axis=0
         )
-        self._synced_fleet = self._fleet_snapshot()
+        self._synced = self._snapshot()
         return batch
 
     def reachable_mask(self) -> np.ndarray:
@@ -465,19 +638,22 @@ class StorePlacement:
                 f"key {int(self._keys[key_index])} has no reachable replica "
                 f"(all holders failed)", epoch=self.epoch,
             )
-        mode = MODE_NORMAL if found.size >= min(self.spec.r, self.n_alive) \
+        mode = self._zone_mode() if found.size >= min(self.spec.r, self.n_alive) \
             else MODE_DEGRADED
         return found.astype(np.int64), mode
 
     # -- migration + repair enumeration --------------------------------------
-    def plan_migration(self, old_fleet: FleetState | None = None) -> MigrationPlan:
-        """Diff the registered keys' placement between ``old_fleet`` (default:
-        the snapshot captured at the last register/sync) and the CURRENT
-        fleet — ONE device pass over both placements (DESIGN.md §13)."""
-        old = old_fleet if old_fleet is not None else self._synced_fleet
+    def plan_migration(self) -> MigrationPlan:
+        """Diff the registered keys' placement between the snapshot captured
+        at the last register/sync and the CURRENT fleet — ONE device pass
+        over both placements (DESIGN.md §13)."""
+        old, old_zone = self._synced
         new = self._fleet_dev()
+        new_zone = None if self.spec.zones == 1 else self._zone_state_dev()
         keys_u32 = self._keys
-        o, n, moved, _ = ops.placement_diff_bulk(keys_u32, old, new, self.spec)
+        o, n, moved, _ = ops.placement_diff_bulk(
+            keys_u32, old, new, self.spec, old_zone, new_zone
+        )
         return MigrationPlan(
             keys=keys_u32,
             old=np.asarray(o),
@@ -521,7 +697,7 @@ class StorePlacement:
             for j in missing:
                 needed.append((i, j, int(target[i, j])))
             h[i] = aligned
-        self._synced_fleet = self._fleet_snapshot()
+        self._synced = self._snapshot()
         return needed
 
     def repair_source(self, key_index: int) -> int:

@@ -90,7 +90,12 @@ def _check_block_rows(block_rows) -> None:
 
 class BatchRouter:
     """Route request batches through the fused single-dispatch kernel of a
-    pluggable bulk engine."""
+    pluggable bulk engine.
+
+    ``zones`` (keyword, default 1) is a fact of the deployment: the failure
+    domain splits its slot space into that many zones and keeps their
+    tables from genesis, for ``StorePlacement(..., zones=)``; the route
+    itself does not read them."""
 
     def __init__(
         self,
@@ -108,6 +113,7 @@ class BatchRouter:
         block_rows=_UNSET,
         shard_axis=_UNSET,
         donate_keys=_UNSET,
+        zones: int = 1,
     ):
         if n_replicas < 1:
             raise ValueError(f"n_replicas must be >= 1, got {n_replicas}")
@@ -191,6 +197,7 @@ class BatchRouter:
             max_chain=max_chain,
             resolve="table",
             allow_empty=True,
+            zones=zones,
         )
         self.max_chain = max_chain
         self.fused = fused

@@ -17,6 +17,7 @@ from repro.serving.lifecycle.detector import (
 )
 from repro.serving.lifecycle.errors import (
     SHED_INFEASIBLE,
+    MODE_ZONE_DEGRADED,
     SHED_LATE,
     SHED_PAST_DEADLINE,
     SHED_RATE_LIMITED,
@@ -82,5 +83,6 @@ __all__ = [
     "RoutedBatch",
     "MODE_NORMAL",
     "MODE_DEGRADED",
+    "MODE_ZONE_DEGRADED",
     "MODE_UNAVAILABLE",
 ]

@@ -10,6 +10,9 @@ from __future__ import annotations
 #: fleet/placement health modes, ordered by health — shared by the lifecycle
 #: manager's ``RoutedBatch`` and the placement tier's ``PlacedBatch``
 MODE_NORMAL = "normal"
+#: a placement with every shard it needs but fewer alive zones than
+#: ``min(r, zones)``: the holders span every alive zone and share some
+MODE_ZONE_DEGRADED = "zone_degraded"
 MODE_DEGRADED = "degraded"
 MODE_UNAVAILABLE = "unavailable"
 

@@ -65,7 +65,9 @@ class JournalSnapshot:
 
     Everything ``restore`` needs to rebuild a ``FailureDomain`` without
     replaying from genesis: the slot-space size, the replacement-table
-    permutation + inverse + alive count, and the removed set.
+    permutation + inverse + alive count, and the removed set.  ``zones``
+    holds each zone's table the same way (``ZoneTables.capture``), and is
+    empty for a domain without zones.
     """
 
     epoch: int
@@ -74,10 +76,12 @@ class JournalSnapshot:
     slots: tuple[int, ...]
     pos: tuple[int, ...]
     removed: tuple[int, ...]
+    zones: tuple = ()
 
     @classmethod
     def capture(cls, epoch: int, domain) -> "JournalSnapshot":
         rt = domain.replacement_table
+        view = domain.zone_tables
         return cls(
             epoch=epoch,
             n_total=domain.total_count,
@@ -85,6 +89,7 @@ class JournalSnapshot:
             slots=tuple(rt.slots),
             pos=tuple(rt.pos),
             removed=tuple(sorted(domain.removed)),
+            zones=() if view is None else view.capture(),
         )
 
     def to_json(self) -> str:
@@ -100,6 +105,11 @@ class JournalSnapshot:
             slots=tuple(int(s) for s in d["slots"]),
             pos=tuple(int(p) for p in d["pos"]),
             removed=tuple(int(r) for r in d["removed"]),
+            zones=tuple(
+                (tuple(int(s) for s in slots), tuple(int(p) for p in pos),
+                 int(n_alive))
+                for slots, pos, n_alive in d.get("zones", ())
+            ),
         )
 
 
@@ -216,6 +226,14 @@ def restore(
     eng.table.slots = list(snapshot.slots)
     eng.table.pos = list(snapshot.pos)
     eng.table.n_alive = snapshot.n_alive
+    view = eng.zone_tables
+    if view is not None:
+        view.install(snapshot.zones)
+    elif snapshot.zones:
+        raise ValueError(
+            f"snapshot holds {len(snapshot.zones)} zone tables; the domain "
+            "keeps none"
+        )
     for ev in events:
         apply_event(domain, ev)
     return domain

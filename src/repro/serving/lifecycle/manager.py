@@ -259,6 +259,7 @@ class LifecycleManager:
             max_chain=self.router.max_chain,
             resolve="table",
             allow_empty=True,
+            zones=self.router.domain.zones,
         )
 
     def snapshot(self) -> JournalSnapshot:
@@ -297,6 +298,11 @@ class LifecycleManager:
             or rt_new.n_alive != rt_live.n_alive
         ):
             raise AssertionError("replayed ReplacementTable differs from live")
+        zones_new, zones_live = rebuilt.zone_tables, live.zone_tables
+        if (zones_new is None) != (zones_live is None) or (
+            zones_live is not None and zones_new.capture() != zones_live.capture()
+        ):
+            raise AssertionError("replayed zone tables differ from live")
         packed = FleetState.pack(rebuilt, self.router.spec.capacity)
         host = self.router._fleet_host
         for leaf in ("packed", "table", "state"):
@@ -435,17 +441,22 @@ class PlacementRepairer:
         state.  Raises ``AssertionError`` on mismatch."""
         import numpy as np
 
-        from repro.core.bulk import FleetState
+        from repro.core.bulk import FleetState, ZoneState
         from repro.kernels import ops
 
         self.manager.verify_replay(snapshot)
         if self.store.keys.size == 0 or self.manager.n_alive == 0:
             return
         rebuilt = self.manager.rebuild_domain(snapshot)
-        fleet = FleetState.pack(rebuilt, self.manager.router.spec.capacity)
-        replayed, _ = ops.route_replicas_bulk(
-            self.store.keys, fleet.device_put(), self.store.spec
+        capacity = self.manager.router.spec.capacity
+        fleet = FleetState.pack(rebuilt, capacity)
+        spec = self.store.spec
+        zone = None if spec.zones == 1 else ZoneState.pack(
+            rebuilt.zone_tables, capacity
         )
+        replayed = ops.route_replicas_bulk(
+            self.store.keys, fleet.device_put(), spec, zone
+        )[0]
         live, _ = self.store.place_keys(self.store.keys)
         if not np.array_equal(np.asarray(replayed), np.asarray(live)):
             raise AssertionError(

@@ -158,6 +158,7 @@ class SessionRouter:
         max_chain: int = 4096,
         resolve: str = "chain",
         allow_empty: bool = False,
+        zones: int = 1,
     ):
         self.domain = FailureDomain(
             n_replicas,
@@ -167,6 +168,7 @@ class SessionRouter:
             max_chain=max_chain,
             resolve=resolve,
             allow_empty=allow_empty,
+            zones=zones,
         )
         self.stats = RoutingStats()
         #: session key -> last replica (observability only): bulk

@@ -92,13 +92,14 @@ def _children(events, parent):
 FAILED = (3, 17, 40)
 
 
-def _router():
+def _router(zones=1):
     """A small router on the interpret-mode kernel; no node failed yet."""
-    return BatchRouter(64, capacity=64, interpret=True, block_rows=8)
+    return BatchRouter(64, capacity=64, interpret=True, block_rows=8,
+                       zones=zones)
 
 
-def _stormed_manager():
-    mgr = LifecycleManager(_router())
+def _stormed_manager(zones=1):
+    mgr = LifecycleManager(_router(zones))
     for node in FAILED:
         mgr.fail(node)
     return mgr
@@ -179,6 +180,21 @@ def test_place_keys_spans_under_the_profiler(tmp_path):
     (call,) = _named(events, "route.call")
     (launch,) = _children(events, call)
     assert launch[0] == "route.launch" and launch[3] == {"rows": KEYS.size // 128}
+    assert len(events) == 2
+
+
+def test_zoned_place_keys_tags_its_zones(tmp_path):
+    mgr = _stormed_manager(zones=3)
+    for node in set(range(2, 63, 3)) - set(FAILED):  # all of zone 2 of 3
+        mgr.fail(node)
+    store = StorePlacement(mgr.router, r=3, zones=3)
+    jax.block_until_ready(store.place_keys(KEYS))
+    events = _profiled(tmp_path, lambda: jax.block_until_ready(
+        store.place_keys(KEYS)))
+    (call,) = _named(events, "route.call")
+    (launch,) = _children(events, call)
+    assert launch[0] == "route.launch"
+    assert launch[3] == {"rows": KEYS.size // 128, "zones": 3, "alive_zones": 2}
     assert len(events) == 2
 
 

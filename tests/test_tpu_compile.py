@@ -131,3 +131,29 @@ def test_largest_autotuned_tile_fits_v5e(one_chip):
     assert "tpu_custom_call" in _compiled_text(
         one_chip, "binomial", "route", max(CANDIDATES)
     )
+
+
+def test_zoned_placement_pass_compiles_for_v5e(one_chip):
+    """The placement pass with three zones (``StorePlacement(r=3,
+    zones=3)``), XLA and no kernel: while-free, and its temporaries no
+    larger than the zone-free pass's but for the zone gathers' lanes."""
+    from repro.core.bulk import PlacementSpec, RouterSpec, ZoneState
+    from repro.core.memento_jax import table_width
+    from repro.placement.store import _route_replicas_jit
+
+    keys = _shape(one_chip, (KEYS,), jnp.uint32)
+    static = dict(r=3, omega=16, n_words=mask_words(CAPACITY), max_resalt=3,
+                  route=BULK_ENGINES["binomial"].route)
+    spec = PlacementSpec(router=RouterSpec(capacity=CAPACITY), r=3, zones=3)
+    zone = (_shape(one_chip, (1, table_width(3 * spec.zone_width)), jnp.int32),
+            _shape(one_chip, (2, 3), jnp.uint32))
+    zoned = _route_replicas_jit.lower(
+        keys, *_fleet(one_chip), ZoneState(*zone),
+        _shape(one_chip, (2, 2), jnp.uint32), zones=3,
+        zone_width=spec.zone_width, **static,
+    ).compile()
+    plain = _route_replicas_jit.lower(keys, *_fleet(one_chip), **static).compile()
+    assert " while(" not in zoned.as_text()
+    extra = (zoned.memory_analysis().temp_size_in_bytes
+             - plain.memory_analysis().temp_size_in_bytes)
+    assert extra <= 2 * 4 * KEYS  # two u32 lanes a key at most
