@@ -41,6 +41,7 @@ from repro.kernels.binomial_hash import (
     binomial_route_pallas_fused,
 )
 from repro.kernels.ref import binomial_bulk_lookup_ref
+from repro.kernels.table_read import TableRead
 
 #: deprecation shims that already warned this process (warn once, not per
 #: batch; tests reset this to assert the warning fires)
@@ -116,10 +117,9 @@ def route_load_bulk(
     ``LoadMonitor`` picks the shift per batch via its exact cutoff).
     Replica ids are bit-exact with ``route_bulk`` at every shift — the
     instrumentation never changes routing — and the accumulator stays on
-    device (the monitor drains it on its own cadence).  Like the
-    placement pass, pure-jnp on every backend (the accumulate is one
-    comparison-sum or scatter — no Pallas twin); certified as
-    ``observability/load_pass``.
+    device (the monitor drains it on its own cadence).  Pure-jnp on
+    every backend (the accumulate is one comparison-sum or scatter — no
+    Pallas twin); certified as ``observability/load_pass``.
 
     keys    any int shape (u32 key space)
     fleet   ``FleetState``;  counts  (capacity,) u32 running accumulator
@@ -182,8 +182,10 @@ def route_replicas_bulk(keys: jax.Array, fleet: FleetState, pspec,
     families route through the spec'd engine's fused jnp datapath as one
     broadcast batch, then the bounded re-salt resolution breaks inter-family
     collisions in-trace.  Engine resolved per call like every dispatcher
-    here; the pass is pure-jnp on every backend (the resolution is
-    elementwise + gathers — XLA fuses it; no Pallas twin).
+    here.  The pass is XLA on every backend but for its small-table reads:
+    where the route's kernel runs (a TPU, or interpret mode) the probes
+    and the zone fallback read the slots and zone tables from VMEM by the
+    ``table_read`` kernel, elsewhere by XLA gathers (``TableRead``).
 
     keys   any int shape (u32 key space); fleet  ``FleetState``;
     pspec  ``PlacementSpec`` — replication r, probe bound, zones, the
@@ -198,7 +200,8 @@ def route_replicas_bulk(keys: jax.Array, fleet: FleetState, pspec,
     spec = pspec.router
     eng = _engine(spec)
     static = dict(r=pspec.r, omega=spec.omega, n_words=spec.n_words,
-                  max_resalt=pspec.resolved_max_resalt, route=eng.route)
+                  max_resalt=pspec.resolved_max_resalt, route=eng.route,
+                  read=TableRead.for_spec(spec))
     if pspec.zones == 1:
         return _route_replicas_jit(
             keys, fleet.packed, fleet.table, fleet.state, **static
@@ -231,6 +234,7 @@ def placement_diff_bulk(
         r=pspec.r, omega=spec.omega, n_words=spec.n_words,
         max_resalt=pspec.resolved_max_resalt, route=eng.route,
         zones=pspec.zones, zone_width=pspec.zone_width,
+        read=TableRead.for_spec(spec),
     )
 
 

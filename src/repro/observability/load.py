@@ -15,9 +15,8 @@ traffic (DESIGN.md §15).  Three pieces:
   coherently in one accumulator (see ``LoadConfig.exact_cutoff`` for why
   sampling is load-bearing: counting every key of a 1M-key batch costs
   more than the 3 % overhead budget on a single-core host no matter how
-  the histogram is phrased).  Like the placement pass, the instrumented
-  route is pure-jnp on every backend (elementwise + one reduction — no
-  Pallas twin needed).  While-free, affine in ω, dtype-closed, zero
+  the histogram is phrased).  The instrumented route is pure-jnp on
+  every backend (elementwise + one reduction — no Pallas twin needed).  While-free, affine in ω, dtype-closed, zero
   transfers — certified as ``observability/load_pass``.
 
 * ``LoadMonitor`` — the host control plane: attaches to a ``BatchRouter``

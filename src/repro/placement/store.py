@@ -57,8 +57,10 @@ import numpy as np
 
 from repro.core.binomial_jax import GOLDEN32, hash_pair, mix32, mulhi32
 from repro.core.bulk import FleetState, PlacementSpec, RouterSpec, ZoneState
+from repro.core.memento_jax import table_width
 from repro.kernels import ops
 from repro.kernels.fused import LANES
+from repro.kernels.table_read import TableRead
 from repro.observability.metrics import MetricsRegistry
 from repro.observability.trace import span
 from repro.placement.assignment import MovementPlan
@@ -111,6 +113,7 @@ def route_replicas_impl(
     route,
     zones: int = 1,
     zone_width: int = 0,
+    read: TableRead = TableRead(),
 ) -> tuple:
     """Place every key on ``r`` distinct alive shards — ONE traced pass.
 
@@ -129,6 +132,9 @@ def route_replicas_impl(
     zones        static zone count (slot ``s`` in zone ``s mod zones``);
                  1 traces the zone-free pass and nothing of the zones
     zone_width   static zone-table entries per zone (``PlacementSpec``)
+    read         how the pass reads its slots and zone tables at every lane
+                 (``TableRead``: the XLA gather, the default, or the VMEM
+                 kernel); the answer is the same either way
 
     Returns ``(replicas, exhausted)``: ``replicas`` is ``(N, r)`` int32,
     every entry an ALIVE shard; column ``j`` is distinct from columns
@@ -158,8 +164,11 @@ def route_replicas_impl(
     n_alive = state[1].astype(jnp.uint32)
     slots = table[0].astype(jnp.uint32)
 
-    # all r salted families through the fused engine as ONE broadcast batch
-    fam = mix32(keys_u32[:, None] ^ family_salts(r))  # (N, r) u32
+    # all r salted families through the fused engine as ONE broadcast batch,
+    # family-major: column j is the flat run [j * n, (j + 1) * n), so each
+    # column keeps the keys' own 1-D layout
+    n = keys_u32.shape[0]
+    fam = mix32(keys_u32 ^ family_salts(r)[:, None]).reshape(-1)  # (r * N,)
     base = route(
         fam, packed_mask, table, state, omega=omega, n_words=n_words
     ).astype(jnp.uint32)
@@ -202,7 +211,7 @@ def route_replicas_impl(
     cols = []
     exhausted = jnp.zeros(keys_u32.shape, bool)
     for j in range(r):
-        b = base[:, j]
+        b, fam_j = base[j * n : (j + 1) * n], fam[j * n : (j + 1) * n]
         if j > 0:
             if zoned and j < zones:
                 # j columns hold j distinct zones; a free alive zone exists
@@ -211,7 +220,7 @@ def route_replicas_impl(
                 move = free_left & (((used_zones >> zone_of(b)) & 1) != 0)
                 n_free = jnp.where(free_left, n_zones_alive - np.uint32(j),
                                    np.uint32(0))
-                k = mulhi32(mix32(fam[:, j] ^ ZONE_SALT), n_free)
+                k = mulhi32(mix32(fam_j ^ ZONE_SALT), n_free)
                 # the k-th free alive zone in zone order, with its counts
                 target = jnp.zeros_like(b)
                 z_n = jnp.zeros_like(b)
@@ -225,12 +234,10 @@ def route_replicas_impl(
                     z_a = jnp.where(pick, z_alive[z], z_a)
                     seen = seen + free.astype(jnp.uint32)
                 # the table divert's two redirects over that zone's table
-                h = hash_pair(fam[:, j], target)
+                h = hash_pair(fam_j, target)
                 q = mulhi32(h, z_n)
                 q = jnp.where(q >= z_a, mulhi32(mix32(h ^ (q * GOLDEN32)), z_a), q)
-                cand = z_slots.at[target * np.uint32(zone_width) + q].get(
-                    mode="promise_in_bounds"
-                )
+                cand = read(z_slots, target * np.uint32(zone_width) + q)
                 b = jnp.where(move, cand, b)
                 moved_zone = moved_zone + jnp.sum(move, dtype=jnp.uint32)
             coll = is_used(b)
@@ -242,9 +249,9 @@ def route_replicas_impl(
             # probes visit min(max_resalt, n_alive) DISTINCT positions, of
             # which at most j are taken, so max_resalt >= j+1 guarantees a
             # distinct alive shard whenever n_alive > j
-            q = mulhi32(mix32(fam[:, j] ^ RESALT_SALT), n_alive)
+            q = mulhi32(mix32(fam_j ^ RESALT_SALT), n_alive)
             for _probe in range(max_resalt):
-                cand = slots.at[q].get(mode="promise_in_bounds")
+                cand = read(slots, q)
                 free = coll & ~is_used(cand)
                 b = jnp.where(free, cand, b)
                 coll = coll & ~free
@@ -267,22 +274,22 @@ def route_replicas_impl(
 
 @functools.partial(
     jax.jit, static_argnames=("r", "omega", "n_words", "max_resalt", "route",
-                              "zones", "zone_width")
+                              "zones", "zone_width", "read")
 )
 def _route_replicas_jit(keys, packed, table, state, zone=None, counts=None, *,
                         r, omega, n_words, max_resalt, route, zones=1,
-                        zone_width=0):
+                        zone_width=0, read=TableRead()):
     if zones == 1:
         return route_replicas_impl(
             keys, packed, table, state, r=r, omega=omega, n_words=n_words,
-            max_resalt=max_resalt, route=route,
+            max_resalt=max_resalt, route=route, read=read,
         )
     # the fallback counts ride across calls in a device accumulator of
     # (low, high) u32 words, the carry taken on the device
     replicas, exhausted, moved = route_replicas_impl(
         keys, packed, table, state, zone.table, zone.state, r=r, omega=omega,
         n_words=n_words, max_resalt=max_resalt, route=route, zones=zones,
-        zone_width=zone_width,
+        zone_width=zone_width, read=read,
     )
     lo = counts[0] + moved
     hi = counts[1] + (lo < moved).astype(jnp.uint32)
@@ -293,6 +300,7 @@ def placement_diff_impl(
     keys, old_fleet: FleetState, new_fleet: FleetState,
     old_zone: ZoneState | None = None, new_zone: ZoneState | None = None,
     *, r, omega, n_words, max_resalt, route, zones=1, zone_width=0,
+    read=TableRead(),
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Old-vs-new placement diff — the bulk migration plan, ONE traced pass.
 
@@ -308,7 +316,7 @@ def placement_diff_impl(
         return route_replicas_impl(
             keys, fleet.packed, fleet.table, fleet.state, *zone_ops, r=r,
             omega=omega, n_words=n_words, max_resalt=max_resalt, route=route,
-            zones=zones, zone_width=zone_width,
+            zones=zones, zone_width=zone_width, read=read,
         )
 
     old = place(old_fleet, old_zone)[0]
@@ -322,8 +330,27 @@ def placement_diff_impl(
 _placement_diff_jit = jax.jit(
     placement_diff_impl,
     static_argnames=("r", "omega", "n_words", "max_resalt", "route", "zones",
-                     "zone_width"),
+                     "zone_width", "read"),
 )
+
+
+def table_reads(pspec: PlacementSpec) -> dict[str, int]:
+    """The small-table reads one placement pass makes, by ``TableRead``
+    path (``"vmem"`` or ``"gather"``): ``(r - 1) * max_resalt`` probes of
+    the slots table, and with zones ``min(r, zones) - 1`` zone-table reads.
+    Static, so the host counts them without reading the device."""
+    read = TableRead.for_spec(pspec.router)
+    reads: dict[str, int] = {}
+    for width, n in (
+        (table_width(pspec.router.capacity),
+         (pspec.r - 1) * pspec.resolved_max_resalt),
+        (table_width(pspec.zones * pspec.zone_width),
+         min(pspec.r, pspec.zones) - 1),
+    ):
+        if n > 0:
+            path = read.path(width)
+            reads[path] = reads.get(path, 0) + n
+    return reads
 
 
 # ---------------------------------------------------------------------------
@@ -432,6 +459,12 @@ class StorePlacement:
         #: the registry the zone-fallback counters reach, synced from the
         #: device when it is read
         self.metrics = metrics if metrics is not None else MetricsRegistry()
+        #: the table reads each dispatch makes, as (counter, count) by path
+        self._reads = [
+            (self.metrics.counter("placement_table_reads_total", path=path), n)
+            for path, n in table_reads(self.spec).items()
+        ]
+        self._n_reads = sum(n for _, n in self._reads)
         if zones > 1:
             #: (routing epoch, device ZoneState) last uploaded
             self._zone_dev: tuple[int, ZoneState] | None = None
@@ -540,16 +573,18 @@ class StorePlacement:
             fleet = self._fleet_dev()
             keys_u32 = self.router._coerce_keys(keys)
             size = int(np.size(keys_u32))
+            for counter, n in self._reads:
+                counter.inc(n)
             if self.spec.zones == 1:
                 with span("route.launch") as s:
                     if s:
-                        s.tag(rows=-(-size // LANES))
+                        s.tag(rows=-(-size // LANES), reads=self._n_reads)
                     return ops.route_replicas_bulk(keys_u32, fleet, self.spec)
             zone = self._zone_state_dev()
             with span("route.launch") as s:
                 if s:
-                    s.tag(rows=-(-size // LANES), zones=self.spec.zones,
-                          alive_zones=self.alive_zones)
+                    s.tag(rows=-(-size // LANES), reads=self._n_reads,
+                          zones=self.spec.zones, alive_zones=self.alive_zones)
                 replicas, exhausted, self._fallbacks = ops.route_replicas_bulk(
                     keys_u32, fleet, self.spec, zone, self._fallbacks
                 )

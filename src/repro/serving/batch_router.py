@@ -480,9 +480,9 @@ class BatchRouter:
             monitor = self._load_monitor
             if monitor is not None:
                 # the instrumented route: same dispatch count, the per-shard
-                # bincount rides along (always the fused jnp pass — like the
-                # placement pass it has no Pallas twin; bit-exact with the
-                # kernel, as tests enforce)
+                # bincount rides along (always the fused jnp pass, which
+                # has no Pallas twin; bit-exact with the kernel, as tests
+                # enforce)
                 n_keys = int(np.size(keys_u32))
                 out, counts = ops.route_load_bulk(
                     keys_u32, self._fleet_dev, monitor.counts_dev, spec,

@@ -179,7 +179,8 @@ def test_place_keys_spans_under_the_profiler(tmp_path):
         store.place_keys(KEYS)))
     (call,) = _named(events, "route.call")
     (launch,) = _children(events, call)
-    assert launch[0] == "route.launch" and launch[3] == {"rows": KEYS.size // 128}
+    assert launch[0] == "route.launch"
+    assert launch[3] == {"rows": KEYS.size // 128, "reads": 6}
     assert len(events) == 2
 
 
@@ -194,7 +195,8 @@ def test_zoned_place_keys_tags_its_zones(tmp_path):
     (call,) = _named(events, "route.call")
     (launch,) = _children(events, call)
     assert launch[0] == "route.launch"
-    assert launch[3] == {"rows": KEYS.size // 128, "zones": 3, "alive_zones": 2}
+    assert launch[3] == {"rows": KEYS.size // 128, "reads": 8, "zones": 3,
+                         "alive_zones": 2}
     assert len(events) == 2
 
 

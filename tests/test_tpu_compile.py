@@ -4,9 +4,10 @@ Interpret mode runs the kernels on the CPU but cannot see what the chip's
 compiler refuses (unsupported casts, unsigned ops, tiles that overflow the
 scoped VMEM).  These tests compile every ``pallas_call`` the routing path
 dispatches — route, u64 ingest and ``lookup_dyn`` of both bulk engines, and
-the static binomial lookup — for one chip of a ``v5e:2x2`` topology that is
-described, not attached, at 2^20 keys and capacity 1024, and check the
-kernel is in the compiled program.
+the static binomial lookup — and the placement pass with its ``table_read``
+kernel, for one chip of a ``v5e:2x2`` topology that is described, not
+attached, at 2^20 keys and capacity 1024, and check the kernel is in the
+compiled program.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and pytest-xdist workers
@@ -133,12 +134,14 @@ def test_largest_autotuned_tile_fits_v5e(one_chip):
     )
 
 
-def test_zoned_placement_pass_compiles_for_v5e(one_chip):
-    """The placement pass with three zones (``StorePlacement(r=3,
-    zones=3)``), XLA and no kernel: while-free, and its temporaries no
-    larger than the zone-free pass's but for the zone gathers' lanes."""
+@pytest.fixture(scope="module")
+def placement_programs(one_chip):
+    """The placement pass (``StorePlacement(r=3)``, and with ``zones=3``)
+    compiled for the chip, its tables read by the XLA gather and by the
+    ``table_read`` kernel: ``{(zones, read path): compiled}``."""
     from repro.core.bulk import PlacementSpec, RouterSpec, ZoneState
     from repro.core.memento_jax import table_width
+    from repro.kernels.table_read import TableRead
     from repro.placement.store import _route_replicas_jit
 
     keys = _shape(one_chip, (KEYS,), jnp.uint32)
@@ -147,13 +150,48 @@ def test_zoned_placement_pass_compiles_for_v5e(one_chip):
     spec = PlacementSpec(router=RouterSpec(capacity=CAPACITY), r=3, zones=3)
     zone = (_shape(one_chip, (1, table_width(3 * spec.zone_width)), jnp.int32),
             _shape(one_chip, (2, 3), jnp.uint32))
-    zoned = _route_replicas_jit.lower(
-        keys, *_fleet(one_chip), ZoneState(*zone),
-        _shape(one_chip, (2, 2), jnp.uint32), zones=3,
-        zone_width=spec.zone_width, **static,
-    ).compile()
-    plain = _route_replicas_jit.lower(keys, *_fleet(one_chip), **static).compile()
-    assert " while(" not in zoned.as_text()
-    extra = (zoned.memory_analysis().temp_size_in_bytes
-             - plain.memory_analysis().temp_size_in_bytes)
-    assert extra <= 2 * 4 * KEYS  # two u32 lanes a key at most
+    programs = {}
+    for read in (TableRead(), TableRead(vmem=True)):
+        path = read.path(CAPACITY)
+        programs[1, path] = _route_replicas_jit.lower(
+            keys, *_fleet(one_chip), read=read, **static).compile()
+        programs[3, path] = _route_replicas_jit.lower(
+            keys, *_fleet(one_chip), ZoneState(*zone),
+            _shape(one_chip, (2, 2), jnp.uint32), zones=3,
+            zone_width=spec.zone_width, read=read, **static,
+        ).compile()
+    return programs
+
+
+def test_zoned_placement_pass_compiles_for_v5e(placement_programs):
+    """The placement pass with three zones (``StorePlacement(r=3,
+    zones=3)``), either way its tables are read: while-free, and its
+    temporaries no larger than the zone-free pass's but for the zone
+    reads' lanes."""
+    for path in ("gather", "vmem"):
+        zoned, plain = placement_programs[3, path], placement_programs[1, path]
+        assert " while(" not in zoned.as_text()
+        extra = (zoned.memory_analysis().temp_size_in_bytes
+                 - plain.memory_analysis().temp_size_in_bytes)
+        assert extra <= 2 * 4 * KEYS  # two u32 lanes a key at most
+
+
+#: an XLA gather and the shape it gathers
+GATHER = re.compile(r"= (\S+) gather\(")
+
+
+@pytest.mark.parametrize("zones", (1, 3))
+def test_placement_pass_reads_its_tables_in_the_kernel_on_v5e(
+        placement_programs, zones):
+    """With the ``table_read`` kernel the pass keeps one XLA gather, the
+    route's divert over all 3 x 2^20 family lanes, where the XLA reads
+    gather six probes of the slots table and, zoned, two reads of the zone
+    table too; and it adds no copy."""
+    by_xla = placement_programs[zones, "gather"].as_text()
+    by_kernel = placement_programs[zones, "vmem"].as_text()
+    assert len(GATHER.findall(by_xla)) == 1 + 6 + (2 if zones > 1 else 0)
+    assert [g.split("{")[0] for g in GATHER.findall(by_kernel)] == [
+        f"u32[{3 * KEYS}]"]
+    assert by_kernel.count('custom_call_target="tpu_custom_call"') >= 1
+    assert " while(" not in by_kernel
+    assert by_kernel.count(" copy(") <= by_xla.count(" copy(")

@@ -320,7 +320,8 @@ def _route_replicas_before_zones(keys, packed, table, state, *, r, omega,
     keys_u32 = keys.reshape(-1).astype(jnp.uint32)
     n_alive = state[1].astype(jnp.uint32)
     slots = table[0].astype(jnp.uint32)
-    fam = mix32(keys_u32[:, None] ^ family_salts(r))
+    n = keys_u32.shape[0]
+    fam = mix32(keys_u32 ^ family_salts(r)[:, None]).reshape(-1)
     base = route(
         fam, packed, table, state, omega=omega, n_words=n_words
     ).astype(jnp.uint32)
@@ -342,10 +343,10 @@ def _route_replicas_before_zones(keys, packed, table, state, *, r, omega,
     cols = []
     exhausted = jnp.zeros(keys_u32.shape, bool)
     for j in range(r):
-        b = base[:, j]
+        b, fam_j = base[j * n : (j + 1) * n], fam[j * n : (j + 1) * n]
         if j > 0:
             coll = is_used(b)
-            q = mulhi32(mix32(fam[:, j] ^ RESALT_SALT), n_alive)
+            q = mulhi32(mix32(fam_j ^ RESALT_SALT), n_alive)
             for _probe in range(max_resalt):
                 cand = slots.at[q].get(mode="promise_in_bounds")
                 free = coll & ~is_used(cand)
