@@ -12,9 +12,9 @@ import numpy as np
 from benchmarks.common import emit, enable_compile_cache, rows_to_csv, time_loop
 from repro.core.binomial import binomial_lookup32
 from repro.core.binomial_jax import binomial_lookup_vec
-from repro.kernels.binomial_hash import binomial_bulk_lookup_pallas
 from repro.kernels.ref import binomial_bulk_lookup_ref
 from repro.roofline import hw
+from repro.serving.batch_router import BatchRouter
 
 
 def main() -> list[list]:
@@ -35,8 +35,10 @@ def main() -> list[list]:
         rows.append(["vec-u32", n, round(us, 1), f"{kps:.3e}"])
         emit(f"kernel/vec-u32/n={n}", us, f"{kps:.3e}_keys_per_s")
 
-    # pallas interpret: correctness at benchmark scale
-    out = binomial_bulk_lookup_pallas(kv[: 1 << 16], 1000, interpret=True)
+    # pallas interpret: correctness at benchmark scale (the fused route on a
+    # healthy fleet skips the divert, so it is the plain lookup)
+    router = BatchRouter(1000, capacity=1024, interpret=True, block_rows=64)
+    out = router.route_keys(kv[: 1 << 16])
     ref = binomial_bulk_lookup_ref(kv[: 1 << 16], 1000)
     ok = bool((np.asarray(out) == np.asarray(ref)).all())
     emit("kernel/pallas-interpret/n=1000", 0.0, f"matches_ref={ok}")

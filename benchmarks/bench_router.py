@@ -1,15 +1,13 @@
 """Routing throughput: scalar SessionRouter vs the batched device datapaths.
 
-Three tiers, measured on (a) a steady batch stream, (b) a stream interleaved
+Two tiers, measured on (a) a steady batch stream, (b) a stream interleaved
 with scale/fail fleet events — the storm the constant-time replacement table
 exists for — and (c) a storm-severity sweep at fixed removed fractions:
 
 * ``scalar``   — one Python lookup at a time (``FailureDomain.locate``,
   table resolution: the scalar oracle of the device path);
-* ``two_pass`` — pre-fusion pipeline: dynamic-n bulk lookup, ``buckets[N]``
-  through HBM, then the table remap (two dispatches per batch);
 * ``fused``    — the single-dispatch fused lookup+divert kernel over
-  device-resident fleet state (``BatchRouter`` default).
+  device-resident fleet state (``BatchRouter``).
 
 Plus a multi-device section — the mesh-sharded datapath (DESIGN.md §8) over
 every device of the process, in-process — an ``end_to_end`` ingest
@@ -54,7 +52,7 @@ from repro.serving.batch_router import BatchRouter
 from repro.serving.router import SessionRouter
 
 N_REPLICAS = 16
-BATCH = 1 << 20  # >= 1M keys: the acceptance size for fused vs two-pass
+BATCH = 1 << 20  # >= 1M keys: the paper's benchmark batch
 SCALAR_KEYS = 2000
 E2E_SESSIONS = 1 << 17  # end-to-end ingest batch (session ids, not keys)
 EVENTS = [("fail", 3), ("scale_up", None), ("recover", 3), ("scale_down", None)] * 2
@@ -129,7 +127,7 @@ def _event_storm_stats(router: BatchRouter, keys, iters: int) -> dict:
     }
 
 
-def _severity_sweep(keys, iters: int, fused: bool) -> dict:
+def _severity_sweep(keys, iters: int) -> dict:
     """Steady-state batch rate at fixed removed fractions of the slot space.
 
     This isolates the divert cost from event-handling overhead: one fleet
@@ -141,7 +139,7 @@ def _severity_sweep(keys, iters: int, fused: bool) -> dict:
     claim this PR makes."""
     routers, removed_counts = [], []
     for frac in SEVERITIES:
-        router = BatchRouter(N_REPLICAS, fused=fused)
+        router = BatchRouter(N_REPLICAS)
         n_removed = int(round(frac * router.domain.total_count))
         for b in range(n_removed):
             router.fail(b)
@@ -372,13 +370,11 @@ def main(argv: list[str] | None = None) -> None:
     if run & {"steady", "event_storm"}:
         scalar = _table_router(N_REPLICAS)
         fused = BatchRouter(N_REPLICAS)
-        two_pass = BatchRouter(N_REPLICAS, fused=False)
 
     if "steady" in run:
         steady = {
             "scalar": {"keys_per_sec": _scalar_rate(scalar, skeys)},
             "fused": _batch_stats(fused, keys, iters),
-            "two_pass": _batch_stats(two_pass, keys, iters),
         }
 
     if "event_storm" in run:
@@ -400,14 +396,10 @@ def main(argv: list[str] | None = None) -> None:
             # full iteration budget: the per-position minimum needs as many
             # passes as the steady loop to converge under hypervisor noise
             "fused": _event_storm_stats(fused, keys, iters),
-            "two_pass": _event_storm_stats(two_pass, keys, iters),
         }
 
     if "severity" in run:
-        severity = {
-            "fused": _severity_sweep(keys, iters, fused=True),
-            "two_pass": _severity_sweep(keys, iters, fused=False),
-        }
+        severity = {"fused": _severity_sweep(keys, iters)}
     if "multi_device" in run:
         multi_device = _multi_device_stats(batch, max(3, iters // 3))
     if "end_to_end" in run:
@@ -429,10 +421,6 @@ def main(argv: list[str] | None = None) -> None:
             "end_to_end": end_to_end,
             "engines": engines,
             "speedup": {
-                "fused_over_two_pass_steady": steady["two_pass"]["us_per_batch"]
-                / steady["fused"]["us_per_batch"],
-                "fused_over_two_pass_storm": storm["two_pass"]["us_per_batch"]
-                / storm["fused"]["us_per_batch"],
                 "fused_over_scalar_steady": steady["fused"]["keys_per_sec"]
                 / steady["scalar"]["keys_per_sec"],
                 "fused_storm_over_steady": storm["fused"]["us_per_batch"]
@@ -455,7 +443,7 @@ def main(argv: list[str] | None = None) -> None:
     for stream, tiers in (("steady", steady), ("event_storm", storm)):
         if tiers is None:
             continue
-        for tier in ("scalar", "two_pass", "fused"):
+        for tier in ("scalar", "fused"):
             stats = tiers[tier]
             rate = stats["keys_per_sec"]
             # scalar tier has no real batch; report the batch-equivalent time
@@ -476,8 +464,6 @@ def main(argv: list[str] | None = None) -> None:
         emit(
             "router_fused_batch_steady",
             steady["fused"]["us_per_batch"],
-            f"{steady['two_pass']['us_per_batch'] / steady['fused']['us_per_batch']:.2f}x "
-            f"vs two-pass, "
             f"{steady['fused']['keys_per_sec'] / steady['scalar']['keys_per_sec']:.0f}x vs scalar",
         )
         emit(

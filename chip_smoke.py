@@ -167,24 +167,19 @@ class Smoke:
         return out
 
     def base(self, router):
-        """Base-engine buckets without the divert (the ``lookup_dyn``
-        kernel), checked against the jnp ``lookup_dyn`` mirror."""
+        """Base-engine buckets without the divert (the jnp ``lookup_dyn``)."""
         from repro.core.registry import BULK_ENGINES
-        from repro.kernels import ops
 
         n = np.uint32(router.domain.total_count)
-        out = np.asarray(ops.lookup_bulk_dyn(self.keys, n, router.spec))
-        mirror = BULK_ENGINES[router.engine].lookup_dyn(self.keys, n,
-                                                        omega=router.omega)
-        same(out, mirror, f"{router.engine} lookup_dyn: jnp mirror")
-        return out
+        return np.asarray(BULK_ENGINES[router.engine].lookup_dyn(
+            self.keys, n, omega=router.omega))
 
     # -- phases ---------------------------------------------------------------
     def check_kernels(self, engine: str, router) -> None:
         from repro.core.registry import BULK_ENGINES
 
         eng = BULK_ENGINES[engine]
-        for name in ("route_pallas", "ingest_pallas", "lookup_dyn_pallas"):
+        for name in ("route_pallas", "ingest_pallas"):
             check(getattr(eng, name) is not None, f"{engine}: no {name}")
         check(router.spec.pallas_selected() or self.rehearse,
               f"{engine}: the Pallas kernels are not selected on this device")

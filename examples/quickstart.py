@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import BinomialHash, binomial_lookup_vec
-from repro.kernels.ops import binomial_bulk_lookup
+from repro.serving.batch_router import BatchRouter
 
 # -- host-side: route 100k data units onto 11 nodes ---------------------------
 engine = BinomialHash(n=11)
@@ -35,7 +35,9 @@ counts = np.bincount(np.asarray(experts), minlength=256)
 print(f"in-graph routing: 64k tokens -> 256 experts, max/mean load "
       f"{counts.max()/counts.mean():.3f}")
 
-# -- the Pallas TPU kernel (interpret mode on CPU) ----------------------------
-buckets = binomial_bulk_lookup(tokens[:8192], 256, interpret=True)
+# -- the fused Pallas TPU kernel (interpret mode on CPU) ----------------------
+# a healthy fleet skips the failure divert, so the route is the plain lookup
+router = BatchRouter(256, interpret=True, block_rows=8)
+buckets = router.route_keys(tokens[:8192])
 print("pallas kernel   : matches jnp path =",
       bool((np.asarray(buckets) == np.asarray(experts)[:8192]).all()))

@@ -8,8 +8,8 @@ The curated public surface (``__all__``):
   replicated tier built on it;
 * ``RouterSpec`` / ``FleetState`` / ``BulkEngine`` — the engine-agnostic
   bulk-routing protocol (DESIGN.md §10);
-* ``route_bulk`` / ``route_ingest_bulk`` / ``lookup_bulk_dyn`` /
-  ``make_sharded_route`` — the jit'd bulk routing entry points;
+* ``route_bulk`` / ``route_ingest_bulk`` / ``make_sharded_route`` — the
+  jit'd bulk routing entry points;
 * ``make`` / ``make_bulk`` + the ``ENGINES`` / ``BULK_ENGINES`` registries —
   the scalar comparison suite and the pluggable device engines;
 * ``SessionRouter`` / ``hash_session_ids`` — the scalar control plane and
@@ -44,7 +44,6 @@ _EXPORTS = {
     "make_bulk": "repro.core.registry",
     "route_bulk": "repro.kernels.ops",
     "route_ingest_bulk": "repro.kernels.ops",
-    "lookup_bulk_dyn": "repro.kernels.ops",
     "make_sharded_route": "repro.kernels.ops",
     "PlacementSpec": "repro.core.bulk",
     "StorePlacement": "repro.placement.store",

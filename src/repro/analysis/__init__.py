@@ -4,8 +4,8 @@ paper's O(1) guarantee for every registered device engine (DESIGN.md §11).
 Three layers, one CLI (``python -m repro.analysis``), one CI gate:
 
 * **jaxpr certifier** (``repro.analysis.certify``) — traces every
-  ``BULK_ENGINES`` entry's fused route / ingest / dynamic-n lookup (jnp
-  mirrors AND Pallas kernel bodies via ``interpret=True`` lowering) to
+  ``BULK_ENGINES`` entry's fused route / ingest (jnp mirrors AND Pallas
+  kernel bodies via ``interpret=True`` lowering) and jnp dynamic-n lookup to
   closed jaxprs and walks them recursively, enforcing: no ``while_loop``
   (waivable for paper-faithful baselines via
   ``repro.analysis.markers.constant_time_waiver``), equation count affine

@@ -1,12 +1,12 @@
 """Layer 1 — the jaxpr certifier: machine-check the O(1) contract of every
 registered device engine (DESIGN.md §11).
 
-For each ``BULK_ENGINES`` entry the certifier traces the fused route, the
-fused u64-id ingest and the plain dynamic-n lookup — BOTH the pure-jnp
-mirrors and the Pallas kernels (the kernel body jaxpr is reached by tracing
-the ``interpret=True`` lowering: the ``pallas_call`` equation carries the
-body as a sub-jaxpr, so one recursive walk covers wrapper and kernel) — to
-closed jaxprs and enforces, per target:
+For each ``BULK_ENGINES`` entry the certifier traces the fused route and
+the fused u64-id ingest — BOTH the pure-jnp mirrors and the Pallas kernels
+(the kernel body jaxpr is reached by tracing the ``interpret=True``
+lowering: the ``pallas_call`` equation carries the body as a sub-jaxpr, so
+one recursive walk covers wrapper and kernel) — and the jnp dynamic-n
+lookup to closed jaxprs and enforces, per target:
 
 * ``while-free``       — no ``while`` primitive anywhere (incl. ``pjit`` /
   ``cond`` / ``scan`` / ``pallas_call`` sub-jaxprs).  ``scan`` is fine (its
@@ -378,15 +378,6 @@ def engine_targets(
                 interpret=True,
             )
         )(lo, hi, packed, table, state),
-    )
-    add(
-        "lookup_dyn/pallas",
-        eng.lookup_dyn_pallas,
-        lambda om: jax.make_jaxpr(
-            lambda k, m: eng.lookup_dyn_pallas(
-                k, m, omega=om, block_rows=rows, interpret=True
-            )
-        )(keys, n),
     )
     return targets
 

@@ -17,7 +17,7 @@ from repro.core.binomial_jax import (  # noqa: F401
 from repro.core.bulk import BulkEngine, FleetState, RouterSpec  # noqa: F401
 from repro.core.jump_jax import JumpHash32, jump_lookup_dyn, jump_lookup_vec  # noqa: F401
 from repro.core.memento import MementoWrapper, ReplacementTable  # noqa: F401
-from repro.core.memento_jax import memento_remap, memento_remap_table  # noqa: F401
+from repro.core.memento_jax import memento_remap  # noqa: F401
 from repro.core.registry import (  # noqa: F401
     BULK_ENGINES,
     CONSTANT_TIME,

@@ -57,7 +57,7 @@ def next_pow2_u32(n: jax.Array) -> jax.Array:
     """Smallest power of two >= n, elementwise on uint32 (shift-or cascade).
 
     Pure u32 shift/or ops — usable both in a jit trace and inside a Pallas
-    kernel body, so the dynamic-n kernel and ``binomial_lookup_dyn`` share
+    kernel body, so the fused kernel's lookup and ``binomial_lookup_dyn`` share
     one E/M derivation (the bit that must stay identical for kernel == ref).
     """
     return _or_cascade(jnp.asarray(n, jnp.uint32) - np.uint32(1)) + np.uint32(1)

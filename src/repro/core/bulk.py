@@ -20,8 +20,8 @@ defines the three pieces the datapath is parameterised over:
 * ``BulkEngine`` — the per-engine bundle: the name of the bit-exact scalar
   oracle (an ``ENGINES`` entry — the control-plane truth the device path is
   tested against), the pure-jnp fused ``route``/``ingest`` mirrors, the
-  optional Pallas kernels, and the plain bulk-lookup flavours the two-pass
-  baseline and the MoE hash router consume.  Engines register in
+  optional Pallas kernels, and the plain bulk-lookup flavours the MoE hash
+  router and the certifier consume.  Engines register in
   ``repro.core.registry.BULK_ENGINES``.
 
 ``repro.kernels.ops`` dispatches over a spec + fleet state; porting a new
@@ -151,7 +151,7 @@ class FleetState:
 
     def __post_init__(self):
         if not self.capacity:
-            # manual construction (e.g. the deprecation shims): the padded
+            # manual construction (tests, the placement tier): the padded
             # table width bounds the slot space, which is all packing needs.
             # Leaves without a (1, C) shape (PartitionSpec trees, tracing
             # placeholders) keep capacity 0 — they never pack.
@@ -342,9 +342,7 @@ class BulkEngine:
     ingest_pallas     binomial flavours); None falls back to the jnp mirror
                       even when Pallas is selected
     lookup_dyn        traced-n bulk lookup ``(keys, n, omega=)`` — the
-                      two-pass baseline's first dispatch and the eager MoE
-                      hash router
-    lookup_dyn_pallas scalar-prefetch Pallas twin of ``lookup_dyn``
+                      eager MoE hash router
     lookup_vec        static-n bulk lookup (constant-folded masks; the
                       jitted-model MoE router)
     """
@@ -356,5 +354,4 @@ class BulkEngine:
     route_pallas: Callable | None = None
     ingest_pallas: Callable | None = None
     lookup_dyn: Callable | None = None
-    lookup_dyn_pallas: Callable | None = None
     lookup_vec: Callable | None = None

@@ -10,8 +10,7 @@ the two host flavours in ``repro.core.memento``:
   resolved by at most two u32 hash rounds and EXACTLY ONE table gather —
   no data-dependent loop, so storm-time batch cost equals steady-time cost.
   ``binomial_memento_route`` fuses base lookup + table divert under one jit
-  (the pure-jnp mirror of the fused Pallas kernel);
-  ``memento_remap_table`` is the second dispatch of the two-pass baseline.
+  (the pure-jnp mirror of the fused Pallas kernel).
 
 * **chain** (``resolve="chain"`` — paper-faithful library flavour):
   ``memento_remap`` applies the deterministic rejection chain to a whole
@@ -248,10 +247,6 @@ def fused_route_impl(
     return b.astype(jnp.int32).reshape(shape)
 
 
-#: backward-compatible name for the binomial-lookup flavour of the body
-_route_table_impl = fused_route_impl
-
-
 @functools.partial(jax.jit, static_argnames=("omega", "n_words"))
 def binomial_memento_route(
     keys: jax.Array,
@@ -274,7 +269,7 @@ def binomial_memento_route(
     against the scalar ``SessionRouter(binomial32, chain_bits=32,
     resolve="table")`` oracle (tests enforce).
     """
-    return _route_table_impl(keys, packed_mask, table, state, omega, n_words)
+    return fused_route_impl(keys, packed_mask, table, state, omega, n_words)
 
 
 @functools.partial(jax.jit, static_argnames=("omega", "n_words"))
@@ -301,37 +296,5 @@ def binomial_ingest_route(
     host (``bits.np_mix64`` then truncate) and routing the keys.
     """
     keys = mix64_lo32(ids_lo, ids_hi)
-    return _route_table_impl(keys, packed_mask, table, state, omega, n_words)
+    return fused_route_impl(keys, packed_mask, table, state, omega, n_words)
 
-
-@functools.partial(jax.jit, static_argnames=("n_words",))
-def memento_remap_table(
-    keys: jax.Array,
-    buckets: jax.Array,
-    packed_mask: jax.Array,
-    table: jax.Array,
-    state: jax.Array,
-    *,
-    n_words: int,
-) -> jax.Array:
-    """Second dispatch of the two-pass table baseline: divert pre-computed
-    buckets off removed slots (``buckets[N]`` round-trips through HBM
-    between the lookup dispatch and this one — the cost the fused kernel
-    removes).
-
-    keys    any int shape S; buckets shape S in [0, n_total)
-    packed_mask (1, W) u32 bit-words; table (1, C) i32 slots permutation;
-    state   (2,) u32 [n_total, n_alive]; n_words static mask word count
-    """
-    shape = buckets.shape
-    keys_u32 = keys.reshape(-1).astype(jnp.uint32)
-    b = buckets.reshape(-1).astype(jnp.uint32)
-    total = state[0].astype(jnp.uint32)
-    n_alive = state[1].astype(jnp.uint32)
-    b = jax.lax.cond(
-        n_alive != total,
-        lambda bb: _table_divert(keys_u32, bb, packed_mask, table, state, n_words),
-        lambda bb: bb,
-        b,
-    )
-    return b.astype(jnp.int32).reshape(shape)

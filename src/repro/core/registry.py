@@ -38,12 +38,10 @@ from repro.core.jump_jax import (
 )
 from repro.core.memento_jax import binomial_ingest_route, binomial_memento_route
 from repro.kernels.binomial_hash import (
-    binomial_bulk_lookup_pallas_dyn,
     binomial_ingest_pallas_fused,
     binomial_route_pallas_fused,
 )
 from repro.kernels.jump_hash import (
-    jump_bulk_lookup_pallas_dyn,
     jump_ingest_pallas_fused,
     jump_route_pallas_fused,
 )
@@ -92,7 +90,6 @@ BULK_ENGINES: dict[str, BulkEngine] = {
         route_pallas=binomial_route_pallas_fused,
         ingest_pallas=binomial_ingest_pallas_fused,
         lookup_dyn=binomial_lookup_dyn,
-        lookup_dyn_pallas=binomial_bulk_lookup_pallas_dyn,
         lookup_vec=binomial_lookup_vec,
     ),
     "jump": BulkEngine(
@@ -103,7 +100,6 @@ BULK_ENGINES: dict[str, BulkEngine] = {
         route_pallas=jump_route_pallas_fused,
         ingest_pallas=jump_ingest_pallas_fused,
         lookup_dyn=jump_lookup_dyn,
-        lookup_dyn_pallas=jump_bulk_lookup_pallas_dyn,
         lookup_vec=jump_lookup_vec,
     ),
 }

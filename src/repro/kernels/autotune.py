@@ -85,7 +85,7 @@ def tuned_block_rows(
     ``repeats + 1`` times per candidate on a cache miss (first call warms
     up/compiles, the rest are timed, best-of wins) and never on a hit.
     ``variant`` names the datapath being measured (e.g. ``fused`` vs
-    ``two_pass``) so verdicts are never reused across kernels with
+    ``jump_fused``) so verdicts are never reused across kernels with
     different cost profiles; ``CACHE_SCHEMA`` in the key invalidates stale
     verdicts when the kernels themselves change shape.
     """

@@ -1,4 +1,4 @@
-"""Pallas TPU kernels: bulk BinomialHash lookup (keys[N] -> buckets[N]).
+"""Pallas TPU kernels: bulk BinomialHash routing (keys[N] -> replicas[N]).
 
 TPU adaptation of the paper's scalar hot loop (DESIGN.md §3):
 * u32 integer arithmetic only (murmur3 fmix32 mixers) — the VPU has no
@@ -7,31 +7,22 @@ TPU adaptation of the paper's scalar hot loop (DESIGN.md §3):
 * the early-exit rejection loop becomes an ω-unrolled masked blend — on an
   8×128 lane grid divergent exits buy nothing;
 * keys are laid out (rows, 128) so each block is a native VREG tile; the
-  block row count is the VMEM tiling knob (default 512 rows = 256 KiB per
-  in/out block, comfortably inside the ~16 MiB VMEM budget with double
-  buffering).
+  block row count is the VMEM tiling knob.
 
-Only the **static-n** flavour (``binomial_bulk_lookup_2d`` /
-``binomial_bulk_lookup_pallas`` — ``n`` baked into the trace, masks
-constant-fold, any cluster resize retraces) is hand-written here.  The
-serving datapath kernels are instantiated from the generic factory
+The kernels are instantiated from the generic factory
 (``repro.kernels.fused.make_fused_kernels``) with the binomial lookup body
 — the SAME machinery every other ``BULK_ENGINES`` entry uses, so the
 constant-time certifier (``repro.analysis``) checks one uniform kernel
 shape per engine:
 
-* **dynamic-n** (``binomial_bulk_lookup_dyn_2d`` /
-  ``binomial_bulk_lookup_pallas_dyn``) — ``n`` rides in as a scalar-prefetch
-  operand (SMEM before the grid body runs); ``E``/``M`` are derived
-  in-kernel with the shift-or cascade, so elastic scale-up/down and replica
-  failures NEVER retrace;
 * **fused** (``binomial_route_fused_2d`` / ``binomial_route_pallas_fused``)
   — the dynamic-n lookup *and* the replacement-table failure divert in one
-  kernel (DESIGN.md §3, §7): ``[n_total, n_alive]`` scalar-prefetch SMEM,
-  packed removed-slot mask + (1, C) slots permutation as whole-block VMEM
-  operands, replica ids written in a single pass — no intermediate
-  ``buckets[N]`` HBM round-trip, ONE device dispatch per batch, storm-time
-  cost equal to steady-time cost;
+  kernel (DESIGN.md §3, §7): ``[n_total, n_alive]`` scalar-prefetch SMEM
+  (``E``/``M`` derived in-kernel, so elastic scale-up/down and replica
+  failures never retrace), packed removed-slot mask + (1, C) slots
+  permutation as whole-block VMEM operands, replica ids written in a single
+  pass — no intermediate ``buckets[N]`` HBM round-trip, ONE device dispatch
+  per batch, storm-time cost equal to steady-time cost;
 * **fused ingest** (``binomial_ingest_fused_2d`` /
   ``binomial_ingest_pallas_fused``) — the fused kernel with the session-key
   hash pulled inside too: raw u64 session ids ride in as (lo, hi) u32
@@ -44,87 +35,8 @@ so kernel == ref == scalar-u32-oracle is enforced transitively by tests.
 """
 from __future__ import annotations
 
-import functools
-
-import jax
-import jax.numpy as jnp
-import numpy as np
-from jax.experimental import pallas as pl
-
-from repro.core.binomial_jax import _unrolled_body
 from repro.core.memento_jax import _binomial_lookup_body
-from repro.kernels.fused import (  # noqa: F401  (re-exported for back-compat)
-    LANES,
-    _fused_route_body,
-    make_fused_kernels,
-)
-
-
-def _kernel(keys_ref, out_ref, *, n: int, omega: int):
-    keys = keys_ref[...]
-    l = (n - 1).bit_length()  # ct: host-ok — n is a static Python int
-    E = np.uint32(1 << l)  # ct: host-ok
-    M = np.uint32(1 << (l - 1))  # ct: host-ok
-    out = _unrolled_body(keys.astype(jnp.uint32), E, M, np.uint32(n), omega)
-    out_ref[...] = out.astype(jnp.int32)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("n", "omega", "block_rows", "interpret")
-)
-def binomial_bulk_lookup_2d(
-    keys: jax.Array,
-    n: int,
-    omega: int = 16,
-    block_rows: int = 512,
-    interpret: bool = False,
-) -> jax.Array:
-    """(rows, 128) uint32 keys -> (rows, 128) int32 buckets. rows % block_rows == 0."""
-    rows, lanes = keys.shape
-    if lanes != LANES:
-        raise ValueError(f"minor dim must be {LANES}, got {lanes}")
-    if rows % block_rows != 0:
-        raise ValueError(f"rows ({rows}) must be a multiple of block_rows ({block_rows})")
-    if n <= 1:
-        return jnp.zeros(keys.shape, dtype=jnp.int32)
-    grid = (rows // block_rows,)
-    return pl.pallas_call(
-        functools.partial(_kernel, n=n, omega=omega),
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
-        interpret=interpret,
-    )(keys.astype(jnp.uint32))
-
-
-def binomial_bulk_lookup_pallas(
-    keys: jax.Array,
-    n: int,
-    omega: int = 16,
-    block_rows: int = 512,
-    interpret: bool = False,
-) -> jax.Array:
-    """Any-shape int keys -> int32 buckets, padding/reshaping to kernel layout."""
-    flat = keys.reshape(-1).astype(jnp.uint32)
-    total = flat.shape[0]
-    tile = block_rows * LANES
-    padded = (total + tile - 1) // tile * tile
-    if padded != total:
-        flat = jnp.pad(flat, (0, padded - total))
-    out = binomial_bulk_lookup_2d(
-        flat.reshape(-1, LANES), n, omega=omega, block_rows=block_rows, interpret=interpret
-    )
-    return out.reshape(-1)[:total].reshape(keys.shape)
-
-
-# ---------------------------------------------------------------------------
-# serving-datapath kernels: ONE factory call replaces the hand-written
-# dynamic-n / fused / fused-ingest pallas_call plumbing (operand contracts,
-# jit static_argnames and numerics are identical by construction — the
-# factory body IS the former hand-written body, parameterised on the
-# lookup; tests pin kernel == jnp mirror == scalar oracle bit-for-bit).
-# ---------------------------------------------------------------------------
+from repro.kernels.fused import make_fused_kernels
 
 _KERNELS = make_fused_kernels(_binomial_lookup_body, "binomial")
 
@@ -135,6 +47,3 @@ binomial_route_pallas_fused = _KERNELS.route_pallas
 #: fused u64-id ingest twins — splitmix64 limb mix + lookup + divert (DESIGN §9)
 binomial_ingest_fused_2d = _KERNELS.ingest_2d
 binomial_ingest_pallas_fused = _KERNELS.ingest_pallas
-#: plain dynamic-n bulk lookup (the two-pass baseline's first dispatch)
-binomial_bulk_lookup_dyn_2d = _KERNELS.lookup_dyn_2d
-binomial_bulk_lookup_pallas_dyn = _KERNELS.lookup_dyn_pallas

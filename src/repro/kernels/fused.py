@@ -3,26 +3,26 @@ the replacement-table divert under ONE ``pallas_call``.
 
 This module is the machinery EVERY ``BULK_ENGINES`` entry gets its device
 kernels from (DESIGN.md §10) — the binomial paper engine included
-(``repro.kernels.binomial_hash`` instantiates it alongside its static-n
-extras): hand ``make_fused_kernels`` an unrolled jnp lookup body
+(``repro.kernels.binomial_hash`` instantiates it): hand
+``make_fused_kernels`` an unrolled jnp lookup body
 ``lookup(keys_u32, n_u32, omega) -> u32 buckets`` (usable inside a kernel:
-u32/f32 elementwise ops only, n <= 1 handled) and it returns the full
-kernel set —
+u32/f32 elementwise ops only, n <= 1 handled) and it returns the engine's
+one device datapath —
 
 * ``route_2d`` / ``route_pallas``   — fused lookup + divert, pre-hashed keys
   (``route_2d`` takes keys of whole tiles in any shape and does the layout
   in and out inside its one executable);
 * ``ingest_2d`` / ``ingest_pallas`` — the u64-id ingest twins (limb-wise
-  splitmix64 mixed in-register, then the same body);
-* ``lookup_dyn_2d`` / ``lookup_dyn_pallas`` — the plain dynamic-n bulk
-  lookup (the two-pass baseline's first dispatch).
+  splitmix64 mixed in-register, then the same body).
 
-All flavours keep the fleet state traced (scalar-prefetch ``[n_total,
-n_alive]``, whole-block VMEM mask + table), so fleet events never retrace;
-the divert body is the one ``_fused_route_body`` below with the lookup
-swapped, so every engine presents the SAME kernel shape — which is also
-what lets the constant-time certifier (``repro.analysis``) check one
-uniform structure per engine instead of per-engine plumbing.
+A healthy fleet skips the divert, so the route on a fleet with nothing
+removed is the plain lookup.  Both flavours keep the fleet state traced
+(scalar-prefetch ``[n_total, n_alive]``, whole-block VMEM mask + table), so
+fleet events never retrace; the divert body is the one
+``_fused_route_body`` below with the lookup swapped, so every engine
+presents the SAME kernel shape — which is also what lets the
+constant-time certifier (``repro.analysis``) check one uniform structure
+per engine instead of per-engine plumbing.
 """
 from __future__ import annotations
 
@@ -109,8 +109,6 @@ class FusedKernels(NamedTuple):
     route_pallas: Callable
     ingest_2d: Callable
     ingest_pallas: Callable
-    lookup_dyn_2d: Callable
-    lookup_dyn_pallas: Callable
 
 
 def _check_2d(rows: int, lanes: int, block_rows: int) -> None:
@@ -190,13 +188,10 @@ def make_fused_kernels(lookup, name: str) -> FusedKernels:
         )
         out_ref[...] = b.astype(jnp.int32)
 
-    def _kernel_lookup_dyn(n_ref, keys_ref, out_ref, *, omega):
-        keys = keys_ref[...].astype(jnp.uint32)
-        out_ref[...] = lookup(keys, n_ref[0].astype(jnp.uint32), omega).astype(
-            jnp.int32
-        )
-
-    def _route_grid_spec(block_rows, mask_shape, table_shape, n_blocks):
+    def _route_grid_spec(block_rows, mask_shape, table_shape, n_blocks,
+                         key_operands=1):
+        """Whole mask and table blocks, then ``key_operands`` tiles of keys
+        (the ingest kernel takes two: the id halves)."""
         return pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n_blocks,),
@@ -204,7 +199,8 @@ def make_fused_kernels(lookup, name: str) -> FusedKernels:
                 # whole-block mask/table: same small blocks every grid step
                 pl.BlockSpec(mask_shape, lambda i, s: (0, 0)),
                 pl.BlockSpec(table_shape, lambda i, s: (0, 0)),
-                pl.BlockSpec((block_rows, LANES), lambda i, s: (i, 0)),
+                *(pl.BlockSpec((block_rows, LANES), lambda i, s: (i, 0))
+                  for _ in range(key_operands)),
             ],
             out_specs=pl.BlockSpec((block_rows, LANES), lambda i, s: (i, 0)),
         )
@@ -298,16 +294,9 @@ def make_fused_kernels(lookup, name: str) -> FusedKernels:
             )
         _check_2d(rows, lanes, block_rows)
         _check_state_extents(packed_mask, table, n_words, n_slots)
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(rows // block_rows,),
-            in_specs=[
-                pl.BlockSpec(packed_mask.shape, lambda i, s: (0, 0)),
-                pl.BlockSpec(table.shape, lambda i, s: (0, 0)),
-                pl.BlockSpec((block_rows, LANES), lambda i, s: (i, 0)),
-                pl.BlockSpec((block_rows, LANES), lambda i, s: (i, 0)),
-            ],
-            out_specs=pl.BlockSpec((block_rows, LANES), lambda i, s: (i, 0)),
+        grid_spec = _route_grid_spec(
+            block_rows, packed_mask.shape, table.shape, rows // block_rows,
+            key_operands=2,
         )
         return pl.pallas_call(
             functools.partial(
@@ -338,46 +327,14 @@ def make_fused_kernels(lookup, name: str) -> FusedKernels:
         )
         return out.reshape(-1)[:total].reshape(ids_lo.shape)
 
-    @functools.partial(jax.jit, static_argnames=("omega", "block_rows", "interpret"))
-    def lookup_dyn_2d(keys, n, omega=16, block_rows=512, interpret=False):
-        """(rows, 128) u32 keys + traced n -> (rows, 128) i32 buckets."""
-        rows, lanes = keys.shape
-        _check_2d(rows, lanes, block_rows)
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(rows // block_rows,),
-            in_specs=[pl.BlockSpec((block_rows, LANES), lambda i, n_ref: (i, 0))],
-            out_specs=pl.BlockSpec((block_rows, LANES), lambda i, n_ref: (i, 0)),
-        )
-        return pl.pallas_call(
-            functools.partial(_kernel_lookup_dyn, omega=omega),
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
-            interpret=interpret,
-        )(jnp.asarray(n, jnp.uint32).reshape(1), keys.astype(jnp.uint32))
-
-    def lookup_dyn_pallas(keys, n, omega=16, block_rows=512, interpret=False):
-        """Any-shape int keys + traced n -> i32 buckets (recompile-free)."""
-        flat, total = _pad_flat(keys.reshape(-1).astype(jnp.uint32), block_rows)
-        out = lookup_dyn_2d(
-            flat.reshape(-1, LANES), n, omega=omega, block_rows=block_rows,
-            interpret=interpret,
-        )
-        return out.reshape(-1)[:total].reshape(keys.shape)
-
     for fn, suffix in (
         (route_2d, "route_fused_2d"),
         (route_pallas, "route_pallas_fused"),
         (ingest_2d, "ingest_fused_2d"),
         (ingest_pallas, "ingest_pallas_fused"),
-        (lookup_dyn_2d, "bulk_lookup_dyn_2d"),
-        (lookup_dyn_pallas, "bulk_lookup_pallas_dyn"),
     ):
         try:
             fn.__name__ = f"{name}_{suffix}"
         except AttributeError:  # jitted wrappers may refuse the rebrand
             pass
-    return FusedKernels(
-        route_2d, route_pallas, ingest_2d, ingest_pallas,
-        lookup_dyn_2d, lookup_dyn_pallas,
-    )
+    return FusedKernels(route_2d, route_pallas, ingest_2d, ingest_pallas)

@@ -26,6 +26,3 @@ jump_route_pallas_fused = _KERNELS.route_pallas
 #: fused u64-id ingest twins
 jump_ingest_fused_2d = _KERNELS.ingest_2d
 jump_ingest_pallas_fused = _KERNELS.ingest_pallas
-#: plain dynamic-n bulk lookup (the two-pass baseline's first dispatch)
-jump_bulk_lookup_dyn_2d = _KERNELS.lookup_dyn_2d
-jump_bulk_lookup_pallas_dyn = _KERNELS.lookup_dyn_pallas

@@ -8,53 +8,21 @@ in to intercept dispatches), and picks the Pallas kernel on TPU backends /
 interpret mode or the pure-jnp mirror elsewhere — model and serving code
 calls one function everywhere.
 
-Spec-era entry points:
-
 * ``route_bulk(keys, fleet, spec)``                — fused lookup + divert;
 * ``route_load_bulk(keys, fleet, counts, spec)``   — fused route + per-shard
   load accumulate (the observability tier's instrumented dispatch);
 * ``route_ingest_bulk(lo, hi, fleet, spec)``       — fused u64-id ingest;
-* ``lookup_bulk_dyn(keys, n, spec)``               — plain traced-n lookup;
+* ``route_replicas_bulk(keys, fleet, pspec)``      — R-way placement;
+* ``placement_diff_bulk(keys, old, new, pspec)``   — the migration diff;
 * ``make_sharded_route(mesh, spec)``               — the mesh-sharded route.
-
-The pre-spec binomial-only signatures (``binomial_route_bulk``,
-``binomial_route_ingest_bulk``, kwargs-style ``make_sharded_route``) remain
-as thin deprecation shims: warn once, build the equivalent spec, forward —
-bit-identical results (tests enforce).  The plain static-n
-``binomial_bulk_lookup`` / ``binomial_bulk_lookup_dyn`` helpers predate the
-fleet-state datapath and stay as-is.
 """
 from __future__ import annotations
-
-import warnings
 
 import jax
 import numpy as np
 
-from repro.core.binomial_jax import binomial_lookup_dyn
 from repro.core.bulk import FleetState, RouterSpec
-from repro.core.memento_jax import binomial_ingest_route, binomial_memento_route
-from repro.kernels.binomial_hash import (
-    binomial_bulk_lookup_pallas,
-    binomial_bulk_lookup_pallas_dyn,
-    binomial_ingest_pallas_fused,
-    binomial_route_pallas_fused,
-)
-from repro.kernels.ref import binomial_bulk_lookup_ref
 from repro.kernels.table_read import TableRead
-
-#: deprecation shims that already warned this process (warn once, not per
-#: batch; tests reset this to assert the warning fires)
-_warned: set[str] = set()
-
-
-def _warn_once(name: str, hint: str) -> None:
-    if name in _warned:
-        return
-    _warned.add(name)
-    warnings.warn(
-        f"{name} is deprecated; {hint}", DeprecationWarning, stacklevel=3
-    )
 
 
 def _engine(spec: RouterSpec):
@@ -238,78 +206,12 @@ def placement_diff_bulk(
     )
 
 
-def lookup_bulk_dyn(keys: jax.Array, n, spec: RouterSpec) -> jax.Array:
-    """Plain dynamic-n bulk lookup for the spec's engine: n is traced, so
-    elastic resize never retraces.  The two-pass baseline's first dispatch
-    (the divert then runs as a second dispatch over ``buckets[N]``)."""
-    eng = _engine(spec)
-    if eng.lookup_dyn is None:
-        raise ValueError(f"bulk engine '{spec.engine}' has no dynamic-n lookup")
-    if (spec.pallas_selected() or spec.interpret) and eng.lookup_dyn_pallas is not None:
-        return eng.lookup_dyn_pallas(
-            keys, n, omega=spec.omega,
-            block_rows=spec.resolved_block_rows(), interpret=spec.interpret,
-        )
-    return eng.lookup_dyn(keys, n, omega=spec.omega)
-
-
-# ---------------------------------------------------------------------------
-# static-n helpers (predate the fleet-state datapath; binomial-specific)
-# ---------------------------------------------------------------------------
-
-
-def binomial_bulk_lookup(
-    keys: jax.Array,
-    n: int,
-    omega: int = 16,
-    *,
-    use_pallas: bool | None = None,
-    interpret: bool = False,
-    block_rows: int = 512,
-) -> jax.Array:
-    """keys (any int shape) -> int32 buckets in [0, n).
-
-    use_pallas=None selects the kernel automatically (TPU backend only).
-    """
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    if use_pallas or interpret:
-        return binomial_bulk_lookup_pallas(
-            keys, n, omega=omega, block_rows=block_rows, interpret=interpret
-        )
-    return binomial_bulk_lookup_ref(keys, n, omega=omega)
-
-
-def binomial_bulk_lookup_dyn(
-    keys: jax.Array,
-    n,
-    omega: int = 16,
-    *,
-    use_pallas: bool | None = None,
-    interpret: bool = False,
-    block_rows: int = 512,
-) -> jax.Array:
-    """Dynamic-n bulk lookup: n is traced, so resize events never retrace.
-
-    Dispatches to the scalar-prefetch Pallas kernel on TPU (or in interpret
-    mode) and to the pure-jnp ``binomial_lookup_dyn`` elsewhere; both keep a
-    single compiled executable across arbitrary n.
-    """
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    if use_pallas or interpret:
-        return binomial_bulk_lookup_pallas_dyn(
-            keys, n, omega=omega, block_rows=block_rows, interpret=interpret
-        )
-    return binomial_lookup_dyn(keys, n, omega=omega)
-
-
 # ---------------------------------------------------------------------------
 # mesh-sharded datapath
 # ---------------------------------------------------------------------------
 
 
-def make_sharded_route(mesh, spec: RouterSpec | None = None, **legacy_kwargs):
+def make_sharded_route(mesh, spec: RouterSpec | None = None):
     """Build the mesh-sharded bulk routing callable (DESIGN.md §8).
 
     Returns ``route(keys, fleet) -> replica ids`` where 1-D ``keys`` are
@@ -326,25 +228,11 @@ def make_sharded_route(mesh, spec: RouterSpec | None = None, **legacy_kwargs):
     caller must not reuse it) — the serving tier enables this for key
     batches it uploads itself, making the sharded hot path allocation-free
     on the input side.
-
-    The pre-spec kwargs signature ``make_sharded_route(mesh, axis_name,
-    n_words=..., n_slots=..., ...)`` is a deprecation shim returning the
-    old 4-operand ``route(keys, packed_mask, table, state)`` callable.
     """
-    if spec is None and not legacy_kwargs:
+    if not isinstance(spec, RouterSpec):
         raise TypeError(
             "make_sharded_route requires a RouterSpec: "
             "make_sharded_route(mesh, RouterSpec(...))"
-        )
-    if spec is None or not isinstance(spec, RouterSpec):
-        # pre-spec call shapes: axis_name positional (bound to ``spec``),
-        # axis_name keyword (in ``legacy_kwargs``), or omitted entirely
-        axis_name = spec if spec is not None else legacy_kwargs.pop("axis_name", None)
-        return _make_sharded_route_legacy(mesh, axis_name, **legacy_kwargs)
-    if legacy_kwargs:
-        raise TypeError(
-            f"make_sharded_route(mesh, spec) takes no extra kwargs, got "
-            f"{sorted(legacy_kwargs)}; fold them into the RouterSpec"
         )
     return _make_sharded_route_impl(mesh, spec)
 
@@ -367,146 +255,3 @@ def _make_sharded_route_impl(mesh, spec: RouterSpec):
     )
     return jax.jit(sharded, donate_argnums=(0,) if spec.donate_keys else ())
 
-
-def _make_sharded_route_legacy(
-    mesh,
-    axis_name: str | None = None,
-    *,
-    n_words: int,
-    n_slots: int,
-    omega: int = 16,
-    use_pallas: bool | None = None,
-    interpret: bool = False,
-    block_rows: int = 512,
-    donate_keys: bool = False,
-):
-    """Pre-spec shim: kwargs -> RouterSpec, old 4-operand callable out."""
-    _warn_once(
-        "make_sharded_route(mesh, axis_name, n_words=..., ...)",
-        "pass a RouterSpec: make_sharded_route(mesh, spec) — the returned "
-        "route then takes (keys, FleetState)",
-    )
-    spec = _legacy_spec(
-        n_words, n_slots, omega, use_pallas, interpret, block_rows,
-        shard_axis="data" if axis_name is None else axis_name,
-        donate_keys=donate_keys,
-    )
-    route = _make_sharded_route_impl(mesh, spec)
-
-    def legacy_route(keys, packed_mask, table, state):
-        return route(keys, _legacy_fleet(packed_mask, table, state, spec))
-
-    return legacy_route
-
-
-# ---------------------------------------------------------------------------
-# pre-spec fused entry points — thin deprecation shims over the spec path
-# ---------------------------------------------------------------------------
-
-
-def _legacy_spec(
-    n_words: int, n_slots: int, omega, use_pallas, interpret, block_rows,
-    **extra,
-) -> RouterSpec:
-    """Pre-spec kwargs -> the equivalent ``RouterSpec``.
-
-    ``capacity`` is the next power of two >= ``n_slots`` — pre-spec callers
-    could pass any slot bound (the jnp path ignored it, the Pallas gather
-    cascade just scanned it), and rounding up is result-identical: the
-    extra mask words are zero padding, the extra cascade entries are never
-    selected (every index < n_total <= n_slots).  ``n_words`` must match
-    what the caller's ``n_slots`` implies — the contract every pre-spec
-    call site followed.
-    """
-    from repro.core.bits import next_pow2
-
-    spec = RouterSpec(
-        engine="binomial", capacity=next_pow2(max(1, n_slots)), omega=omega,
-        use_pallas=use_pallas, interpret=interpret, block_rows=block_rows,
-        **extra,
-    )
-    from repro.core.memento_jax import mask_words
-
-    if n_words != mask_words(n_slots):
-        raise ValueError(
-            f"n_words ({n_words}) disagrees with n_slots {n_slots} "
-            f"(expected {mask_words(n_slots)})"
-        )
-    return spec
-
-
-def _legacy_fleet(packed_mask, table, state, spec: RouterSpec) -> FleetState:
-    """Legacy operands -> ``FleetState``, zero-padded out to the rounded-up
-    capacity's extents when the caller packed for a non-pow2 ``n_slots``
-    (the padding is never read: every gathered index < n_total <= the
-    caller's real slot payload, and zero mask words mean never-removed)."""
-    import jax.numpy as jnp
-
-    if table.shape[1] < spec.n_slots:
-        table = jnp.pad(
-            jnp.asarray(table), ((0, 0), (0, spec.n_slots - table.shape[1]))
-        )
-    if packed_mask.shape[1] < spec.n_words:
-        packed_mask = jnp.pad(
-            jnp.asarray(packed_mask),
-            ((0, 0), (0, spec.n_words - packed_mask.shape[1])),
-        )
-    return FleetState(packed_mask, table, state)
-
-
-def binomial_route_bulk(
-    keys: jax.Array,
-    packed_mask: jax.Array,
-    table: jax.Array,
-    state: jax.Array,
-    *,
-    n_words: int,
-    n_slots: int,
-    omega: int = 16,
-    use_pallas: bool | None = None,
-    interpret: bool = False,
-    block_rows: int = 512,
-) -> jax.Array:
-    """Deprecated pre-spec signature of the fused binomial route.
-
-    Forwards to ``route_bulk(keys, FleetState(...), RouterSpec(...))`` —
-    bit-identical results (tests enforce).  ``n_words`` is implied by
-    ``n_slots`` and only validated here.
-    """
-    _warn_once(
-        "binomial_route_bulk",
-        "use route_bulk(keys, FleetState(packed, table, state), "
-        "RouterSpec(engine='binomial', capacity=n_slots, ...))",
-    )
-    spec = _legacy_spec(n_words, n_slots, omega, use_pallas, interpret, block_rows)
-    return route_bulk(keys, _legacy_fleet(packed_mask, table, state, spec), spec)
-
-
-def binomial_route_ingest_bulk(
-    ids_lo: jax.Array,
-    ids_hi: jax.Array,
-    packed_mask: jax.Array,
-    table: jax.Array,
-    state: jax.Array,
-    *,
-    n_words: int,
-    n_slots: int,
-    omega: int = 16,
-    use_pallas: bool | None = None,
-    interpret: bool = False,
-    block_rows: int = 512,
-) -> jax.Array:
-    """Deprecated pre-spec signature of the fused binomial u64-id ingest.
-
-    Forwards to ``route_ingest_bulk`` — bit-identical results (tests
-    enforce); operand contract as ``binomial_route_bulk``.
-    """
-    _warn_once(
-        "binomial_route_ingest_bulk",
-        "use route_ingest_bulk(ids_lo, ids_hi, FleetState(packed, table, "
-        "state), RouterSpec(engine='binomial', capacity=n_slots, ...))",
-    )
-    spec = _legacy_spec(n_words, n_slots, omega, use_pallas, interpret, block_rows)
-    return route_ingest_bulk(
-        ids_lo, ids_hi, _legacy_fleet(packed_mask, table, state, spec), spec
-    )

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.binomial_jax import _unrolled_body, mix64_lo32
-from repro.core.memento_jax import _route_table_impl
+from repro.core.memento_jax import fused_route_impl
 
 
 def binomial_bulk_lookup_ref(keys: jax.Array, n: int, omega: int = 16) -> jax.Array:
@@ -43,7 +43,7 @@ def binomial_route_ref(
                  slower cascade, fine for an eager test oracle).
     """
     packed_mask = jnp.asarray(packed_mask, jnp.uint32)
-    return _route_table_impl(
+    return fused_route_impl(
         jnp.asarray(keys),
         packed_mask,
         jnp.asarray(table, jnp.int32),

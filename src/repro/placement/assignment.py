@@ -8,33 +8,16 @@ tests check against the paper's guarantees (movement fraction ~ delta/n).
 ``MovementPlan`` is the ONE movement-accounting type: canonically a thin
 view over a before/after placement diff (``from_diff`` — host arrays here,
 the device migration diff in ``repro.placement.store``), with the eager
-``Move`` list materialised lazily on demand.  The pre-diff eager
-constructor ``MovementPlan(moves, total_keys)`` remains as a deprecation
-shim (warn-once, like the pre-spec shims in ``repro.kernels.ops``).
+``Move`` list materialised lazily on demand.
 """
 from __future__ import annotations
 
-import warnings
 from dataclasses import dataclass
 from typing import Sequence
 
 import numpy as np
 
 from repro.core import make
-
-#: deprecation shims that already warned this process (warn once, not per
-#: plan; tests reset this to assert the warning fires)
-_warned: set[str] = set()
-
-
-def _warn_once(name: str, hint: str) -> None:
-    if name in _warned:
-        return
-    _warned.add(name)
-    warnings.warn(
-        f"{name} is deprecated; {hint}", DeprecationWarning, stacklevel=3
-    )
-
 
 @dataclass(frozen=True)
 class Move:
@@ -54,26 +37,15 @@ class MovementPlan:
     accounting type.
     """
 
-    def __init__(self, moves=None, total_keys: int | None = None, *,
-                 keys=None, before=None, after=None, moved=None):
-        if before is not None:
-            self._keys = np.asarray(keys)
-            self._before = np.asarray(before)
-            self._after = np.asarray(after)
-            if moved is None:
-                moved = self._before != self._after
-            self._moved = np.asarray(moved, bool)
-            self._moves: list[Move] | None = None
-            self.total_keys = int(self._keys.size)
-        else:
-            _warn_once(
-                "MovementPlan(moves, total_keys)",
-                "build plans from the placement diff: "
-                "MovementPlan.from_diff(keys, before, after)",
-            )
-            self._moves = list(moves or [])
-            self.total_keys = int(total_keys or 0)
-            self._keys = self._before = self._after = self._moved = None
+    def __init__(self, *, keys, before, after, moved=None):
+        self._keys = np.asarray(keys)
+        self._before = np.asarray(before)
+        self._after = np.asarray(after)
+        if moved is None:
+            moved = self._before != self._after
+        self._moved = np.asarray(moved, bool)
+        self._moves: list[Move] | None = None
+        self.total_keys = int(self._keys.size)
 
     @classmethod
     def from_diff(cls, keys, before, after, moved=None) -> "MovementPlan":
@@ -84,9 +56,7 @@ class MovementPlan:
     # -- accounting ----------------------------------------------------------
     @property
     def moved_count(self) -> int:
-        if self._moved is not None:
-            return int(self._moved.sum())
-        return len(self._moves)
+        return int(self._moved.sum())
 
     @property
     def moves(self) -> list[Move]:
@@ -104,14 +74,10 @@ class MovementPlan:
         return self.moved_count / max(self.total_keys, 1)
 
     def destinations(self) -> set[int]:
-        if self._moved is not None:
-            return set(np.unique(self._after[self._moved]).tolist())
-        return {m.dst for m in self._moves}
+        return set(np.unique(self._after[self._moved]).tolist())
 
     def sources(self) -> set[int]:
-        if self._moved is not None:
-            return set(np.unique(self._before[self._moved]).tolist())
-        return {m.src for m in self._moves}
+        return set(np.unique(self._before[self._moved]).tolist())
 
 
 class Assignment:

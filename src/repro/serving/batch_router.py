@@ -39,10 +39,6 @@ replicated, per-device fused dispatch, no collectives — DESIGN.md §8) for
 near-linear keys/s scaling.  ``block_rows=None`` engages the measure-once
 persistent autotuner on Pallas backends (``repro.kernels.autotune``).
 
-The pre-fusion two-stage pipeline (``lookup_bulk_dyn`` then
-``memento_remap_table`` — two dispatches, ``buckets[N]`` materialised in
-HBM between them) is kept behind ``fused=False`` as the benchmark baseline.
-
 Configuration rides in one frozen ``RouterSpec`` (``BatchRouter(16,
 spec)``); the individual keyword arguments remain as sugar that builds the
 spec (``BatchRouter(16, engine="jump", capacity=128)``) — passing both is
@@ -63,7 +59,6 @@ import numpy as np
 
 from repro.core import bits
 from repro.core.bulk import FleetState, RouterSpec
-from repro.core.memento_jax import memento_remap_table
 from repro.core.registry import make_bulk
 from repro.kernels import autotune
 from repro.kernels import ops
@@ -103,8 +98,6 @@ class BatchRouter:
         spec: RouterSpec | None = None,
         *,
         mesh=None,
-        fused: bool = True,
-        max_chain: int = 4096,
         engine=_UNSET,
         capacity=_UNSET,
         omega=_UNSET,
@@ -160,18 +153,6 @@ class BatchRouter:
             raise ValueError(
                 f"n_replicas ({n_replicas}) exceeds capacity ({spec.capacity})"
             )
-        if max_chain < 0:
-            raise ValueError(
-                f"max_chain must be >= 0, got {max_chain}; note the table "
-                "resolution has a hard two-redirect bound, so max_chain only "
-                "labels the (unused) chain budget — any value >= 0 routes "
-                "identically"
-            )
-        if mesh is not None and not fused:
-            raise ValueError(
-                "the two-pass baseline (fused=False) is single-host only; "
-                "the mesh-sharded datapath always runs the fused kernel"
-            )
         if spec.donate_keys and mesh is None:
             raise ValueError(
                 "donate_keys applies to the mesh-sharded datapath only; "
@@ -182,10 +163,6 @@ class BatchRouter:
         # control-plane truth: the engine's u32 scalar oracle + u32 table
         # resolution (the device semantics); omega mirrors the device
         # operand so scalar == batch holds for non-default values too.
-        # max_chain is INERT under table resolution (hard two-redirect
-        # bound) — accepted and validated for API stability with the
-        # chain-mode library flavour, forwarded only so the control plane
-        # would stay bit-exact if flipped to chain mode.
         # allow_empty: an all-failed fleet is a queryable state the route
         # entry points answer with FleetUnavailableError — the failure event
         # itself is never refused (DESIGN.md §12)
@@ -194,13 +171,10 @@ class BatchRouter:
             engine=self._bulk.scalar_engine,
             chain_bits=32,
             omega=spec.omega,
-            max_chain=max_chain,
             resolve="table",
             allow_empty=True,
             zones=zones,
         )
-        self.max_chain = max_chain
-        self.fused = fused
         self.mesh = mesh
         self._n_shards = 1 if mesh is None else int(mesh.shape[spec.shard_axis])
         #: per-batch-rows resolved block size (autotuner results memoised)
@@ -213,11 +187,9 @@ class BatchRouter:
         # canonical host mirror of the device fleet state, mutated
         # incrementally on fleet events; the device twin is pinned once
         # here, then refreshed only on fleet events — never rebuilt or
-        # re-uploaded per batch.  The two-pass baseline additionally keeps
-        # the n scalar its first dispatch reads.
+        # re-uploaded per batch.
         self._fleet_host = FleetState.pack(self.domain, spec.capacity)
         self._fleet_dev: FleetState | None = None
-        self._n_dev: jax.Array | None = None
         #: attached observability LoadMonitor (None = uninstrumented): when
         #: set, the fused dispatch runs the instrumented route so the
         #: per-shard bincount rides in the SAME device pass as the routing
@@ -325,12 +297,7 @@ class BatchRouter:
             # as ONE wholesale resync + upload on exit
             self._state_dirty = True
             return
-        if self.fused:
-            self._fleet_dev = self._device_put(self._fleet_host)
-        else:
-            self._fleet_dev, self._n_dev = self._device_put(
-                (self._fleet_host, np.uint32(self.domain.total_count))
-            )
+        self._fleet_dev = self._device_put(self._fleet_host)
 
     def _set_removed_bit(self, replica: int, removed: bool) -> None:
         """Incremental fleet-event update: flip one mask bit, re-pin."""
@@ -402,15 +369,15 @@ class BatchRouter:
                 finally:
                     self._load_monitor = monitor
 
-            flavour = "fused" if self.fused else "two_pass"
-            if self.spec.engine != "binomial":
-                flavour = f"{self.spec.engine}_{flavour}"
+            # the variant string keys persisted verdicts: keep it stable
+            variant = ("fused" if self.spec.engine == "binomial"
+                       else f"{self.spec.engine}_fused")
             self._tuned_rows[rows] = autotune.tuned_block_rows(
                 jax.default_backend(),
                 rows,
                 self.spec.capacity,
                 measure,
-                variant=flavour,
+                variant=variant,
             )
         return self._tuned_rows[rows]
 
@@ -456,17 +423,12 @@ class BatchRouter:
         """Instrument the fused dispatch with the monitor's device-side
         load accumulator (``ops.route_load_bulk``).  Replica ids stay
         bit-exact with the uninstrumented path; the accumulate is folded
-        into the same single dispatch.  Single-host fused datapath only —
-        the mesh-sharded and two-pass paths are not instrumented."""
+        into the same single dispatch.  Single-host only — the mesh-sharded
+        path is not instrumented."""
         if self.mesh is not None:
             raise ValueError(
                 "load monitoring is single-host only; the mesh-sharded "
                 "datapath is not instrumented"
-            )
-        if not self.fused:
-            raise ValueError(
-                "load monitoring requires the fused datapath "
-                "(fused=False is the two-pass benchmark baseline)"
             )
         self._load_monitor = monitor
 
@@ -476,32 +438,19 @@ class BatchRouter:
     def _dispatch(self, keys_u32, block_rows: int) -> jax.Array:
         """Single-host dispatch of one batch at a given tiling."""
         spec = self._dispatch_spec(block_rows)
-        if self.fused:
-            monitor = self._load_monitor
-            if monitor is not None:
-                # the instrumented route: same dispatch count, the per-shard
-                # bincount rides along (always the fused jnp pass, which
-                # has no Pallas twin; bit-exact with the kernel, as tests
-                # enforce)
-                n_keys = int(np.size(keys_u32))
-                out, counts = ops.route_load_bulk(
-                    keys_u32, self._fleet_dev, monitor.counts_dev, spec,
-                    sample_shift=monitor.effective_shift(n_keys),
-                )
-                monitor.note_dispatch(counts, n_keys)
-                return out
-            return ops.route_bulk(keys_u32, self._fleet_dev, spec)
-        # pre-fusion two-pass pipeline (benchmark baseline): buckets[N]
-        # round-trips through HBM between two dispatches
-        buckets = ops.lookup_bulk_dyn(keys_u32, self._n_dev, spec)
-        return memento_remap_table(
-            keys_u32,
-            buckets,
-            self._fleet_dev.packed,
-            self._fleet_dev.table,
-            self._fleet_dev.state,
-            n_words=self.spec.n_words,
-        )
+        monitor = self._load_monitor
+        if monitor is not None:
+            # the instrumented route: same dispatch count, the per-shard
+            # bincount rides along (always the fused jnp pass, which has no
+            # Pallas twin; bit-exact with the kernel, as tests enforce)
+            n_keys = int(np.size(keys_u32))
+            out, counts = ops.route_load_bulk(
+                keys_u32, self._fleet_dev, monitor.counts_dev, spec,
+                sample_shift=monitor.effective_shift(n_keys),
+            )
+            monitor.note_dispatch(counts, n_keys)
+            return out
+        return ops.route_bulk(keys_u32, self._fleet_dev, spec)
 
     def _route_sharded(self, keys_u32, block_rows: int) -> jax.Array:
         """Mesh-sharded dispatch: keys split over the mesh axis, fleet state

@@ -256,7 +256,6 @@ class LifecycleManager:
             engine=self.router._bulk.scalar_engine,
             chain_bits=32,
             omega=self.router.spec.omega,
-            max_chain=self.router.max_chain,
             resolve="table",
             allow_empty=True,
             zones=self.router.domain.zones,

@@ -1,28 +1,44 @@
-"""Batched recompile-free routing datapath: dynamic-n kernel, device Memento
-remap, BatchRouter — bit-exactness vs the scalar oracles and no-retrace
-guarantees across fleet events."""
+"""Batched recompile-free routing datapath: the route kernel on healthy
+fleets of every size, device Memento remap, BatchRouter — bit-exactness vs
+the scalar oracles and no-retrace guarantees across fleet events."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import MementoWrapper, make
 from repro.core.binomial import binomial_lookup32
-from repro.core.binomial_jax import binomial_lookup_dyn
-from repro.core.memento_jax import memento_remap, memento_remap_table
-from repro.kernels.binomial_hash import (
-    binomial_bulk_lookup_dyn_2d,
-    binomial_bulk_lookup_pallas,
-    binomial_bulk_lookup_pallas_dyn,
-)
+from repro.core.binomial_jax import binomial_lookup_dyn, binomial_lookup_vec
+from repro.core.bulk import FleetState
+from repro.core.memento_jax import mask_words, memento_remap, pack_removed_mask
+from repro.kernels.binomial_hash import binomial_route_fused_2d
 from repro.serving.batch_router import BatchRouter
 from repro.serving.router import SessionRouter
 
 RNG = np.random.default_rng(11)
+#: the healthy-fleet kernel tests' capacity: holds every n they route
+CAP = 1024
 
 
 # ---------------------------------------------------------------------------
-# dynamic-n Pallas kernel
+# the route kernel on a healthy fleet: the plain lookup at a traced n
 # ---------------------------------------------------------------------------
+
+
+def _healthy_fleet(n):
+    """A fleet of ``n`` slots, none removed, at capacity ``CAP``."""
+    table = np.zeros((1, CAP), np.int32)
+    table[0, :n] = np.arange(n)
+    return FleetState(pack_removed_mask([], CAP), table,
+                      np.array([n, n], np.uint32))
+
+
+def _route_healthy(keys, n):
+    """(16, 128) keys through ``route_2d``, one 8-row tile a grid step."""
+    f = _healthy_fleet(n)
+    return binomial_route_fused_2d(
+        keys, f.packed, f.table, f.state, mask_words(CAP), CAP,
+        block_rows=8, interpret=True,
+    )
 
 
 @pytest.mark.parametrize("k", [1, 2, 3, 5, 8])
@@ -32,28 +48,29 @@ def test_dyn_kernel_pow2_boundaries(k, delta):
     n = (1 << k) + delta
     if n < 2:
         pytest.skip("n < 2 is the degenerate single-bucket case")
-    keys = jnp.asarray(RNG.integers(0, 2**32, size=(512,), dtype=np.uint32))
-    out = np.asarray(binomial_bulk_lookup_pallas_dyn(keys, n, interpret=True, block_rows=2))
-    scal = [binomial_lookup32(int(x), n) for x in np.asarray(keys)]
+    keys = jnp.asarray(RNG.integers(0, 2**32, size=(16, 128), dtype=np.uint32))
+    out = np.asarray(_route_healthy(keys, n)).reshape(-1)
+    scal = [binomial_lookup32(int(x), n) for x in np.asarray(keys).reshape(-1)]
     np.testing.assert_array_equal(out, scal)
 
 
 @pytest.mark.parametrize("n", [1, 2, 7, 37, 128, 1000])
 def test_dyn_kernel_matches_static(n):
+    """The kernel at a traced n == the jnp lookup with n baked in."""
     keys = jnp.asarray(RNG.integers(0, 2**32, size=(16, 128), dtype=np.uint32))
-    dyn = binomial_bulk_lookup_pallas_dyn(keys, n, interpret=True, block_rows=8)
-    static = binomial_bulk_lookup_pallas(keys, n, interpret=True, block_rows=8)
+    dyn = _route_healthy(keys, n)
+    static = binomial_lookup_vec(keys, n)
     np.testing.assert_array_equal(np.asarray(dyn), np.asarray(static))
 
 
 def test_dyn_kernel_no_retrace_across_resizes():
     """One executable serves every cluster size (the recompile-free core)."""
     keys = jnp.asarray(RNG.integers(0, 2**32, size=(16, 128), dtype=np.uint32))
-    binomial_bulk_lookup_dyn_2d(keys, 3, interpret=True, block_rows=8)
-    before = binomial_bulk_lookup_dyn_2d._cache_size()
+    _route_healthy(keys, 3)
+    before = binomial_route_fused_2d._cache_size()
     for n in [4, 7, 8, 9, 64, 1000, 2, 5]:  # crosses several pow2 boundaries
-        binomial_bulk_lookup_dyn_2d(keys, n, interpret=True, block_rows=8)
-    assert binomial_bulk_lookup_dyn_2d._cache_size() == before
+        _route_healthy(keys, n)
+    assert binomial_route_fused_2d._cache_size() == before
 
 
 # ---------------------------------------------------------------------------
@@ -171,8 +188,8 @@ def test_batch_router_failure_reroutes_minimally():
 
 
 def test_batch_router_non_default_omega_max_chain_parity():
-    """omega/max_chain reach the scalar oracle too — scalar == batch holds."""
-    r = BatchRouter(9, omega=2, max_chain=64)
+    """omega reaches the scalar oracle too — scalar == batch holds."""
+    r = BatchRouter(9, omega=2)
     r.fail(1)
     keys = RNG.integers(0, 2**64, size=(4096,), dtype=np.uint64)
     out = r.route_keys(keys)
@@ -203,39 +220,27 @@ def test_batch_router_capacity_guard():
 
 @pytest.mark.slow
 def test_batch_router_1m_keys_zero_retrace_acceptance():
-    """Acceptance: 1M-key batches through the FUSED Pallas kernel (and the
-    two-pass baseline) with zero retraces across >= 8 scale/fail events,
-    bit-exact with the scalar router."""
-    from repro.kernels.binomial_hash import binomial_route_fused_2d
-
+    """Acceptance: 1M-key batches through the FUSED Pallas kernel with zero
+    retraces across >= 8 scale/fail events, bit-exact with the scalar
+    router."""
     router = BatchRouter(8, interpret=True)  # force the fused Pallas kernel (CPU)
-    two_pass = BatchRouter(8, interpret=True, fused=False)
     scalar = SessionRouter(8, engine="binomial32", chain_bits=32, resolve="table")
     keys = RNG.integers(0, 2**64, size=(1 << 20,), dtype=np.uint64)
 
     router.route_keys(keys)  # compile once
-    two_pass.route_keys(keys)
     fused_before = binomial_route_fused_2d._cache_size()
-    kernel_before = binomial_bulk_lookup_dyn_2d._cache_size()
-    remap_before = memento_remap_table._cache_size()
 
     sample = RNG.choice(len(keys), size=512, replace=False)
     assert len(EVENTS) >= 8
     for ev, arg in EVENTS:
         _apply_events(router, [(ev, arg)])
-        _apply_events(two_pass, [(ev, arg)])
         _apply_events(scalar, [(ev, arg)])
         out = np.asarray(router.route_keys(keys))
         assert out.shape == keys.shape
         expect = [scalar.domain.locate(int(keys[j])) for j in sample]
         np.testing.assert_array_equal(out[sample], expect)
-        np.testing.assert_array_equal(
-            np.asarray(two_pass.route_keys(keys))[sample], expect
-        )
 
     assert binomial_route_fused_2d._cache_size() == fused_before
-    assert binomial_bulk_lookup_dyn_2d._cache_size() == kernel_before
-    assert memento_remap_table._cache_size() == remap_before
 
 
 # ---------------------------------------------------------------------------

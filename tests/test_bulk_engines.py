@@ -1,16 +1,14 @@
 """The engine-agnostic bulk routing API (DESIGN.md §10): the jump device
 engine's bit-exactness chain (scalar oracle == jnp mirror == Pallas
-kernel == BatchRouter), RouterSpec construction semantics, the deprecation
-shims' bit-identical forwarding, and the curated ``repro`` public surface."""
+kernel == BatchRouter), RouterSpec construction semantics, and the curated
+``repro`` public surface."""
 import dataclasses
-import warnings
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import bits
-from repro.core.bulk import FleetState, RouterSpec
+from repro.core.bulk import RouterSpec
 from repro.core.jump_jax import (
     _F_TOP,
     JumpHash32,
@@ -21,11 +19,7 @@ from repro.core.jump_jax import (
     rn_top_quotient,
 )
 from repro.core.memento_jax import mask_words, pack_removed_mask, pack_table
-from repro.kernels import ops
-from repro.kernels.jump_hash import (
-    jump_bulk_lookup_pallas_dyn,
-    jump_route_pallas_fused,
-)
+from repro.kernels.jump_hash import jump_route_pallas_fused
 from repro.serving.batch_router import BatchRouter
 from repro.serving.router import SessionRouter, hash_session_ids
 
@@ -59,9 +53,13 @@ def test_jump_lookup_pow2_boundaries(k, delta):
     keys = RNG.integers(0, 2**32, size=(512,), dtype=np.uint32)
     dyn = np.asarray(jump_lookup_dyn(jnp.asarray(keys), np.uint32(n)))
     vec = np.asarray(jump_lookup_vec(jnp.asarray(keys), n))
+    # the route kernel on a healthy fleet skips its divert: the plain lookup
+    packed, table, state = _oracle_state(_jump_oracle(n), capacity=512)
     pal = np.asarray(
-        jump_bulk_lookup_pallas_dyn(
-            jnp.asarray(keys), np.uint32(n), interpret=True, block_rows=2
+        jump_route_pallas_fused(
+            jnp.asarray(keys), jnp.asarray(packed), jnp.asarray(table),
+            jnp.asarray(state), mask_words(512), 512, interpret=True,
+            block_rows=2,
         )
     )
     scal = [jump_lookup32(int(x), n) for x in keys]
@@ -224,124 +222,6 @@ def test_router_spec_conflicts_and_validation():
         RouterSpec().capacity = 128
     assert RouterSpec(capacity=64).n_words == 2
     assert RouterSpec(capacity=64).n_slots == 64
-
-
-# ---------------------------------------------------------------------------
-# deprecation shims: bit-identical forwarding, warn once
-# ---------------------------------------------------------------------------
-
-
-def _shim_operands():
-    oracle = SessionRouter(12, engine="binomial32", chain_bits=32, resolve="table")
-    for r in (2, 7):
-        oracle.fail(r)
-    packed, table, state = _oracle_state(oracle)
-    return (
-        jnp.asarray(packed), jnp.asarray(table), jnp.asarray(state),
-    )
-
-
-def test_binomial_route_bulk_shim_is_bit_identical():
-    packed, table, state = _shim_operands()
-    keys = jnp.asarray(RNG.integers(0, 2**32, size=(2048,), dtype=np.uint32))
-    ops._warned.clear()
-    with pytest.warns(DeprecationWarning, match="binomial_route_bulk"):
-        old = ops.binomial_route_bulk(
-            keys, packed, table, state,
-            n_words=mask_words(64), n_slots=64, use_pallas=False,
-        )
-    new = ops.route_bulk(
-        keys,
-        FleetState(packed, table, state),
-        RouterSpec(engine="binomial", capacity=64, use_pallas=False),
-    )
-    np.testing.assert_array_equal(np.asarray(old), np.asarray(new))
-    # warn ONCE: the second legacy call passes silently
-    with warnings.catch_warnings():
-        warnings.simplefilter("error", DeprecationWarning)
-        ops.binomial_route_bulk(
-            keys, packed, table, state,
-            n_words=mask_words(64), n_slots=64, use_pallas=False,
-        )
-
-
-def test_binomial_route_ingest_bulk_shim_is_bit_identical():
-    packed, table, state = _shim_operands()
-    ids = RNG.integers(0, 2**64, size=(2048,), dtype=np.uint64)
-    lo, hi = bits.np_split64(ids)
-    ops._warned.clear()
-    with pytest.warns(DeprecationWarning, match="binomial_route_ingest_bulk"):
-        old = ops.binomial_route_ingest_bulk(
-            jnp.asarray(lo), jnp.asarray(hi), packed, table, state,
-            n_words=mask_words(64), n_slots=64, use_pallas=False,
-        )
-    new = ops.route_ingest_bulk(
-        jnp.asarray(lo), jnp.asarray(hi),
-        FleetState(packed, table, state),
-        RouterSpec(engine="binomial", capacity=64, use_pallas=False),
-    )
-    np.testing.assert_array_equal(np.asarray(old), np.asarray(new))
-
-
-def test_make_sharded_route_shim_is_bit_identical():
-    import jax
-
-    mesh = jax.make_mesh((1,), ("data",))
-    packed, table, state = _shim_operands()
-    keys = jnp.asarray(RNG.integers(0, 2**32, size=(1024,), dtype=np.uint32))
-    spec = RouterSpec(engine="binomial", capacity=64, use_pallas=False)
-    ops._warned.clear()
-    with pytest.warns(DeprecationWarning, match="make_sharded_route"):
-        legacy = ops.make_sharded_route(
-            mesh, "data", n_words=mask_words(64), n_slots=64, use_pallas=False
-        )
-    old = legacy(keys, packed, table, state)
-    new = ops.make_sharded_route(mesh, spec)(
-        keys, FleetState(packed, table, state)
-    )
-    np.testing.assert_array_equal(np.asarray(old), np.asarray(new))
-
-
-def test_shim_accepts_non_pow2_n_slots_on_every_path():
-    """Pre-spec callers could pack for any slot bound (lane-padded, not
-    pow2-padded); the shim re-pads to the rounded-up capacity, so both
-    dispatch flavours keep returning the pre-spec results."""
-    oracle = SessionRouter(
-        200, engine="binomial32", chain_bits=32, resolve="table"
-    )
-    for r in (3, 77, 150):
-        oracle.fail(r)
-    dom = oracle.domain
-    packed = pack_removed_mask(dom.removed, 300)  # width 128 words
-    table = pack_table(dom.replacement_table, 300)  # width 384 < pow2(300)
-    state = np.array([dom.total_count, dom.alive_count], np.uint32)
-    keys = jnp.asarray(RNG.integers(0, 2**32, size=(1024,), dtype=np.uint32))
-    kw = dict(n_words=mask_words(300), n_slots=300)
-    ops._warned.clear()
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        jnp_out = ops.binomial_route_bulk(
-            keys, packed, table, state, use_pallas=False, **kw
-        )
-        pal_out = ops.binomial_route_bulk(
-            keys, packed, table, state, interpret=True, block_rows=4, **kw
-        )
-    expect = [dom.locate(int(k)) for k in np.asarray(keys)]
-    np.testing.assert_array_equal(np.asarray(jnp_out), expect)
-    np.testing.assert_array_equal(np.asarray(pal_out), expect)
-
-
-def test_shim_rejects_inconsistent_n_words():
-    packed, table, state = _shim_operands()
-    keys = jnp.asarray(RNG.integers(0, 2**32, size=(128,), dtype=np.uint32))
-    ops._warned.clear()
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        with pytest.raises(ValueError, match="disagrees with n_slots"):
-            ops.binomial_route_bulk(
-                keys, packed, table, state, n_words=7, n_slots=64,
-                use_pallas=False,
-            )
 
 
 # ---------------------------------------------------------------------------

@@ -178,7 +178,7 @@ def test_every_registered_engine_certifies():
     for name in BULK_ENGINES:
         assert by_engine[name] >= {
             "route/jnp", "ingest/jnp", "lookup_dyn/jnp",
-            "route/pallas", "ingest/pallas", "lookup_dyn/pallas",
+            "route/pallas", "ingest/pallas",
         }
 
 
@@ -360,7 +360,6 @@ def test_cli_fails_on_seeded_violation_engine(capsys, monkeypatch):
         route_pallas=None,
         ingest_pallas=None,
         lookup_dyn=None,
-        lookup_dyn_pallas=None,
     )
     monkeypatch.setitem(registry.BULK_ENGINES, "broken", broken)
     assert (

@@ -13,13 +13,11 @@ from repro.core.memento_jax import (
     pack_removed_mask,
     pack_table,
 )
-from repro.kernels import ops
 from repro.kernels.binomial_hash import (
     binomial_route_fused_2d,
     binomial_route_pallas_fused,
 )
 from repro.kernels.ref import binomial_route_ref
-from repro.serving import batch_router as br_mod
 from repro.serving.batch_router import BatchRouter
 from repro.serving.router import SessionRouter
 
@@ -125,7 +123,7 @@ def test_fused_kernel_randomized_fail_recover_stream():
 
 
 def test_fused_paths_agree_with_ref_and_two_pass():
-    """pallas(interpret) == jnp jit == unjitted ref == two-pass BatchRouter."""
+    """pallas(interpret) == jnp jit == unjitted ref."""
     oracle = _oracle(12)
     for r in (1, 4, 9):
         oracle.fail(r)
@@ -145,12 +143,8 @@ def test_fused_paths_agree_with_ref_and_two_pass():
         )
     )
     ref = np.asarray(binomial_route_ref(kj, packed, table, state))
-    two_pass = BatchRouter(12, fused=False)
-    for r in (1, 4, 9):
-        two_pass.fail(r)
     np.testing.assert_array_equal(fused_pl, fused_jnp)
     np.testing.assert_array_equal(fused_pl, ref)
-    np.testing.assert_array_equal(fused_pl, two_pass.route_keys_np(keys))
 
 
 def test_fused_multiword_mask_and_table_cascade():
@@ -191,7 +185,8 @@ EVENTS = [
 
 def test_route_keys_is_exactly_one_dispatch_per_batch(monkeypatch):
     """The fused path issues ONE device dispatch per batch and never touches
-    the two-pass entry points — asserted across scale/fail/recover events.
+    the jnp mirror or the plain lookup — asserted across scale/fail/recover
+    events.
 
     The spec dispatcher resolves its engine bundle from ``BULK_ENGINES``
     per call, so swapping the entry intercepts every dispatch."""
@@ -204,14 +199,14 @@ def test_route_keys_is_exactly_one_dispatch_per_batch(monkeypatch):
     router.route_keys(keys)  # compile once
 
     calls = {"fused": 0}
-    real = ops.binomial_route_pallas_fused
+    real = binomial_route_pallas_fused
 
     def counting(*a, **k):
         calls["fused"] += 1
         return real(*a, **k)
 
     def forbidden(*a, **k):  # pragma: no cover - the assertion IS the test
-        raise AssertionError("two-pass entry point reached on the fused path")
+        raise AssertionError("a second entry point reached on the fused path")
 
     monkeypatch.setitem(
         registry.BULK_ENGINES,
@@ -221,10 +216,8 @@ def test_route_keys_is_exactly_one_dispatch_per_batch(monkeypatch):
             route_pallas=counting,
             route=forbidden,  # interpret mode must take the kernel, not jnp
             lookup_dyn=forbidden,
-            lookup_dyn_pallas=forbidden,
         ),
     )
-    monkeypatch.setattr(br_mod, "memento_remap_table", forbidden)
 
     before = binomial_route_fused_2d._cache_size()
     n_batches = 0
@@ -401,12 +394,6 @@ def test_batch_router_rejects_bad_block_rows():
     BatchRouter(8, block_rows=8)  # the smallest legal tiling
 
 
-def test_batch_router_rejects_bad_max_chain():
-    with pytest.raises(ValueError, match="max_chain must be >= 0"):
-        BatchRouter(8, max_chain=-1)
-    BatchRouter(8, max_chain=0)  # zero is a legal (degenerate) budget
-
-
 def test_batch_router_rejects_non_pow2_capacity():
     with pytest.raises(ValueError, match="power of two"):
         BatchRouter(8, capacity=48)
@@ -423,12 +410,7 @@ def test_batch_router_rejects_bad_n_replicas():
 
 
 def test_batch_router_rejects_meaningless_mesh_combinations():
-    """fused=False and donate_keys are sharded-vs-single-host specific —
-    silently ignoring them would invalidate benchmark comparisons."""
-    import jax
-
-    mesh = jax.make_mesh((1,), ("data",))
-    with pytest.raises(ValueError, match="single-host only"):
-        BatchRouter(8, mesh=mesh, fused=False)
+    """donate_keys is specific to the mesh-sharded datapath — silently
+    ignoring it would invalidate benchmark comparisons."""
     with pytest.raises(ValueError, match="donate_keys"):
         BatchRouter(8, donate_keys=True)

@@ -2,7 +2,7 @@
 
 * chain (``memento_remap`` — library flavour): max_chain exhaustion ->
   first_alive fallback, bit-exact vs ``MementoWrapper(chain_bits=32)``;
-* table (``binomial_memento_route`` / ``memento_remap_table`` — the serving
+* table (``binomial_memento_route`` / ``fused_route_impl`` — the serving
   datapath): all-removed-but-one fleets, the deep second redirect, and the
   alive-slot property under hypothesis.
 """
@@ -14,9 +14,9 @@ from repro.core import MementoWrapper, make
 from repro.core.binomial_jax import binomial_lookup_dyn
 from repro.core.memento_jax import (
     binomial_memento_route,
+    fused_route_impl,
     mask_words,
     memento_remap,
-    memento_remap_table,
     pack_removed_mask,
     pack_table,
 )
@@ -63,13 +63,12 @@ def _fused(keys, eng):
 
 
 def _remap_table(keys, eng):
-    """Two-pass TABLE remap (the fused kernel's two-dispatch baseline)."""
+    """TABLE divert through the unjitted body the test oracles share."""
     packed, table, state = _table_state(eng)
-    buckets = binomial_lookup_dyn(keys, np.uint32(eng.n_total))
     return np.asarray(
-        memento_remap_table(jnp.asarray(keys), buckets, jnp.asarray(packed),
-                            jnp.asarray(table), jnp.asarray(state),
-                            n_words=mask_words(CAP))
+        fused_route_impl(jnp.asarray(keys), jnp.asarray(packed),
+                         jnp.asarray(table), jnp.asarray(state), 16,
+                         mask_words(CAP))
     )
 
 
@@ -93,20 +92,6 @@ def test_max_chain_exhaustion_falls_back_to_first_alive(max_chain, removed):
         hit = np.isin(base, list(eng.removed))
         assert hit.any()
         assert (scal[hit] == eng.first_alive()).all()
-
-
-def test_batch_router_parity_with_degenerate_max_chain():
-    """BatchRouter(max_chain=0) stays bit-exact with its scalar oracle —
-    the table divert has a hard two-redirect bound, so a degenerate chain
-    budget changes nothing on the serving datapath."""
-    router = BatchRouter(8, max_chain=0, interpret=True, block_rows=8)
-    router.fail(0)
-    router.fail(4)
-    keys = RNG.integers(0, 2**64, size=(1024,), dtype=np.uint64)
-    out = router.route_keys_np(keys)
-    expect = [router.domain.locate(int(k)) for k in keys]
-    np.testing.assert_array_equal(out, expect)
-    assert 0 not in out and 4 not in out
 
 
 # ---------------------------------------------------------------------------

@@ -225,12 +225,6 @@ def test_load_config_rejects_bad_sampling_knobs():
         LoadConfig(exact_cutoff=-1)
 
 
-def test_attach_rejects_two_pass_baseline():
-    router = BatchRouter(8, engine="binomial", fused=False)
-    with pytest.raises(ValueError, match="fused"):
-        LoadMonitor(router)
-
-
 # ---------------------------------------------------------------------------
 # theory-bound alarms
 # ---------------------------------------------------------------------------

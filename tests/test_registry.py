@@ -94,7 +94,7 @@ def test_bulk_engine_entry_is_complete(name):
     assert eng.name == name
     assert eng.scalar_engine in ENGINES
     assert callable(eng.route)
-    # the serving tier's two-pass baseline and the MoE router need these
+    # the MoE hash router and the certifier need these
     assert callable(eng.lookup_dyn) and callable(eng.lookup_vec)
 
 

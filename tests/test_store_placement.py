@@ -4,8 +4,7 @@ import numpy as np
 import pytest
 
 from repro.core.bulk import PlacementSpec, RouterSpec
-from repro.placement import assignment
-from repro.placement.assignment import Move, MovementPlan
+from repro.placement.assignment import MovementPlan
 from repro.placement.store import NO_HOLDER, StorePlacement, family_salts
 from repro.serving.batch_router import BatchRouter
 from repro.serving.lifecycle import (
@@ -210,20 +209,6 @@ def test_movement_plan_from_diff():
     assert plan.destinations() == {3}
     assert plan.sources() == {0, 2}
     assert {(m.key, m.src, m.dst) for m in plan.moves} == {(2, 2, 3), (3, 0, 3)}
-
-
-def test_movement_plan_legacy_shim_warns_once():
-    assignment._warned.discard("MovementPlan(moves, total_keys)")
-    with pytest.warns(DeprecationWarning, match="from_diff"):
-        plan = MovementPlan([Move(1, 0, 2)], 10)
-    assert plan.moved_count == 1
-    assert plan.moved_fraction == 0.1
-    # warn-once: the second legacy construction is silent
-    import warnings
-
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        MovementPlan([Move(1, 0, 2)], 10)
 
 
 # -- repair scheduler ---------------------------------------------------------

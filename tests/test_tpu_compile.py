@@ -3,9 +3,8 @@
 Interpret mode runs the kernels on the CPU but cannot see what the chip's
 compiler refuses (unsupported casts, unsigned ops, tiles that overflow the
 scoped VMEM).  These tests compile every ``pallas_call`` the routing path
-dispatches — route, u64 ingest and ``lookup_dyn`` of both bulk engines, and
-the static binomial lookup — and the placement pass with its ``table_read``
-kernel, for one chip of a ``v5e:2x2`` topology that is described, not
+dispatches — route and u64 ingest of both bulk engines — and the
+placement pass with its ``table_read`` kernel, for one chip of a ``v5e:2x2`` topology that is described, not
 attached, at 2^20 keys and capacity 1024, and check the kernel is in the
 compiled program.
 
@@ -83,18 +82,14 @@ def _compiled_text(sharding, engine: str, kernel: str, block_rows: int) -> str:
         fn = lambda k, *f: eng.route_pallas(  # noqa: E731
             k, *f, *extents, block_rows=block_rows)
         args = (keys, *fleet)
-    elif kernel == "ingest":
+    else:
         fn = lambda lo, hi, *f: eng.ingest_pallas(  # noqa: E731
             lo, hi, *f, *extents, block_rows=block_rows)
         args = (keys, keys, *fleet)
-    else:
-        fn = lambda k, n: eng.lookup_dyn_pallas(  # noqa: E731
-            k, n, block_rows=block_rows)
-        args = (keys, _shape(sharding, (), jnp.uint32))
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("kernel", ["route", "ingest", "lookup_dyn"])
+@pytest.mark.parametrize("kernel", ["route", "ingest"])
 @pytest.mark.parametrize("engine", sorted(BULK_ENGINES))
 def test_kernel_compiles_for_v5e(one_chip, engine, kernel):
     """Smallest autotuner tile: the kernel body itself must lower."""
@@ -114,16 +109,6 @@ def test_aligned_route_is_the_kernel_alone_on_v5e(one_chip, engine):
     ).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert LAYOUT_COPY.search(text) is None, LAYOUT_COPY.search(text).group(0)
-
-
-def test_static_binomial_lookup_compiles_for_v5e(one_chip):
-    """The static-n lookup (``ops.binomial_bulk_lookup`` on a TPU)."""
-    from repro.kernels.binomial_hash import binomial_bulk_lookup_pallas
-
-    keys = _shape(one_chip, (KEYS,), jnp.uint32)
-    fn = lambda k: binomial_bulk_lookup_pallas(  # noqa: E731
-        k, 1000, block_rows=min(CANDIDATES))
-    assert "tpu_custom_call" in jax.jit(fn).lower(keys).compile().as_text()
 
 
 def test_largest_autotuned_tile_fits_v5e(one_chip):
