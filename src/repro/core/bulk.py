@@ -335,7 +335,9 @@ class BulkEngine:
                       plane embeds it via ``SessionRouter`` and tests pin
                       device == scalar key-for-key
     route             pure-jnp fused lookup+divert mirror:
-                      ``(keys, packed, table, state, omega=, *, n_words=)``
+                      ``(keys, packed, table, state, omega=, *, n_words=,
+                      read=)``; ``read`` (static) reads the slots table in
+                      the divert, the XLA gather by default
     ingest            fused u64-id ingest mirror (u32 halves); None if the
                       engine has no in-kernel session-key mix
     route_pallas /    the Pallas kernel twins (same operand contract as the

@@ -39,7 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.binomial_jax import _mul64, mix64_lo32
-from repro.core.memento_jax import fused_route_impl
+from repro.core.memento_jax import fused_route_impl, gather_read
 
 #: the 64-bit LCG multiplier from the paper (Lamping & Veach, 2014)
 JUMP_LCG = 2862933555777941757
@@ -205,7 +205,7 @@ def jump_lookup_dyn(keys: jax.Array, n: jax.Array, omega: int = 16) -> jax.Array
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnames=("omega", "n_words"))
+@functools.partial(jax.jit, static_argnames=("omega", "n_words", "read"))
 def jump_memento_route(
     keys: jax.Array,
     packed_mask: jax.Array,
@@ -214,6 +214,7 @@ def jump_memento_route(
     omega: int = 16,
     *,
     n_words: int,
+    read=gather_read,
 ) -> jax.Array:
     """Fused jump lookup + replacement-table divert — one dispatch.
 
@@ -221,10 +222,12 @@ def jump_memento_route(
     operand contract and fleet-state semantics identical to
     ``binomial_memento_route`` (only the base lookup body differs).
     Bit-exact against the scalar ``SessionRouter(jump32, chain_bits=32,
-    resolve="table")`` oracle (tests enforce).
+    resolve="table")`` oracle (tests enforce).  ``read`` (static) is the
+    divert's read of the slots table, the XLA gather by default.
     """
     return fused_route_impl(
-        keys, packed_mask, table, state, omega, n_words, lookup=jump_unrolled_body
+        keys, packed_mask, table, state, omega, n_words,
+        lookup=jump_unrolled_body, read=read,
     )
 
 

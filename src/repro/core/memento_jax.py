@@ -7,8 +7,9 @@ the two host flavours in ``repro.core.memento``:
 * **table** (``resolve="table"`` — the serving datapath, DESIGN.md §7):
   the ``ReplacementTable`` slots permutation rides on device as a
   ``(1, C)`` i32 array, uploaded at fleet-event time.  A removed bucket is
-  resolved by at most two u32 hash rounds and EXACTLY ONE table gather —
-  no data-dependent loop, so storm-time batch cost equals steady-time cost.
+  resolved by at most two u32 hash rounds and EXACTLY ONE read of the
+  table — no data-dependent loop, so storm-time batch cost equals
+  steady-time cost.
   ``binomial_memento_route`` fuses base lookup + table divert under one jit
   (the pure-jnp mirror of the fused Pallas kernel).
 
@@ -147,6 +148,13 @@ def memento_remap(
 # ---------------------------------------------------------------------------
 
 
+def gather_read(table: jax.Array, idx: jax.Array) -> jax.Array:
+    """``table[idx]`` by one XLA gather, for indices in bounds by
+    construction: ``promise_in_bounds`` skips XLA's clamp logic (~30%
+    cheaper gathers on XLA:CPU at 1M lanes)."""
+    return table.at[idx].get(mode="promise_in_bounds")
+
+
 def _table_divert(
     keys_u32: jax.Array,
     b: jax.Array,
@@ -154,8 +162,9 @@ def _table_divert(
     table: jax.Array,
     state: jax.Array,
     n_words: int,
+    read=gather_read,
 ) -> jax.Array:
-    """Divert buckets off removed slots — EXACTLY ONE gather, no loop.
+    """Divert buckets off removed slots — EXACTLY ONE table read, no loop.
 
     Mirrors ``ReplacementTable.resolve`` lane-wise (DESIGN.md §7):
 
@@ -171,9 +180,15 @@ def _table_divert(
     Membership is a select cascade over the ``n_words`` packed mask words —
     pure elementwise ops that fuse into the hash pass, unlike a per-lane
     LUT gather.  The whole divert is therefore one fused elementwise pass +
-    one final ``slots`` gather per lane — data-independent and one memory
+    one final read of ``slots`` per lane — data-independent and one memory
     pass short of the two-gather variant, which is what keeps an event
     storm off the batch critical path on memory-bound hosts.
+
+    ``read(slots, q) -> slots[q]`` takes that final read: ``gather_read``,
+    one XLA gather, by default; the placement pass hands in its
+    ``TableRead`` so that on a TPU the read is the ``table_read`` VMEM
+    kernel (DESIGN.md §13.6).  Either way the answer is ``slots[q]`` bit for
+    bit.
     """
     total = state[0].astype(jnp.uint32)
     n_alive = state[1].astype(jnp.uint32)
@@ -189,9 +204,8 @@ def _table_divert(
     deep = q >= n_alive  # a removed position: one more redirect settles it
     # second hash chains off the first (h is avalanched; one fmix32 over q)
     q = jnp.where(deep, mulhi32(mix32(h ^ (q * GOLDEN32)), n_alive), q)
-    # q is in-bounds by construction (q < n_total <= C) — promise_in_bounds
-    # skips XLA's clamp logic (~30% cheaper gathers on XLA:CPU at 1M lanes)
-    return jnp.where(hit, slots.at[q].get(mode="promise_in_bounds"), b)
+    # q is in-bounds by construction (q < n_total <= C)
+    return jnp.where(hit, read(slots, q), b)
 
 
 def _binomial_lookup_body(keys_u32: jax.Array, total: jax.Array, omega: int) -> jax.Array:
@@ -211,6 +225,7 @@ def fused_route_impl(
     omega: int,
     n_words: int,
     lookup=_binomial_lookup_body,
+    read=gather_read,
 ) -> jax.Array:
     """Traceable fused lookup + table-divert body, generic over the base
     engine: ``lookup(keys_u32, n_total, omega) -> u32 buckets`` is the only
@@ -225,6 +240,8 @@ def fused_route_impl(
     state        (2,) uint32 — [n_total, n_alive]
     n_words      static mask word count (= ceil(capacity/32)), bounding the
                  membership select cascade
+    read         the divert's read of the slots table, ``read(slots, q) ->
+                 slots[q]``: ``gather_read`` or a ``TableRead``
     """
     shape = keys.shape
     keys_u32 = keys.reshape(-1).astype(jnp.uint32)
@@ -240,14 +257,16 @@ def fused_route_impl(
     # (measured at 2x batch latency in the PR 2 chain implementation).
     b = jax.lax.cond(
         n_alive != total,
-        lambda bb: _table_divert(keys_u32, bb, packed_mask, table, state, n_words),
+        lambda bb: _table_divert(
+            keys_u32, bb, packed_mask, table, state, n_words, read
+        ),
         lambda bb: bb,
         b,
     )
     return b.astype(jnp.int32).reshape(shape)
 
 
-@functools.partial(jax.jit, static_argnames=("omega", "n_words"))
+@functools.partial(jax.jit, static_argnames=("omega", "n_words", "read"))
 def binomial_memento_route(
     keys: jax.Array,
     packed_mask: jax.Array,
@@ -256,6 +275,7 @@ def binomial_memento_route(
     omega: int = 16,
     *,
     n_words: int,
+    read=gather_read,
 ) -> jax.Array:
     """Fused BinomialHash lookup + replacement-table divert — one dispatch.
 
@@ -267,9 +287,13 @@ def binomial_memento_route(
     state is traced and fixed-shape (``packed_mask``, ``table``, the state
     2-vector), so scale/fail/recover streams never retrace.  Bit-exact
     against the scalar ``SessionRouter(binomial32, chain_bits=32,
-    resolve="table")`` oracle (tests enforce).
+    resolve="table")`` oracle (tests enforce).  ``read`` (static) is the
+    divert's read of the slots table, the XLA gather by default
+    (``fused_route_impl``).
     """
-    return fused_route_impl(keys, packed_mask, table, state, omega, n_words)
+    return fused_route_impl(
+        keys, packed_mask, table, state, omega, n_words, read=read
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("omega", "n_words"))

@@ -151,9 +151,10 @@ def route_replicas_bulk(keys: jax.Array, fleet: FleetState, pspec,
     broadcast batch, then the bounded re-salt resolution breaks inter-family
     collisions in-trace.  Engine resolved per call like every dispatcher
     here.  The pass is XLA on every backend but for its small-table reads:
-    where the route's kernel runs (a TPU, or interpret mode) the probes
-    and the zone fallback read the slots and zone tables from VMEM by the
-    ``table_read`` kernel, elsewhere by XLA gathers (``TableRead``).
+    where the route's kernel runs (a TPU, or interpret mode) the route's
+    divert, the probes and the zone fallback read the slots and zone
+    tables from VMEM by the ``table_read`` kernel, elsewhere by XLA
+    gathers (``TableRead``).
 
     keys   any int shape (u32 key space); fleet  ``FleetState``;
     pspec  ``PlacementSpec`` — replication r, probe bound, zones, the

@@ -2,7 +2,8 @@
 
 The placement pass (``placement/store.py``) reads small tables at every
 key's lane: the slots permutation (1,024 entries at capacity 1024) in each
-re-salt probe, and the zone table (1,152 entries at three zones) in the
+re-salt probe and, while a slot has failed, in the route's divert over all
+``r`` families; and the zone table (1,152 entries at three zones) in the
 zone fallback.  As an XLA gather over 2^20 lanes such a read takes ~7 ms
 on a TPU v5e, far more than the few vector ops a key costs elsewhere.
 
@@ -26,6 +27,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core.bulk import DEFAULT_BLOCK_ROWS, RouterSpec
+from repro.core.memento_jax import gather_read
 from repro.kernels.fused import LANES
 
 #: widest table, in rows of 128 entries, read from VMEM (8,192 entries);
@@ -113,7 +115,7 @@ class TableRead:
     def __call__(self, table: jax.Array, idx: jax.Array) -> jax.Array:
         """(W,) u32 table, (N,) u32 indices below W -> (N,) u32."""
         if self.path(table.shape[0]) == "gather":
-            return table.at[idx].get(mode="promise_in_bounds")
+            return gather_read(table, idx)
         return _vmem_read(
             table, idx, block_rows=self.block_rows, interpret=self.interpret
         )

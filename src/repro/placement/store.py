@@ -128,13 +128,14 @@ def route_replicas_impl(
     max_resalt   static probe bound per column (``PlacementSpec``
                  resolves ``None`` to ``r``, the distinctness guarantee)
     route        the engine's fused jnp route
-                 ``(keys, packed, table, state, omega=, n_words=)``
+                 ``(keys, packed, table, state, omega=, n_words=, read=)``
     zones        static zone count (slot ``s`` in zone ``s mod zones``);
                  1 traces the zone-free pass and nothing of the zones
     zone_width   static zone-table entries per zone (``PlacementSpec``)
-    read         how the pass reads its slots and zone tables at every lane
-                 (``TableRead``: the XLA gather, the default, or the VMEM
-                 kernel); the answer is the same either way
+    read         how the pass reads its slots and zone tables at every lane,
+                 the route's divert included (``TableRead``: the XLA gather,
+                 the default, or the VMEM kernel); the answer is the same
+                 either way
 
     Returns ``(replicas, exhausted)``: ``replicas`` is ``(N, r)`` int32,
     every entry an ALIVE shard; column ``j`` is distinct from columns
@@ -170,7 +171,7 @@ def route_replicas_impl(
     n = keys_u32.shape[0]
     fam = mix32(keys_u32 ^ family_salts(r)[:, None]).reshape(-1)  # (r * N,)
     base = route(
-        fam, packed_mask, table, state, omega=omega, n_words=n_words
+        fam, packed_mask, table, state, omega=omega, n_words=n_words, read=read
     ).astype(jnp.uint32)
 
     # per-lane used-shard bitmask: n_words u32 words, set/tested via the
@@ -338,7 +339,12 @@ def table_reads(pspec: PlacementSpec) -> dict[str, int]:
     """The small-table reads one placement pass makes, by ``TableRead``
     path (``"vmem"`` or ``"gather"``): ``(r - 1) * max_resalt`` probes of
     the slots table, and with zones ``min(r, zones) - 1`` zone-table reads.
-    Static, so the host counts them without reading the device."""
+    Static, so the host counts them without reading the device.
+
+    Not listed: the route's divert, one more read of the slots table (over
+    all ``r`` families at once, on the path ``divert_read_path`` gives),
+    which runs only while a slot has failed.  ``StorePlacement.place_keys``
+    counts it per dispatch from the fleet's ``n_alive != n_total``."""
     read = TableRead.for_spec(pspec.router)
     reads: dict[str, int] = {}
     for width, n in (
@@ -351,6 +357,13 @@ def table_reads(pspec: PlacementSpec) -> dict[str, int]:
             path = read.path(width)
             reads[path] = reads.get(path, 0) + n
     return reads
+
+
+def divert_read_path(pspec: PlacementSpec) -> str:
+    """The ``TableRead`` path of the route's divert read of the slots
+    table inside the pass."""
+    spec = pspec.router
+    return TableRead.for_spec(spec).path(table_width(spec.capacity))
 
 
 # ---------------------------------------------------------------------------
@@ -465,6 +478,10 @@ class StorePlacement:
             for path, n in table_reads(self.spec).items()
         ]
         self._n_reads = sum(n for _, n in self._reads)
+        #: the divert's read, counted on each dispatch while a slot has failed
+        self._divert_reads = self.metrics.counter(
+            "placement_table_reads_total", path=divert_read_path(self.spec)
+        )
         if zones > 1:
             #: (routing epoch, device ZoneState) last uploaded
             self._zone_dev: tuple[int, ZoneState] | None = None
@@ -575,15 +592,20 @@ class StorePlacement:
             size = int(np.size(keys_u32))
             for counter, n in self._reads:
                 counter.inc(n)
+            reads = self._n_reads
+            if self.n_alive != self.router.domain.total_count:
+                # the route's divert opens its cond and reads the slots table
+                self._divert_reads.inc()
+                reads += 1
             if self.spec.zones == 1:
                 with span("route.launch") as s:
                     if s:
-                        s.tag(rows=-(-size // LANES), reads=self._n_reads)
+                        s.tag(rows=-(-size // LANES), reads=reads)
                     return ops.route_replicas_bulk(keys_u32, fleet, self.spec)
             zone = self._zone_state_dev()
             with span("route.launch") as s:
                 if s:
-                    s.tag(rows=-(-size // LANES), reads=self._n_reads,
+                    s.tag(rows=-(-size // LANES), reads=reads,
                           zones=self.spec.zones, alive_zones=self.alive_zones)
                 replicas, exhausted, self._fallbacks = ops.route_replicas_bulk(
                     keys_u32, fleet, self.spec, zone, self._fallbacks

@@ -348,8 +348,8 @@ def test_cli_fails_on_seeded_violation_engine(capsys, monkeypatch):
     from repro.analysis.__main__ import main
     from repro.core import registry
 
-    def bad_route(keys, packed, table, state, omega=16, *, n_words):
-        del packed, table, state, n_words
+    def bad_route(keys, packed, table, state, omega=16, *, n_words, read=None):
+        del packed, table, state, n_words, read
         return _while_route(keys, omega)
 
     broken = dataclasses.replace(

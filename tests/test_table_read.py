@@ -1,15 +1,20 @@
 """The placement pass's small-table read (``kernels/table_read.py``): the VMEM
-kernel in interpret mode against ``table[idx]`` bit for bit, the whole pass
-with it against the pass with XLA gathers, the path each table width takes,
-and the count of reads each dispatch adds to the registry."""
+kernel in interpret mode against ``table[idx]`` bit for bit, the route's
+divert and the whole pass with it against the same with XLA gathers, the
+path each table width takes, and the count of reads each dispatch adds to
+the registry."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core.bulk import PlacementSpec, RouterSpec
+from repro.core.jump_jax import jump_unrolled_body
+from repro.core.memento_jax import fused_route_impl
+from repro.core.registry import BULK_ENGINES
+from repro.kernels import ops
 from repro.kernels.table_read import MAX_VMEM_ROWS, TableRead
-from repro.placement.store import StorePlacement, table_reads
+from repro.placement.store import StorePlacement, divert_read_path, table_reads
 from repro.serving.batch_router import BatchRouter
 
 KEYS = np.random.default_rng(17).integers(0, 1 << 32, 3000, dtype=np.uint32)
@@ -103,6 +108,51 @@ FLEETS = {
 }
 
 
+def _conds(jaxpr):
+    """Every ``cond`` equation of a jaxpr, nested ones included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "cond":
+            yield eqn
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (tuple, list)) else (param,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _conds(sub)
+
+
+def _kernel_in_the_divert(closed) -> bool:
+    """The divert's ``cond`` of a traced program reads by the kernel."""
+    return any("pallas_call" in str(eqn) for eqn in _conds(closed.jaxpr))
+
+
+@pytest.mark.parametrize("fleet", ("storm", "zone_loss"))
+@pytest.mark.parametrize("engine", ("binomial", "jump"))
+def test_route_with_vmem_read_equals_the_gather_route(engine, fleet):
+    router = FLEETS[fleet][0]()
+    fleet_ops = tuple(jnp.asarray(a) for a in (
+        router._fleet_host.packed, router._fleet_host.table,
+        router._fleet_host.state))
+    keys = jnp.asarray(KEYS)  # 3,000 keys: not a whole number of 128-lane rows
+    spec = router.spec
+    lookup = {"jump": {"lookup": jump_unrolled_body}}.get(engine, {})
+    want = fused_route_impl(keys, *fleet_ops, spec.omega, spec.n_words,
+                            **lookup)
+    got = fused_route_impl(keys, *fleet_ops, spec.omega, spec.n_words,
+                           read=VMEM, **lookup)
+    route = BULK_ENGINES[engine].route
+    static = dict(omega=spec.omega, n_words=spec.n_words)
+    want_jit = route(keys, *fleet_ops, **static)
+    got_jit = route(keys, *fleet_ops, read=VMEM, **static)
+    for a in (got, want_jit, got_jit):
+        assert np.array_equal(np.asarray(a), np.asarray(want))
+    # the divert ran, and read by the kernel inside its cond
+    assert not np.isin(np.asarray(got), sorted(router.domain.removed)).any()
+    assert _kernel_in_the_divert(
+        jax.make_jaxpr(lambda k: route(k, *fleet_ops, read=VMEM, **static))(keys))
+    assert not _kernel_in_the_divert(
+        jax.make_jaxpr(lambda k: route(k, *fleet_ops, **static))(keys))
+
+
 @pytest.mark.parametrize("fleet", sorted(FLEETS))
 def test_pass_with_vmem_read_equals_the_gather_pass(fleet):
     make, r, max_resalt = FLEETS[fleet]
@@ -113,7 +163,17 @@ def test_pass_with_vmem_read_equals_the_gather_pass(fleet):
                                zones=router.domain.zones)
         path = "vmem" if kw else "gather"
         assert set(table_reads(store.spec)) == {path}
+        assert divert_read_path(store.spec) == path
+        zone = () if store.spec.zones == 1 else (store._zone_state_dev(),)
+        traced = jax.make_jaxpr(lambda k: ops.route_replicas_bulk(
+            k, router._fleet_dev, store.spec, *zone))(KEYS)
+        assert _kernel_in_the_divert(traced) == (path == "vmem")
         answers.append([np.asarray(a) for a in store.place_keys(KEYS)])
+        # a failed slot opens the divert: one more read of the slots table
+        divert = router.domain.alive_count != router.domain.total_count
+        assert divert == (fleet != "exhausting")
+        assert store.metrics.total("placement_table_reads_total", path=path) \
+            == sum(table_reads(store.spec).values()) + divert
     want, got = answers
     for a, b in zip(got, want):
         assert np.array_equal(a, b)
@@ -124,16 +184,29 @@ def test_pass_with_vmem_read_equals_the_gather_pass(fleet):
 # -- the counter -----------------------------------------------------------------
 
 
-@pytest.mark.parametrize("zones,interpret,path,per_call", [
-    (1, False, "gather", 6),
-    (1, True, "vmem", 6),
-    (3, False, "gather", 8),
-    (3, True, "vmem", 8),
+def _counted(zones, interpret, path, per_call, failed=False):
+    # a healthy fleet's cases keep the ids they had before the failed ones
+    name = f"{zones}-{interpret}-{path}-{per_call}" + ("-failed" if failed else "")
+    return pytest.param(zones, interpret, path, per_call, failed, id=name)
+
+
+@pytest.mark.parametrize("zones,interpret,path,per_call,failed", [
+    _counted(1, False, "gather", 6),
+    _counted(1, True, "vmem", 6),
+    _counted(3, False, "gather", 8),
+    _counted(3, True, "vmem", 8),
+    # one failed shard: the route's divert reads the slots table once more
+    _counted(1, False, "gather", 7, failed=True),
+    _counted(1, True, "vmem", 7, failed=True),
+    _counted(3, False, "gather", 9, failed=True),
+    _counted(3, True, "vmem", 9, failed=True),
 ])
 def test_table_reads_counted_per_dispatch_by_path(zones, interpret, path,
-                                                  per_call):
+                                                  per_call, failed):
     router = BatchRouter(64, capacity=64, zones=zones, interpret=interpret,
                          block_rows=8)
+    if failed:
+        router.fail(5)
     store = StorePlacement(router, r=3, zones=zones)
     m = store.metrics
     for calls in (1, 2):
@@ -141,6 +214,12 @@ def test_table_reads_counted_per_dispatch_by_path(zones, interpret, path,
         assert m.total("placement_table_reads_total", path=path) == (
             calls * per_call)
     assert m.total("placement_table_reads_total") == 2 * per_call
+    if failed:
+        # the shard recovered: the divert is skipped, its read not counted
+        router.recover(5)
+        store.place_keys(KEYS[:256])
+        assert m.total("placement_table_reads_total", path=path) == (
+            3 * per_call - 1)
 
 
 def test_wide_tables_count_as_gathers():
